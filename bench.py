@@ -1,20 +1,15 @@
 """
 Benchmark: 2D Rayleigh-Benard IVP timesteps/sec on one chip
-(progression config 3 from BASELINE.md: Fourier x Chebyshev, banded-matsolve
-path, reference example: examples/ivp_2d_rayleigh_benard).
+(progression config 3 from BASELINE.md: Fourier x Chebyshev, reference
+example: examples/ivp_2d_rayleigh_benard; `matsolver` is left to `auto`,
+which is the dense-inverse path at 256x64 — ROADMAP A3).
 
-Prints ONE JSON line on stdout: {"metric", "value", "unit", "vs_baseline"}.
-All progress/diagnostic markers go to stderr so a timeout tail is diagnostic.
-
-Self-defense (round-1 failure mode was a silent TPU-init crash):
-  * every phase (probe, import, devices, build, warmup, measure) prints a
-    timestamped marker to stderr;
-  * the backend is probed in a SUBPROCESS with a timeout before this process
-    commits to initializing it (a wedged PJRT plugin cannot be interrupted
-    in-process);
-  * TPU-init failure is retried once, then falls back to CPU so a number is
-    always produced; the fallback is recorded in the metric name and an
-    "error" field.
+Runs `run_benchmark()` in THIS process: one process per chip, no child, no
+probe, no cached row, no CPU fallback. Prints the platform, device kind and
+device count first, then ONE JSON line on stdout: {"metric", "value",
+"unit", "vs_baseline", ...}; progress markers go to stderr. Exits non-zero
+when the platform is not `tpu`, unless JAX_PLATFORMS names `cpu` itself (a
+CPU run is then what was asked for, and the metric name says `cpu`).
 
 Baseline estimate: the reference example (256x64, RK222+CFL, stop_sim_time=50)
 takes ~5 cpu-minutes on a 4-core workstation (reference docstring,
@@ -24,7 +19,6 @@ adaptive dt averaging ~0.03, that is ~1700 steps / 300 s ~= 5.7 steps/sec.
 
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -34,32 +28,11 @@ NX, NZ = 256, 64
 WARMUP = 10
 MEASURE = 50
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-# Shared wedge-defense helpers (probe subprocess, plugin-strip env) live in
-# __graft_entry__ so bench.py and the dryrun use identical logic.
-from __graft_entry__ import (_append_result, _kill_group, _probe_devices,
-                             _probe_backend_cached, _probe_backend_retrying,
-                             _sanitize_jax_platforms,
-                             _strip_plugin_env)  # noqa: E402
-
-
-def _log_result(record):
-    """Machine-record the PARENT-ACCEPTED outcome (success, fallback with
-    its error context, or total failure): a figure that exists only in
-    stdout/prose is a claim, not a result — and a child's own append could
-    leave orphan lines for runs the parent rejects."""
-    entry = {"config": f"rb{NX}x{NZ}_bench"}
-    entry.update(record)
-    _append_result(entry)
+from __graft_entry__ import _append_result  # noqa: E402
 
 
 def mark(msg):
     print(f"[bench {time.time() - T0:7.1f}s] {msg}", file=sys.stderr, flush=True)
-
-
-def probe_backend(env, timeout=None):
-    """Returns (ok, backend_name_or_error)."""
-    backend, info = _probe_devices(env, timeout)
-    return (backend is not None), (backend if backend is not None else info)
 
 
 def run_benchmark():
@@ -134,26 +107,13 @@ def run_benchmark():
         health_sum = None
     if health_sum is not None:
         record["health"] = health_sum
-    # Resilience summary (tools/resilience.py): rewind/retry/resume
-    # counts when the run was driven by a ResilientLoop (absent — not
-    # zero — for a plain loop, so readers can tell "no resilience" from
-    # "resilience, no events").
-    resilience = getattr(solver, "resilience", None)
-    if resilience is not None:
-        try:
-            record["resilience"] = resilience.summary()
-        except Exception as exc:
-            mark(f"resilience summary failed (non-fatal): {exc}")
     # Jit-hygiene sentinels, so the perf trajectory shows hygiene
     # regressions alongside steps/sec: post-warmup retrace count
     # (tools/retrace.py; anything nonzero means the measured loop paid
     # compile time) and static-analysis cleanliness vs the checked-in
     # baseline (tools/lint).
-    try:
-        from dedalus_tpu.tools.retrace import sentinel
-        record["retraces_post_warmup"] = sentinel.post_arm_retraces
-    except Exception as exc:
-        mark(f"retrace sentinel read failed (non-fatal): {exc}")
+    from dedalus_tpu.tools.retrace import sentinel
+    record["retraces_post_warmup"] = sentinel.post_arm_retraces
     try:
         from dedalus_tpu.tools.lint import lint_package
         lint_summary = lint_package()
@@ -165,617 +125,22 @@ def run_benchmark():
     return record
 
 
-def _run_child(env, timeout, tag):
-    """Run the measurement in a fresh interpreter with a hard timeout (an
-    in-process wedge — PJRT init or a hung remote compile — cannot be
-    interrupted any other way). Returns (record_or_None, error_or_None)."""
-    env = dict(env)
-    env["_BENCH_CHILD"] = "1"
-    mark(f"running benchmark in {tag} subprocess (timeout {timeout}s)")
-    # own session + process-GROUP kill on timeout: a leaked chip-holding
-    # grandchild is the round-2 wedge; stderr streams through live (progress
-    # marks stay observable); only stdout (the JSON record) is captured
-    proc = subprocess.Popen([sys.executable, os.path.abspath(__file__)],
-                            env=env, stdout=subprocess.PIPE, text=True,
-                            start_new_session=True)
-    try:
-        out, _ = proc.communicate(timeout=timeout)
-    except subprocess.TimeoutExpired:
-        _kill_group(proc)
-        return None, f"{tag} child timed out after {timeout}s"
-    line = next((ln for ln in out.splitlines() if ln.startswith("{")), None)
-    if proc.returncode == 0 and line:
-        try:
-            return json.loads(line), None
-        except ValueError:
-            return None, f"{tag} child emitted unparsable record"
-    return None, f"{tag} child rc={proc.returncode}"
-
-
-def _stale_window_sec():
-    """The ONE measurement window every attach/probe helper shares:
-    `[bench] STALE_WINDOW_SEC` (default 48h — wide enough to span a
-    round whose chip window opened early, or the previous round's sweep
-    when the chip stayed unclaimable throughout). Config-backed so
-    operators widen/narrow it in one place instead of chasing hardcoded
-    48s through each helper."""
-    try:
-        from dedalus_tpu.tools.config import config
-        return float(config.get("bench", "STALE_WINDOW_SEC",
-                                fallback=48 * 3600.0))
-    except Exception:
-        return 48.0 * 3600.0
-
-
-def _recent_row(predicate, max_age_sec=None):
-    """Latest results.jsonl row satisfying `predicate` whose report ts
-    falls inside the measurement window (default `_stale_window_sec()`;
-    `max_age_sec=0` disables the window). The ONE scan loop behind the
-    TPU-headline, ensemble, and serving probes, so the provenance-window
-    rules can never drift between them."""
-    import time
-    if max_age_sec is None:
-        max_age_sec = _stale_window_sec()
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "benchmarks", "results.jsonl")
-    best = None
-    try:
-        with open(path) as f:
-            for line in f:
-                try:
-                    row = json.loads(line)
-                except json.JSONDecodeError:
-                    continue
-                if (predicate(row) and row.get("ts")
-                        and (not max_age_sec
-                             or time.time() - row["ts"] < max_age_sec)):
-                    best = row
-    except OSError:
-        return None
-    return best
-
-
-def _recent_tpu_row(config=None, max_age_sec=None):
-    """Latest finite backend=tpu row for `config` (default rb256x64) from
-    results.jsonl recorded within the shared measurement window
-    (`[bench] STALE_WINDOW_SEC` via _stale_window_sec(), as rows carry
-    their own measured_ts provenance). `max_age_sec=0` disables the
-    window (the stale-headline guard's unfiltered probe)."""
-    config = config or f"rb{NX}x{NZ}"
-    return _recent_row(
-        lambda row: (row.get("config") == config
-                     and row.get("backend") == "tpu"
-                     and row.get("finite")
-                     and row.get("steps_per_sec")),
-        max_age_sec)
-
-
-def _prior_headline_reuses(measured_ts, same_round_grace_hours=6.0):
-    """(rounds, rerun): how many PREVIOUS official bench headline ROUNDS
-    already re-reported the watcher row with this measured_ts, and whether
-    the newest such report is recent enough that the current run is a
-    retry of that same round (a flaky-probe re-run inside the window that
-    owns the measurement, not a new reuse). Reports clustered within
-    `same_round_grace_hours` count as ONE round, and refusal records
-    (`stale_headline`) never count — otherwise a refusal would increment
-    the tally it guards on and wedge every subsequent run."""
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "benchmarks", "results.jsonl")
-    report_times = []
-    try:
-        with open(path) as f:
-            for line in f:
-                try:
-                    row = json.loads(line)
-                except json.JSONDecodeError:
-                    continue
-                # rows from before the stale-stamp convention carry
-                # measured_ts but no `stale` flag; any headline that
-                # re-reported this measurement counts as a reuse
-                if (row.get("config") == f"rb{NX}x{NZ}_bench"
-                        and row.get("measured_ts") == measured_ts
-                        and measured_ts is not None
-                        and not row.get("stale_headline")
-                        and row.get("ts")):
-                    report_times.append(float(row["ts"]))
-    except OSError:
-        pass
-    if not report_times:
-        return 0, False
-    report_times.sort()
-    grace = same_round_grace_hours * 3600.0
-    rounds, anchor = 1, report_times[0]
-    for t in report_times[1:]:
-        if t - anchor > grace:
-            rounds += 1
-            anchor = t
-    rerun = (time.time() - report_times[-1]) <= grace
-    return rounds, rerun
-
-
-def _refuse_stale(record, errors, reason):
-    """Record a stale-headline refusal (loudly, rc=1): one shape for both
-    refusal sites so `report` consumers see consistent fields."""
-    record["stale_headline"] = reason
-    record["error"] = "; ".join(errors + [f"stale_headline: {reason}"])
-    mark(f"REFUSING stale headline: {reason}")
-    _attach_progression(record)
-    _log_result(record)
-    print(json.dumps(record), flush=True)
-    sys.exit(1)
-
-
-def _attach_progression(record):
-    """Attach this round's machine-recorded progression-config TPU rows
-    (the north-star RB 2048x1024 and sphere shallow-water ell=255) so the
-    official bench line carries the BASELINE.md deliverables when the
-    watcher sweep landed them. These are by construction CACHED prior
-    measurements, never fresh: each carries `stale: true`, its original
-    `measured_ts`, and `age_s` relative to report time, so a reader can
-    never mistake a re-emitted number for a new run (VERDICT rounds 4-5)."""
-    for key, config in (("north_star_rb2048x1024", "rb2048x1024"),
-                        ("sw_ell255", "sw_ell255")):
-        row = _recent_tpu_row(config)
-        if row is not None:
-            record[key] = {
-                "steps_per_sec": row["steps_per_sec"],
-                "finite": bool(row.get("finite")),
-                "build_sec": row.get("build_sec"),
-                "stale": True,
-                "measured_ts": row.get("ts"),
-                "age_s": round(time.time() - row["ts"], 1)
-                if row.get("ts") else None,
-            }
-    _attach_ensemble(record)
-    _attach_serving(record)
-    _attach_adjoint(record)
-    _attach_checkpoint(record)
-    _attach_fusion(record)
-    _attach_solvecomp(record)
-    _attach_scaling(record)
-    return record
-
-
-def _recent_ensemble_row(config, max_age_sec=None):
-    """Latest benchmarks/ensemble.py sweep row for `config` within the
-    shared measurement window. Ensemble rows are CPU-measured by design
-    (the virtual member mesh; ROADMAP platform note), so unlike
-    _recent_tpu_row this does not filter on backend."""
-    return _recent_row(
-        lambda row: (row.get("config") == config
-                     and isinstance(row.get("sweep"), list)
-                     and row["sweep"]
-                     and row.get("speedup_n64") is not None),
-        max_age_sec)
-
-
-def _attach_ensemble(record):
-    """Attach the newest in-window ensemble benchmark headline (fleet
-    member-steps/s vs N x serial, benchmarks/ensemble.py) to the official
-    bench line. Same provenance discipline as the progression rows: the
-    number is a CACHED prior measurement, stamped stale with its original
-    measured_ts and age so it can never pass as fresh — and the stale-
-    headline guard's 48h window applies (an out-of-window row is simply
-    not attached, so an ancient speedup cannot ride along forever)."""
-    for key, config in (("ensemble_diffusion64", "diffusion64_ensemble"),
-                        ("ensemble_rb256x64", "rb256x64_ensemble")):
-        row = _recent_ensemble_row(config)
-        if row is None:
-            continue
-        best = max(row["sweep"],
-                   key=lambda p: p.get("ensemble_steps_per_sec") or 0)
-        record[key] = {
-            "speedup_n64": row.get("speedup_n64"),
-            "meets_4x_n64": row.get("meets_4x_n64"),
-            "best_members": best.get("members"),
-            "best_ensemble_steps_per_sec":
-                best.get("ensemble_steps_per_sec"),
-            "serial_steps_per_sec":
-                (row.get("serial") or {}).get("steps_per_sec"),
-            "backend": row.get("backend"),
-            "stale": True,
-            "measured_ts": row.get("ts"),
-            "age_s": round(time.time() - row["ts"], 1)
-            if row.get("ts") else None,
-        }
-    return record
-
-
-def _recent_serving_row(config, max_age_sec=None):
-    """Latest benchmarks/serving.py row for `config` within the shared
-    measurement window. Serving rows are CPU-measured by design (the
-    daemon subprocess; ROADMAP platform note), so no backend filter."""
-    return _recent_row(
-        lambda row: (row.get("config") == config
-                     and row.get("ttfs_speedup") is not None
-                     and row.get("bit_identical_cold_warm")),
-        max_age_sec)
-
-
-def _attach_serving(record):
-    """Attach the newest in-window serving benchmark headline (warm
-    pool-hit vs cold fresh-process time-to-first-step,
-    benchmarks/serving.py) to the official bench line. Same provenance
-    discipline as the ensemble rows: a CACHED prior measurement, stamped
-    stale with its original measured_ts and age, and dropped entirely
-    once outside the 48h window."""
-    for key, config in (("serving_rb256x64", "rb256x64_serving"),
-                        ("serving_diffusion64", "diffusion64_serving")):
-        row = _recent_serving_row(config)
-        if row is None:
-            continue
-        record[key] = {
-            "ttfs_cold_sec": row.get("ttfs_cold_sec"),
-            "ttfs_warm_sec": row.get("ttfs_warm_sec"),
-            "ttfs_speedup": row.get("ttfs_speedup"),
-            "meets_10x": row.get("meets_10x"),
-            "throughput_requests_per_sec":
-                row.get("throughput_requests_per_sec"),
-            "backend": row.get("backend"),
-            "stale": True,
-            "measured_ts": row.get("ts"),
-            "age_s": round(time.time() - row["ts"], 1)
-            if row.get("ts") else None,
-        }
-    # the continuous-batching row (benchmarks/serving.py run_batching):
-    # batched vs single-executor requests/s under the same-spec
-    # closed-loop storm, same stale-stamping discipline
-    row = _recent_row(
-        lambda r: (r.get("config") == "diffusion64_batching"
-                   and r.get("requests_speedup") is not None))
-    if row is not None:
-        record["serving_batching"] = {
-            "clients": row.get("clients"),
-            "baseline_requests_per_sec":
-                row.get("baseline_requests_per_sec"),
-            "batched_requests_per_sec":
-                row.get("batched_requests_per_sec"),
-            "requests_speedup": row.get("requests_speedup"),
-            "meets_1p5x": row.get("meets_1p5x"),
-            "batches": row.get("batches"),
-            "late_joins": row.get("late_joins"),
-            "peak_batch_members": row.get("peak_batch_members"),
-            "backend": row.get("backend"),
-            "stale": True,
-            "measured_ts": row.get("ts"),
-            "age_s": round(time.time() - row["ts"], 1)
-            if row.get("ts") else None,
-        }
-    # the overload row (benchmarks/serving.py run_overload): shed-rate +
-    # bounded accepted-latency under a 2x storm, same stale-stamping
-    row = _recent_row(
-        lambda r: (r.get("config") == "diffusion64_overload"
-                   and r.get("shed_rate") is not None))
-    if row is not None:
-        record["serving_overload"] = {
-            "queue_depth": row.get("queue_depth"),
-            "storm_rate_x": row.get("storm_rate_x"),
-            "shed_rate": row.get("shed_rate"),
-            "accepted_p50_sec": row.get("accepted_p50_sec"),
-            "accepted_p95_sec": row.get("accepted_p95_sec"),
-            "latency_bound_sec": row.get("latency_bound_sec"),
-            "max_queued_observed": row.get("max_queued_observed"),
-            "bounded_under_overload": row.get("bounded_under_overload"),
-            "daemon_restarts": row.get("daemon_restarts"),
-            "backend": row.get("backend"),
-            "stale": True,
-            "measured_ts": row.get("ts"),
-            "age_s": round(time.time() - row["ts"], 1)
-            if row.get("ts") else None,
-        }
-    return record
-
-
-def _attach_adjoint(record):
-    """Attach the newest in-window adjoint benchmark headline (grad-step
-    vs forward-step cost ratio + checkpoint-segment memory sweep,
-    benchmarks/adjoint.py) to the official bench line. Same provenance
-    discipline as the ensemble/serving rows: a CACHED prior measurement,
-    stamped stale with its original measured_ts and age, dropped once
-    outside the 48h window. Adjoint rows are CPU-measured by design
-    (ROADMAP platform note), so no backend filter."""
-    row = _recent_row(
-        lambda r: (r.get("config") == "diffusion64_adjoint"
-                   and r.get("grad_forward_ratio") is not None
-                   and r.get("finite")))
-    if row is None:
-        return record
-    best_mem = min((p for p in (row.get("segments_sweep") or [])
-                    if p.get("peak_rss_bytes")),
-                   key=lambda p: p["peak_rss_bytes"], default=None)
-    record["adjoint_diffusion64"] = {
-        "grad_forward_ratio": row.get("grad_forward_ratio"),
-        "grad_steps_per_sec": row.get("grad_steps_per_sec"),
-        "forward_steps_per_sec": row.get("forward_steps_per_sec"),
-        "fd_rel_err": row.get("fd_rel_err"),
-        "n_steps": row.get("n_steps"),
-        "best_mem_segments": best_mem.get("segments") if best_mem else None,
-        "best_mem_peak_rss_bytes":
-            best_mem.get("peak_rss_bytes") if best_mem else None,
-        "backend": row.get("backend"),
-        "stale": True,
-        "measured_ts": row.get("ts"),
-        "age_s": round(time.time() - row["ts"], 1)
-        if row.get("ts") else None,
-    }
-    return record
-
-
-def _attach_checkpoint(record):
-    """Attach the newest in-window checkpointing benchmark headline
-    (per-checkpoint step-loop stall by mode + restore-after-fault wall,
-    benchmarks/checkpointing.py) to the official bench line. Same
-    provenance discipline as the serving/adjoint rows: a CACHED prior
-    measurement, stamped stale with its original measured_ts and age,
-    dropped once outside the 48h window. Checkpoint rows are
-    CPU-measured by design (ROADMAP platform note), so no backend
-    filter."""
-    row = _recent_row(
-        lambda r: (r.get("config") == "rb256x64_checkpoint"
-                   and r.get("stall_async_sharded_sec") is not None
-                   and r.get("finite")))
-    if row is None:
-        return record
-    record["checkpoint_rb256x64"] = {
-        "stall_sync_hdf5_sec": row.get("stall_sync_hdf5_sec"),
-        "stall_sync_sharded_sec": row.get("stall_sync_sharded_sec"),
-        "stall_async_sharded_sec": row.get("stall_async_sharded_sec"),
-        "stall_reduction_async_vs_hdf5":
-            row.get("stall_reduction_async_vs_hdf5"),
-        "restore_after_fault_sec": row.get("restore_after_fault_sec"),
-        "checkpoints": row.get("checkpoints"),
-        "backend": row.get("backend"),
-        "stale": True,
-        "measured_ts": row.get("ts"),
-        "age_s": round(time.time() - row["ts"], 1)
-        if row.get("ts") else None,
-    }
-    return record
-
-
-def _attach_fusion(record):
-    """Attach the newest in-window fusion benchmark headlines (fused vs
-    unfused steps/s + per-phase breakdown, benchmarks/fusion.py) to the
-    official bench line. Same provenance discipline as the ensemble/
-    serving/adjoint rows: a CACHED prior measurement, stamped stale with
-    its original measured_ts and age, dropped once outside the 48h
-    window. Fusion rows are CPU-measured by design (ROADMAP platform
-    note), so no backend filter."""
-    for key, config in (("fusion_rb256x64", "rb256x64_fusion"),
-                        ("fusion_diffusion64", "diffusion64_fusion")):
-        row = _recent_row(
-            lambda r, c=config: (r.get("config") == c
-                                 and r.get("fusion_speedup") is not None
-                                 and r.get("finite")))
-        if row is None:
-            continue
-        record[key] = {
-            "steps_per_sec_unfused": row.get("steps_per_sec_unfused"),
-            "steps_per_sec_fused": row.get("steps_per_sec_fused"),
-            "fusion_speedup": row.get("fusion_speedup"),
-            "meets_1p15x": row.get("meets_1p15x"),
-            "state_rel_diff": row.get("state_rel_diff"),
-            "fusion": row.get("fusion"),
-            "backend": row.get("backend"),
-            "stale": True,
-            "measured_ts": row.get("ts"),
-            "age_s": round(time.time() - row["ts"], 1)
-            if row.get("ts") else None,
-        }
-    return record
-
-
-def _attach_solvecomp(record):
-    """Attach the newest in-window solve-composition sweep headlines
-    (sequential/ascan/spike x f64/f32+refine steps/s + accuracy,
-    benchmarks/fusion.py run_solve_sweep) to the official bench line.
-    Same provenance discipline as the fusion rows: a CACHED prior
-    measurement, stamped stale with its original measured_ts and age,
-    dropped once outside the 48h window. CPU-measured by design (ROADMAP
-    platform note), so no backend filter."""
-    for key, config in (("solvecomp_rb256x64", "rb256x64_solvecomp"),
-                        ("solvecomp_diffusion64", "diffusion64_solvecomp")):
-        row = _recent_row(
-            lambda r, c=config: (r.get("config") == c
-                                 and isinstance(r.get("sweep"), list)
-                                 and r.get("baseline_steps_per_sec")
-                                 is not None))
-        if row is None:
-            continue
-        record[key] = {
-            "baseline_steps_per_sec": row.get("baseline_steps_per_sec"),
-            "best_f64_accurate": row.get("best_f64_accurate"),
-            "meets_1p15x": row.get("meets_1p15x"),
-            "ladder": row.get("ladder"),
-            "ladder_meets_1e10": row.get("ladder_meets_1e10"),
-            "sweep": [{k: c.get(k) for k in
-                       ("composition", "solve_dtype", "steps_per_sec",
-                        "speedup", "state_rel_err", "refine_sweeps",
-                        "achieved_residual")}
-                      for c in row["sweep"]],
-            "backend": row.get("backend"),
-            "stale": True,
-            "measured_ts": row.get("ts"),
-            "age_s": round(time.time() - row["ts"], 1)
-            if row.get("ts") else None,
-        }
-    return record
-
-
-def _attach_scaling(record):
-    """Attach the newest in-window weak-scaling headline (steps/s per
-    device count + transpose overlap split + chunked-vs-monolithic
-    guard + 2048x1024 north-star shape, benchmarks/scaling.py) to the
-    official bench line. Same provenance discipline as the other
-    attached rows: a CACHED prior measurement, stamped stale with its
-    original measured_ts and age, dropped once outside the 48h window.
-    Scaling rows are measured on the virtual CPU mesh by design (ROADMAP
-    platform note: the curve must survive TPU chip outages)."""
-    row = _recent_row(
-        lambda r: (r.get("config") == "weak_scaling"
-                   and isinstance(r.get("sweep"), list)
-                   and r["sweep"]
-                   and isinstance(r.get("chunked_vs_mono"), dict)))
-    if row is None:
-        return record
-    record["weak_scaling"] = {
-        "sweep": [{k: p.get(k) for k in
-                   ("devices", "shape", "steps_per_sec",
-                    "all_to_alls", "all_gathers",
-                    "transpose_exposed_sec", "transpose_overlapped_sec")}
-                  for p in row["sweep"]],
-        "chunks": row.get("chunks"),
-        "chunked_vs_mono": row.get("chunked_vs_mono"),
-        "northstar": row.get("northstar"),
-        "fleet2d": row.get("fleet2d"),
-        "backend": row.get("backend"),
-        "stale": True,
-        "measured_ts": row.get("ts"),
-        "age_s": round(time.time() - row["ts"], 1)
-        if row.get("ts") else None,
-    }
-    return record
-
-
 def main():
-    if os.environ.get("_BENCH_CHILD"):
-        # Re-exec'd measurement child: the parent already validated this env.
-        print(json.dumps(run_benchmark()), flush=True)
-        return
-
-    errors = []
-    mark(f"probing backend JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '')!r}")
-    # One shared env dict: the probe sanitizes JAX_PLATFORMS (and strips
-    # unknown platforms it fails on) IN PLACE, so the measurement child
-    # inherits the working platform list — records never carry a
-    # bogus-platform init error for an entry the probe already routed
-    # around.
-    probe_env = _sanitize_jax_platforms(dict(os.environ))
-    # several cheap probes spread over ~5 minutes: a transiently busy chip
-    # should not forfeit the round (round-2 failure mode: two 240s probes
-    # in one wedged window -> CPU fallback recorded as the official number).
-    # TTL-cached ([bench] PROBE_CACHE_SEC): back-to-back rounds on a
-    # chipless host replay the recorded verdict instead of burning the
-    # ~825s exhausted retry ladder again; live probes append `kind: probe`
-    # history rows so chip-return day is visible in the trajectory.
-    backend, info = _probe_backend_cached(probe_env)
-    ok = backend is not None
-    if not ok:
-        info = f"device probe failed after retries: {info}"
-    else:
-        info = backend
-    if ok:
-        mark(f"backend probe ok: {info}")
-        record, err = _run_child(probe_env, 2400, "default-backend")
-        if record is not None:
-            _attach_progression(record)
-            _log_result(record)
-            print(json.dumps(record), flush=True)
-            return
-        mark(f"default-backend run FAILED: {err}")
-        errors.append(err)
-    else:
-        mark(f"backend probe exhausted retries ({info}); falling back to CPU")
-        errors.append(f"default-backend init failed: {info}")
-
-    # The chip may be unclaimable at round end while the in-round watcher
-    # (benchmarks/tpu_watch_bench.sh) already measured this code on TPU:
-    # report that real measurement as the official number, with explicit
-    # provenance, rather than a CPU number for a TPU framework.
-    watcher = _recent_tpu_row()
-    if watcher is None:
-        # No in-window TPU measurement. If an OLDER one exists, refuse to
-        # fall through silently: record the refusal loudly so the ancient
-        # TPU number can never be mistaken for this round's result — and
-        # the CPU fallback below never masks the staleness.
-        old = _recent_tpu_row(max_age_sec=0)
-        if old is not None and old.get("ts"):
-            age_hours = round((time.time() - old["ts"]) / 3600.0, 2)
-            record = {
-                "metric": f"RB2D_{NX}x{NZ}_IVP_steps_per_sec",
-                "value": 0.0, "unit": "steps/sec", "vs_baseline": 0.0,
-                "measured_ts": old.get("ts"),
-                "age_hours": age_hours,
-            }
-            _refuse_stale(record, errors,
-                          f"newest TPU watcher row is {age_hours}h old "
-                          f"(> 48h window); measured_ts={old['ts']}")
-    if watcher is not None:
-        sps = float(watcher["steps_per_sec"])
-        age_s = round(time.time() - watcher["ts"], 1) \
-            if watcher.get("ts") else None
-        age_hours = round(age_s / 3600.0, 2) if age_s is not None else None
-        reuses, same_round_rerun = _prior_headline_reuses(watcher.get("ts"))
-        headline_reuse = reuses if same_round_rerun else reuses + 1
-        # Re-reported cached measurement: stamped stale with its age AND
-        # its measurement round so it can never pass as a fresh number —
-        # the original measured_ts stays separate from the report-time
-        # `ts` that _append_result stamps, and `round_measured` +
-        # `headline_reuse` record how often this row has already
-        # headlined an official bench line.
-        record = {
-            "metric": f"RB2D_{NX}x{NZ}_IVP_steps_per_sec_"
-                      f"{watcher.get('dtype', 'float32')}_tpu",
-            "value": round(sps, 3),
-            "unit": "steps/sec",
-            "vs_baseline": round(sps / BASELINE_STEPS_PER_SEC, 3),
-            "backend": "tpu",
-            "source": "benchmarks/results.jsonl (in-round TPU watcher "
-                      "sweep; chip unclaimable at round end)",
-            "stale": True,
-            "measured_ts": watcher.get("ts"),
-            "round_measured": time.strftime(
-                "%Y-%m-%dT%H:%M:%SZ", time.gmtime(watcher["ts"]))
-            if watcher.get("ts") else None,
-            "age_s": age_s,
-            "age_hours": age_hours,
-            "headline_reuse": headline_reuse,
-            "error": "; ".join(errors),
-        }
-        # Stale-headline guard: a watcher row may headline ONE round when
-        # the chip is unclaimable; re-reporting it in a later round would
-        # let the same TPU number silently headline a third round — fail
-        # loudly instead. (The >48h window is enforced upstream:
-        # _recent_tpu_row only returns in-window rows, and the
-        # watcher-is-None branch above refuses older ones.) A retry
-        # within the grace window of the newest report is the SAME round
-        # re-running (flaky probe), not a new-round reuse.
-        if reuses >= 1 and not same_round_rerun:
-            _refuse_stale(record, errors,
-                          f"watcher row measured_ts={watcher.get('ts')} "
-                          f"already headlined {reuses} prior round(s)")
-        mark("chip unclaimable now; reporting the in-round watcher TPU "
-             f"measurement ({sps:.1f} steps/s, {age_hours}h old, "
-             f"headline reuse #{headline_reuse})")
-        _attach_progression(record)
-        _log_result(record)
-        print(json.dumps(record), flush=True)
-        return
-
-    # CPU fallback in a fresh subprocess (this process may have a half-wedged
-    # plugin registered; a clean interpreter with JAX_PLATFORMS=cpu is safer).
-    env = _strip_plugin_env(os.environ)
-    mark("probing CPU fallback")
-    ok, info = probe_backend(env, timeout=120)
-    if ok:
-        record, err = _run_child(env, 1800, "cpu-fallback")
-        if record is not None:
-            record["error"] = "; ".join(errors)
-            _attach_progression(record)
-            _log_result(record)
-            print(json.dumps(record), flush=True)
-            return
-        errors.append(err)
-    else:
-        errors.append(f"cpu fallback probe failed: {info}")
-    failure = {
-        "metric": f"RB2D_{NX}x{NZ}_IVP_steps_per_sec",
-        "value": 0.0, "unit": "steps/sec", "vs_baseline": 0.0,
-        "error": "; ".join(errors),
-    }
-    _log_result(failure)
-    print(json.dumps(failure), flush=True)
-    sys.exit(1)
+    import jax
+    devices = jax.devices()
+    platform = devices[0].platform
+    print(f"platform={platform} device_kind={devices[0].device_kind} "
+          f"count={len(devices)}", flush=True)
+    asked_cpu = "cpu" in os.environ.get("JAX_PLATFORMS", "").split(",")
+    if platform != "tpu" and not asked_cpu:
+        mark(f"no TPU: JAX fell back to {platform!r} on its own; not "
+             "measuring")
+        sys.exit(1)
+    record = run_benchmark()
+    record.update(platform=platform, device_kind=devices[0].device_kind,
+                  device_count=len(devices))
+    _append_result({"config": f"rb{NX}x{NZ}_bench", **record})
+    print(json.dumps(record), flush=True)
 
 
 if __name__ == "__main__":

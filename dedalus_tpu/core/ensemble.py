@@ -496,9 +496,13 @@ class EnsembleSolver:
         from ..libraries import pencilops
 
         def with_contexts(*args):
-            with pencilops.pencil_mesh(self.mesh, self.pencil_axis), \
+            # traced INSIDE the member-manual shard_map: nested shard_maps
+            # must name the context mesh (member axis Manual, pencil axis
+            # Auto), not the concrete all-Auto `self.mesh`
+            mesh = jax.sharding.get_abstract_mesh()
+            with pencilops.pencil_mesh(mesh, self.pencil_axis), \
                     field_mod.mesh_transforms(
-                        self.mesh,
+                        mesh,
                         chunks=getattr(self.solver, "_transpose_chunks",
                                        None)):
                 return fn(*args)
@@ -521,8 +525,8 @@ class EnsembleSolver:
                              for a, b in zip(args, batched_flags))
             if self.pencil_axis is not None:
                 fn = shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                               out_specs=P(MEMBER_AXIS), check_rep=False,
-                               auto=frozenset({self.pencil_axis}))
+                               out_specs=P(MEMBER_AXIS), check_vma=False,
+                               axis_names=frozenset({MEMBER_AXIS}))
             else:
                 fn = shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                                out_specs=P(MEMBER_AXIS))
@@ -615,9 +619,12 @@ class EnsembleSolver:
         from .subsystems import gather_state, scatter_state, state_key
         solver = self.solver
         layout, variables = solver.layout, solver.variables
-        mesh, pencil = self.mesh, self.pencil_axis
+        pencil = self.pencil_axis
 
         def project(X):
+            # the context mesh of the enclosing member-manual shard_map
+            # (see _pencil_contexts)
+            mesh = jax.sharding.get_abstract_mesh()
             arrays = scatter_state(layout, variables, X)
             out = {}
             for v in variables:

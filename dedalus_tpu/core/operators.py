@@ -1601,8 +1601,7 @@ class GeneralFunction(Future):
         if self.pure:
             return self.func(*arg_data)
         # Outside a trace, call the host function directly: no callback
-        # machinery needed, and backends without host send/recv support
-        # (e.g. tunneled PJRT plugins) stay usable via eager evaluation.
+        # machinery needed.
         if not _tracing_active() and \
                 not any(isinstance(a, jax.core.Tracer) for a in arg_data):
             return jnp.asarray(self.func(*[np.asarray(a) for a in arg_data]))

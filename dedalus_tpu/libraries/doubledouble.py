@@ -226,7 +226,11 @@ def _exponent_scale(mag):
     Lines whose magnitude exceeds the f32-representable scale range
     (|v| >= 2^125, where the needed s would clip) poison to NaN so a
     blown-up state reads as non-finite instead of int8-wrapped garbage."""
-    _, e = jnp.frexp(mag)
+    # exponent read from the f32 rounding of mag: frexp of an f64 is a
+    # 64-bit bitcast, which the TPU's x64 rewriter does not implement.
+    # Round-to-nearest keeps mag <= 2^e (a mag that rounds up to 2^e reads
+    # one exponent higher — one slice bit lost, the scale still exact)
+    _, e = jnp.frexp(mag.astype(_F32))
     s = _exact_pow2(-(e + 1)).astype(_F64)
     s = jnp.where(mag >= 2.0 ** 125, jnp.float64(np.nan), s)  # dedalus-lint: disable=DTL004
     return jnp.where(mag > 0, s, jnp.float64(1.0))  # dedalus-lint: disable=DTL004

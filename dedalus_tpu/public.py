@@ -3,16 +3,6 @@ User-facing API: `import dedalus_tpu.public as d3`
 (reference: dedalus/public.py:4-14).
 """
 
-import os as _os
-
-if _os.environ.get("DEDALUS_PLATFORM"):
-    # Authoritative backend selection for user scripts: some environments
-    # force a platform at interpreter start (a PJRT-plugin sitecustomize
-    # overrides JAX_PLATFORMS), and probing an unreachable accelerator can
-    # hang; DEDALUS_PLATFORM=cpu pins the backend before any jax use.
-    import jax as _jax
-    _jax.config.update("jax_platforms", _os.environ["DEDALUS_PLATFORM"])
-
 from .core.coords import (Coordinate, CartesianCoordinates, DirectProduct,
                           PolarCoordinates, S2Coordinates,
                           SphericalCoordinates)

@@ -1,46 +1,11 @@
 """
-Version-compat shims for JAX API drift.
+The one spelling of `shard_map` every in-repo use routes through.
 
-The sharding machinery targets the stable `jax.shard_map` entry point
-(promoted out of `jax.experimental` in recent JAX), but deployed runtimes
-span several majors: older installs only ship
-`jax.experimental.shard_map.shard_map`. Every in-repo use routes through
-`shard_map` exported here, so the parallel/collectives suite runs on
-whichever spelling the installed JAX provides instead of failing on the
-8-device virtual CPU mesh (ROADMAP item 4).
-
-Resolution order (first hit wins):
-  1. `jax.shard_map`                          — current public API
-  2. `jax.experimental.shard_map.shard_map`   — the pre-promotion home
-
-Both spellings share the keyword signature used here
-(`mesh=`, `in_specs=`, `out_specs=`), so the shim is a plain re-export,
-not an adapter.
+The installed JAX ships the stable `jax.shard_map` (keywords `mesh=`,
+`in_specs=`, `out_specs=`, `check_vma=`, `axis_names=` — the set of
+MANUAL axes); this module re-exports it so call sites share one import.
 """
 
-import jax
+from jax import shard_map
 
 __all__ = ["shard_map"]
-
-
-def _resolve_shard_map():
-    # getattr (not hasattr+attribute) so jax's module-level deprecation
-    # __getattr__ machinery is honored: an accelerated removal raises
-    # AttributeError and falls through to the experimental spelling.
-    try:
-        sm = getattr(jax, "shard_map")
-        if sm is not None:
-            return sm
-    except AttributeError:
-        pass
-    try:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm
-    except ImportError as exc:
-        raise ImportError(
-            "dedalus_tpu requires a JAX with shard_map (either "
-            "jax.shard_map or jax.experimental.shard_map.shard_map); "
-            f"neither is available in jax {jax.__version__}") from exc
-
-
-shard_map = _resolve_shard_map()

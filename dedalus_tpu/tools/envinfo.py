@@ -12,12 +12,11 @@ moves, `env` says whether the machine changed under it.
 Two hard rules, both load-bearing:
 
 * **Never initialize the JAX backend.** `jax.devices()` /
-  `jax.default_backend()` would spin up the platform as a side effect,
-  and the bench parent process deliberately stays uninitialized (its
-  wedge defense: a hung TPU runtime must wedge a probed subprocess, not
-  the driver). Backend fields are reported only when the backend is
-  ALREADY live in this process, detected through a guarded private
-  check; otherwise they are null — absence is explicit, never forced.
+  `jax.default_backend()` would spin up the platform as a side effect
+  and claim the chip, which belongs to one process at a time. Backend
+  fields are reported only when the backend is ALREADY live in this
+  process, detected through a guarded private check; otherwise they are
+  null — absence is explicit, never forced.
 * **Every field degrades independently.** A missing /proc, an
   unimportable jaxlib, or a renamed private attribute nulls that one
   field; the fingerprint itself always comes back.

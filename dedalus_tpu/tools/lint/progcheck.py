@@ -175,14 +175,16 @@ def _walk_jaxprs(jaxpr, visit, in_auto=False):
     """Depth-first over a (Closed)Jaxpr and every sub-jaxpr reachable
     through eqn params (pjit bodies, scan/while bodies, cond branches,
     custom_vjp calls, shard_map regions). `visit(eqn, in_auto)` sees each
-    equation with whether it sits inside a shard_map region whose `auto`
-    set is nonempty (the partially-manual GSPMD region)."""
+    equation with whether it sits inside a shard_map region that leaves
+    some mesh axis out of `manual_axes` (the partially-manual GSPMD
+    region)."""
     inner = getattr(jaxpr, "jaxpr", jaxpr)
     for eqn in inner.eqns:
         visit(eqn, in_auto)
         sub_auto = in_auto
         if eqn.primitive.name == "shard_map":
-            sub_auto = bool(eqn.params.get("auto"))
+            sub_auto = bool(set(eqn.params["mesh"].axis_names)
+                            - set(eqn.params["manual_axes"]))
         for val in eqn.params.values():
             items = val if isinstance(val, (list, tuple)) else [val]
             for item in items:

@@ -68,11 +68,9 @@ attributable. `kind: ledger` rows (tools/lint/progcheck.py cost tier,
 `lint --programs --ledger`) carry per-census-program compile-time
 resource costs — flops, transcendentals, bytes accessed,
 argument/output/temp/peak memory, HLO instruction count, scan depths —
-plus the resolved-plan provenance block. `kind: probe` rows record TPU
-backend-probe verdicts (bench.py) for TTL replay ([bench]
-PROBE_CACHE_SEC). `python -m dedalus_tpu perfwatch` reads the whole
-file as a perf trajectory and flags noise-band regressions per series
-(docs/observability.md).
+plus the resolved-plan provenance block. `python -m dedalus_tpu
+perfwatch` reads the whole file as a perf trajectory and flags
+noise-band regressions per series (docs/observability.md).
 """
 
 import atexit

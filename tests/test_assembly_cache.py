@@ -212,3 +212,42 @@ def test_build_phases_in_telemetry(cache_dir):
         assert key in phases
     assert phases["compile_sec"] > 0.0
     assert phases["assembly_cache"] in ("hit", "miss")
+
+
+# ------------------------------------------- XLA compile cache placement
+# (the sibling cache: [compilation] CACHE_DIR, dedalus_tpu/__init__.py)
+
+def _xla_cache_dir(env_overrides, cwd):
+    """jax.config.jax_compilation_cache_dir after `import dedalus_tpu` in
+    a fresh process started from `cwd`."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.update(env_overrides)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = repo
+    code = ("import dedalus_tpu, jax; "
+            "print('DIR=' + str(jax.config.jax_compilation_cache_dir))")
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=str(cwd),
+                         stdout=subprocess.PIPE, text=True, timeout=300)
+    assert out.returncode == 0, out.stdout
+    (line,) = [ln for ln in out.stdout.splitlines() if ln.startswith("DIR=")]
+    return line[len("DIR="):]
+
+
+def test_xla_cache_dir_from_environment_is_left_to_jax(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: JAX reads it itself and the package
+    sets no directory in code, so the variable's value is what holds."""
+    assert _xla_cache_dir({"JAX_COMPILATION_CACHE_DIR": "/x"},
+                          tmp_path) == "/x"
+
+
+def test_xla_cache_dir_default_is_one_fixed_path_in_the_checkout(tmp_path):
+    """Unset: one fixed directory inside the checkout — never under ~, a
+    temporary name, a pid or a time (the path is part of the cache key) —
+    identical across two processes with different HOMEs and cwds."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    (tmp_path / "a").mkdir()
+    (tmp_path / "b").mkdir()
+    first = _xla_cache_dir({"HOME": str(tmp_path / "a")}, tmp_path / "a")
+    second = _xla_cache_dir({"HOME": str(tmp_path / "b")}, tmp_path / "b")
+    assert first == second == os.path.join(repo, ".cache", "xla")

@@ -112,7 +112,9 @@ def test_candidate_grid_reference_first_and_pallas_gating():
     assert "skipped" in pallas          # cpu cannot lower it natively
     (tpu_pallas,) = [c for c in autotune.candidate_cells(backend="tpu")
                      if c.get("pallas")]
-    assert "skipped" not in tpu_pallas  # first-class candidate on tpu
+    # the TPU compiler refuses the kernel today (ROADMAP D4): skipped
+    # with its reason everywhere until a backend lists it again
+    assert "skipped" in tpu_pallas
 
 
 def test_pick_winner_accuracy_bar_beats_speed():

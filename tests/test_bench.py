@@ -1,238 +1,20 @@
 """
-bench.py provenance-window plumbing: every results.jsonl probe
-(`_recent_tpu_row`, `_recent_ensemble_row`, `_recent_serving_row`, and
-the attach helpers behind them) shares ONE measurement window —
-`[bench] STALE_WINDOW_SEC` through `_stale_window_sec()` and the single
-`_recent_row` scan loop — so the staleness rules can never drift apart
-helper by helper. Fast, pure-host tests (no JAX import, no benchmark
-runs): bench.py is imported from the repo root and pointed at fixture
-results files.
+The launchers that remain after the bring-up (PR 22): `_append_result`
+stamps every results row with the environment fingerprint, and
+`chip_smoke.py` — rehearsed here on the CPU in a subprocess — runs its
+phases, reports each, and still refuses to say ok for anything but a TPU.
 """
 
-import inspect
 import json
+import os
 import pathlib
+import subprocess
 import sys
-import time
-
-import pytest
 
 REPO = pathlib.Path(__file__).parent.parent
 sys.path.insert(0, str(REPO))
 
-import bench  # noqa: E402
-
-
-@pytest.fixture
-def results(tmp_path, monkeypatch):
-    """Point bench.py's results.jsonl scan at a fixture file; returns a
-    writer that appends rows."""
-    (tmp_path / "benchmarks").mkdir()
-    path = tmp_path / "benchmarks" / "results.jsonl"
-    monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
-
-    def write(*rows):
-        with open(path, "a") as f:
-            for row in rows:
-                f.write(json.dumps(row) + "\n")
-    return write
-
-
-def test_stale_window_is_config_pinned():
-    """The window comes from [bench] STALE_WINDOW_SEC — one knob, not a
-    hardcoded constant per helper."""
-    from dedalus_tpu.tools.config import config
-    assert bench._stale_window_sec() == pytest.approx(
-        float(config.get("bench", "STALE_WINDOW_SEC")))
-    old = config.get("bench", "STALE_WINDOW_SEC")
-    try:
-        config.set("bench", "STALE_WINDOW_SEC", "60")
-        assert bench._stale_window_sec() == 60.0
-    finally:
-        config.set("bench", "STALE_WINDOW_SEC", old)
-
-
-def test_every_probe_defaults_to_the_shared_window():
-    """Pinning: each probe helper takes max_age_sec=None (= the shared
-    config window) — a helper growing its own hardcoded default breaks
-    this."""
-    for fn in (bench._recent_row, bench._recent_tpu_row,
-               bench._recent_ensemble_row, bench._recent_serving_row):
-        sig = inspect.signature(fn)
-        assert "max_age_sec" in sig.parameters, fn.__name__
-        assert sig.parameters["max_age_sec"].default is None, fn.__name__
-
-
-def test_recent_row_window_semantics(results):
-    now = time.time()
-    fresh = {"config": "x", "ts": now - 10, "value": "fresh"}
-    stale = {"config": "x", "ts": now - 30 * 86400.0, "value": "stale"}
-    results(fresh, stale)
-    pred = lambda row: row.get("config") == "x"  # noqa: E731
-    # default window: the stale row (outside [bench] STALE_WINDOW_SEC)
-    # is invisible even though it is the LATEST line in the file
-    assert bench._recent_row(pred)["value"] == "fresh"
-    # max_age_sec=0 disables the window (the stale-headline guard's
-    # unfiltered probe): the latest matching line wins
-    assert bench._recent_row(pred, max_age_sec=0)["value"] == "stale"
-    # explicit narrow window drops both
-    assert bench._recent_row(pred, max_age_sec=5) is None
-    # rows without ts never match (no provenance, no reuse)
-    results({"config": "y", "value": "no-ts"})
-    assert bench._recent_row(lambda r: r.get("config") == "y",
-                             max_age_sec=0) is None
-
-
-def test_recent_row_missing_file_and_junk(results):
-    assert bench._recent_row(lambda row: True) is None  # no file yet
-    with open(pathlib.Path(bench.__file__).parent / "benchmarks"
-              / "results.jsonl", "w") as f:
-        f.write("not json\n")
-    results({"config": "z", "ts": time.time()})
-    assert bench._recent_row(
-        lambda row: row.get("config") == "z") is not None
-
-
-def test_probe_helpers_share_the_scan(results):
-    """The typed probes route through _recent_row with their own
-    predicates: in-window rows of the right shape are found, out-of-
-    window twins are not."""
-    now = time.time()
-    results(
-        {"config": "rb256x64", "backend": "tpu", "finite": True,
-         "steps_per_sec": 5.0, "ts": now - 20},
-        {"config": "diffusion64_ensemble", "sweep": [{"members": 64}],
-         "speedup_n64": 30.0, "ts": now - 20},
-        # a stale serving row: must be invisible under the default window
-        {"config": "rb256x64_serving", "ttfs_speedup": 12.0,
-         "bit_identical_cold_warm": True, "ts": now - 30 * 86400.0},
-    )
-    assert bench._recent_tpu_row()["steps_per_sec"] == 5.0
-    assert bench._recent_ensemble_row(
-        "diffusion64_ensemble")["speedup_n64"] == 30.0
-    assert bench._recent_serving_row("rb256x64_serving") is None
-    assert bench._recent_serving_row("rb256x64_serving",
-                                     max_age_sec=0) is not None
-
-
-# ---------------------------------------------------- probe TTL cache
-
 import __graft_entry__ as graft  # noqa: E402
-
-
-@pytest.fixture
-def probe_log(tmp_path):
-    """A results.jsonl fixture path plus a writer; tests pass the path
-    explicitly (results_path=...) so the real trajectory is untouched."""
-    path = tmp_path / "results.jsonl"
-
-    def write(*rows):
-        with open(path, "a") as f:
-            for row in rows:
-                f.write(json.dumps(row) + "\n")
-    return path, write
-
-
-@pytest.fixture
-def no_live_probe(monkeypatch):
-    """Fails the test if the cached path falls through to a live probe;
-    the returned setter swaps in a canned live verdict instead."""
-    def boom(env, timeouts=None, spacing=45):
-        raise AssertionError("live probe ran despite a fresh cached row")
-    monkeypatch.setattr(graft, "_probe_backend_retrying", boom)
-
-    def allow(backend, info, platforms_after=None):
-        def fake(env, timeouts=None, spacing=45):
-            if platforms_after is not None:
-                env["JAX_PLATFORMS"] = platforms_after
-            return backend, info
-        monkeypatch.setattr(graft, "_probe_backend_retrying", fake)
-    return allow
-
-
-def test_probe_cache_replays_ok_verdict(probe_log, no_live_probe):
-    path, write = probe_log
-    write({"kind": "probe", "config": "backend_probe", "ok": True,
-           "backend": "tpu", "devices": 4, "info": None,
-           "platforms": "tpu,cpu", "platforms_after": "tpu,cpu",
-           "ts": time.time() - 60})
-    env = {"JAX_PLATFORMS": "tpu,cpu"}
-    backend, devices = graft._probe_backend_cached(env, results_path=path)
-    assert (backend, devices) == ("tpu", 4)
-    assert env["JAX_PLATFORMS"] == "tpu,cpu"
-    # a cache replay appends nothing — only LIVE probes make history
-    assert len(path.read_text().splitlines()) == 1
-
-
-def test_probe_cache_replays_failure_and_platform_fallback(
-        probe_log, no_live_probe):
-    """A recorded failed probe that settled JAX_PLATFORMS onto the CPU
-    fallback replays BOTH the verdict and the env mutation."""
-    path, write = probe_log
-    write({"kind": "probe", "config": "backend_probe", "ok": False,
-           "backend": None, "devices": None,
-           "info": "device probe timed out after 90s",
-           "platforms": "tpu,cpu", "platforms_after": None,
-           "ts": time.time() - 60})
-    env = {"JAX_PLATFORMS": "tpu,cpu"}
-    backend, info = graft._probe_backend_cached(env, results_path=path)
-    assert backend is None
-    assert "cached probe failure" in info and "timed out" in info
-    assert "JAX_PLATFORMS" not in env        # replayed the fallback pop
-
-
-def test_probe_cache_ttl_expiry_probes_live(probe_log, no_live_probe):
-    path, write = probe_log
-    write({"kind": "probe", "config": "backend_probe", "ok": True,
-           "backend": "tpu", "devices": 4, "platforms": None,
-           "platforms_after": None, "ts": time.time() - 3600})
-    no_live_probe("cpu", 1)
-    backend, devices = graft._probe_backend_cached(
-        {}, cache_sec=900, results_path=path)
-    assert (backend, devices) == ("cpu", 1)
-    rows = [json.loads(l) for l in path.read_text().splitlines()]
-    assert len(rows) == 2                    # the live probe wrote history
-    assert rows[-1]["ok"] is True and rows[-1]["backend"] == "cpu"
-    assert rows[-1]["wall_sec"] >= 0
-    assert "env" in rows[-1]                 # fingerprint-stamped
-
-
-def test_probe_cache_platforms_mismatch_probes_live(
-        probe_log, no_live_probe):
-    """A verdict recorded for a different requested JAX_PLATFORMS never
-    answers for this one."""
-    path, write = probe_log
-    write({"kind": "probe", "config": "backend_probe", "ok": True,
-           "backend": "tpu", "devices": 4, "platforms": "tpu,cpu",
-           "platforms_after": "tpu,cpu", "ts": time.time() - 10})
-    no_live_probe("cpu", 1)
-    backend, _ = graft._probe_backend_cached(
-        {"JAX_PLATFORMS": "cpu"}, results_path=path)
-    assert backend == "cpu"
-    assert len(path.read_text().splitlines()) == 2
-
-
-def test_probe_cache_zero_ttl_disables(probe_log, no_live_probe):
-    path, write = probe_log
-    write({"kind": "probe", "config": "backend_probe", "ok": True,
-           "backend": "tpu", "devices": 4, "platforms": None,
-           "platforms_after": None, "ts": time.time() - 1})
-    no_live_probe("cpu", 1)
-    backend, _ = graft._probe_backend_cached(
-        {}, cache_sec=0, results_path=path)
-    assert backend == "cpu"                  # fresh row ignored: TTL off
-
-
-def test_probe_cache_ttl_is_config_pinned():
-    from dedalus_tpu.tools.config import config
-    assert graft._probe_cache_sec() == pytest.approx(
-        float(config.get("bench", "PROBE_CACHE_SEC")))
-    old = config.get("bench", "PROBE_CACHE_SEC")
-    try:
-        config.set("bench", "PROBE_CACHE_SEC", "60")
-        assert graft._probe_cache_sec() == 60.0
-    finally:
-        config.set("bench", "PROBE_CACHE_SEC", old)
 
 
 def test_append_result_stamps_env_fingerprint(tmp_path):
@@ -250,3 +32,36 @@ def test_append_result_stamps_env_fingerprint(tmp_path):
                          path=path)
     row2 = json.loads(path.read_text().splitlines()[1])
     assert row2["env"] == {"canned": True}
+
+
+def test_chip_smoke_cpu_rehearsal_passes_phases_but_never_says_ok():
+    """`chip_smoke.py --nx 64 --nz 16` under JAX_PLATFORMS=cpu: the main
+    phases report a pass, the exit code is non-zero, and no line says
+    `"ok": true` — a CPU run can never stand in for the chip."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "chip_smoke.py"), "--nx", "64",
+         "--nz", "16"], env=env, cwd=str(REPO), capture_output=True,
+        text=True, timeout=600)
+    lines = [json.loads(line) for line in proc.stdout.splitlines()
+             if line.startswith("{")]
+    phases = {rec["phase"]: rec for rec in lines if "phase" in rec}
+    for name in ("rb_f32", "rb_f32_banded"):
+        assert phases[name]["passed"] is True, phases[name]
+    assert proc.returncode != 0
+    assert not any(rec.get("ok") for rec in lines)
+    assert lines[-1] == {"ok": False, "device": lines[-1]["device"]}
+    assert lines[-1]["device"]["platform"] == "cpu"
+    assert proc.stdout.rstrip().splitlines()[-1].startswith('{"ok": false')
+
+
+def test_chip_smoke_refuses_a_cpu_at_the_published_size():
+    """No arguments on a CPU (how the driver's sandbox check runs it):
+    exits non-zero at once, with no result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, str(REPO / "chip_smoke.py")],
+                          env=env, cwd=str(REPO), capture_output=True,
+                          text=True, timeout=300)
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout

@@ -1,0 +1,169 @@
+"""
+Ask the chip's compiler before the chip: the IVP main path at its
+published size (Rayleigh-Benard 256x64, RK222, f32) compiled for a
+DESCRIBED TPU v5e — no device attached, nothing runs, no chip time.
+
+What this guards (and a CPU run cannot): the TPU branch of the step —
+`BatchedInverse` solves and fused transforms, chosen by
+`jax.default_backend() == "tpu"` at build time — is a different program
+from the one every other test compiles. The build here steers that
+choice from inside the test (the backend name is monkeypatched for this
+module only), then hands the jitted step/factor/scan programs shapes
+placed on the described device. A refusal by the TPU compiler (tiling,
+memory, an f64 op in an f32 program, a gather in the sharded step)
+fails here instead of on the chip.
+
+The topology is described inside a module-scoped fixture — never at
+import: only one process at a time may load the TPU library, and every
+xdist worker imports every test file. The compile cache is off around
+the compiles (an entry written for a described chip cannot be read back
+without one). A compile that passes is not a chip run: `chip_smoke.py`
+is that.
+"""
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+import dedalus_tpu.public as d3  # noqa: F401  (solver stack ready)
+from dedalus_tpu.extras.bench_problems import build_rb_solver
+from dedalus_tpu.tools.lint.progcheck import collective_counts
+
+NX, NZ = 256, 64
+SCAN_STEPS = 50
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    mp = pytest.MonkeyPatch()
+    mp.setenv("TPU_LOG_DIR", "disabled")   # else the compiler logs to /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        mp.undo()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # every build and compile of this module sees the TPU branch
+    mp.setattr(jax, "default_backend", lambda: "tpu")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+    mp.undo()
+
+
+def _programs(solver, place):
+    """{name: (lifted program, abstract args)} of one built RK solver:
+    every runtime argument — the lifted matrices included — as a
+    ShapeDtypeStruct placed by `place(leaf)`. The factor's output shapes
+    come from eval_shape, so no inverse is ever computed on the CPU."""
+    ts = solver.timestepper
+    rd = solver.real_dtype
+    tree = lambda t: jax.tree.map(          # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=place(a)), t)
+    scalar = jax.ShapeDtypeStruct((), rd, sharding=place(np.zeros(())))
+    M, L, X = tree(solver.M_mat), tree(solver.L_mat), tree(solver.X)
+    extra = tree(solver.rhs_extra())
+    aux = tree(jax.eval_shape(ts._factor, solver.M_mat, solver.L_mat,
+                              jnp.asarray(0.01, dtype=rd)))
+    return {
+        "step": (ts._step, (M, L, X, scalar, scalar, extra, aux)),
+        "factor": (ts._factor_uniq, (M, L, scalar)),
+        "step_many": (ts._step_n, (M, L, X, scalar, scalar, extra, aux,
+                                   SCAN_STEPS)),
+    }
+
+
+@pytest.fixture(scope="module")
+def rb_programs(topo):
+    """One host build per matsolver (cheap on the CPU: the factor is
+    lazy), both under the TPU backend name."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    built = {}
+    for matsolver, ops_name in ((None, "DenseOps"), ("banded", "BandedOps")):
+        solver, _ = build_rb_solver(NX, NZ, np.float32, matsolver=matsolver)
+        assert type(solver.ops).__name__ == ops_name
+        built[ops_name] = _programs(solver, lambda a: one_chip)
+    return built
+
+
+def _compile_f32(program, args):
+    compiled = program.lower(*args).compile()
+    text = compiled.as_text()
+    for wide in ("f64[", "c128["):
+        assert wide not in text, f"{wide} in an f32 program for the TPU"
+    print(compiled.memory_analysis())
+    return compiled, text
+
+
+@pytest.mark.parametrize("program", ["step", "factor", "step_many"])
+@pytest.mark.parametrize("ops", ["DenseOps", "BandedOps"])
+def test_rb_program_compiles_for_v5e(rb_programs, ops, program):
+    compiled, _ = _compile_f32(*rb_programs[ops][program])
+    mem = compiled.memory_analysis()
+    # one program's arguments + temporaries must fit one v5e chip (16 GB)
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 16e9
+
+
+def test_dense_step_took_the_tpu_branch(rb_programs):
+    """The steered build really is the TPU one: the factor is the batched
+    inverse (one (G, S, S) matrix per stage), not LU factors + pivots."""
+    _, args = rb_programs["DenseOps"]["step"]
+    G, S = args[2].shape
+    lhs_aux = args[-1]
+    for leaf in jax.tree.leaves(lhs_aux):
+        assert leaf.shape == (G, S, S) and leaf.dtype == np.float32
+
+
+def test_sharded_step_compiles_for_four_v5e_chips(topo):
+    """The distributed step on a Mesh of the four described chips:
+    pencils move by all-to-all, never by a full-state all-gather — the
+    tests/test_collectives.py assertion, asked of the TPU compiler."""
+    mesh = Mesh(np.array(topo.devices), ("x",))
+    solver, _ = build_rb_solver(NX, NZ, np.float32)
+    # what parallel.distribute_solver records, minus the device_put a
+    # described device cannot take: the step bodies read the mesh at
+    # trace time (timesteppers._mesh_pin, field.mesh_transforms)
+    solver.dist.mesh = mesh
+    G = solver.pencil_shape[0]
+
+    def place(a):
+        lead = np.ndim(a) and np.shape(a)[0] == G
+        return NamedSharding(mesh, P("x") if lead else P())
+
+    program, args = _programs(solver, place)["step"]
+    compiled, text = _compile_f32(program, args)
+    counts = collective_counts(text)
+    assert counts["all-to-all"] >= 2, f"transposes missing: {counts}"
+    assert counts["all-gather"] == 0, f"full-state gathers: {counts}"
+
+
+@pytest.mark.xfail(strict=True, raises=ValueError,
+                   reason="TPU lowering refuses pallas_substitution: 'the "
+                          "last two dimensions of your block shape are "
+                          "divisible by 8 and 128 respectively, or be equal "
+                          "to the respective dimensions of the overall "
+                          "array' — block shape (1, 528), array shape "
+                          "(128, 528) (ROADMAP D4)")
+def test_pallas_substitution_compiles_for_v5e(topo):
+    """Banded RB shapes (G=128 groups, q=16, NB=33 block rows, f32).
+    Turns green the day the kernel is repaired — or goes with it."""
+    from dedalus_tpu.core.fusedstep import pallas_substitution
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    G, q, NB = 128, 16, 33
+    sds = lambda *shape: jax.ShapeDtypeStruct(   # noqa: E731
+        shape, jnp.float32, sharding=one_chip)
+    fsub = {"FwdOp": sds(NB - 1, G, 4 * q * q),
+            "BwdOp": sds(NB - 1, G, 3 * q * q),
+            "lastOp": sds(G, q, q)}
+    jax.jit(lambda f, fp: pallas_substitution(f, fp, q)).lower(
+        fsub, sds(G, NB * q)).compile()

@@ -201,7 +201,8 @@ def test_seeded_pad_in_auto_region():
         return zeropad(block, ((0, 0), (1, 1)))[:, 1:-1] * 2.0
 
     def wrap(body, auto):
-        kw = {"check_rep": False, "auto": frozenset({"b"})} if auto else {}
+        kw = ({"check_vma": False, "axis_names": frozenset({"a"})}
+              if auto else {})
         return partial(shard_map, mesh=mesh, in_specs=P("a"),
                        out_specs=P("a"), **kw)(body)
 
