@@ -19,6 +19,7 @@ import numpy as np
 
 from .field import Field
 from .future import Future
+from ..tools import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -46,8 +47,12 @@ class Evaluator:
         due = [h for h in self.handlers
                if h.check_schedule(iteration=iteration, wall_time=wall_time,
                                    sim_time=sim_time)]
-        self.evaluate_handlers(due, iteration=iteration, wall_time=wall_time,
-                               sim_time=sim_time, timestep=timestep)
+        if not due:
+            return
+        with tracing.span("step/handlers", {"n_due": len(due)}):
+            self.evaluate_handlers(due, iteration=iteration,
+                                   wall_time=wall_time, sim_time=sim_time,
+                                   timestep=timestep)
 
     def evaluate_handlers(self, handlers=None, iteration=0, wall_time=0.0,
                           sim_time=0.0, timestep=None, **kw):
@@ -200,8 +205,16 @@ class Handler:
         if cache is None or cache["key"] != key:
             cache = self._task_cache = {"key": key,
                                         "runner": self._compile_tasks()}
-        arrays = cache["runner"]()
+        runner = cache["runner"]
+        label = self._span_label()
+        # the task program's inputs (the first read of a field after a
+        # step scatters the state: `state/scatter`) and its launch, or the
+        # eager walk after a fallback
+        with tracing.span("handler/eval", {"handler": label}) as span:
+            arrays = runner()
+            span.set(mode=runner.mode)
         import jax
+        to_global = np.asarray
         if jax.process_count() > 1:
             # multi-process world: device arrays spanning processes are
             # gathered collectively to a full copy on every process
@@ -215,8 +228,16 @@ class Handler:
                     return multihost.process_allgather(v)
                 return np.asarray(v)
 
-            return {name: to_global(v) for name, v in arrays.items()}
-        return {name: np.asarray(v) for name, v in arrays.items()}
+        # blocks until the device has produced the results
+        with tracing.span("handler/pull", {"handler": label}) as span:
+            results = {name: to_global(v) for name, v in arrays.items()}
+            span.set(bytes=sum(v.nbytes for v in results.values()))
+        return results
+
+    def _span_label(self):
+        """`<class>:<first task name>`, the `handler` attr of its spans."""
+        first = self.tasks[0]["name"] if self.tasks else ""
+        return f"{type(self).__name__}:{first}"
 
     def process(self, **kw):
         raise NotImplementedError
@@ -322,12 +343,13 @@ class FileHandler(Handler):
                                             wall_time=wall_time,
                                             sim_time=sim_time,
                                             timestep=timestep)
-        if self.io_retry is not None:
-            # transient host/IO faults (flaky disk/NFS) retried with
-            # backoff before they can kill the run (tools/resilience.py)
-            self.io_retry.call(write, label=f"write {self.current_file}")
-        else:
-            write()
+        with tracing.span("handler/write", {"handler": self._span_label()}):
+            if self.io_retry is not None:
+                # transient host/IO faults (flaky disk/NFS) retried with
+                # backoff before they can kill the run (tools/resilience.py)
+                self.io_retry.call(write, label=f"write {self.current_file}")
+            else:
+                write()
 
     def _write_results(self, results, iteration, wall_time, sim_time,
                        timestep):

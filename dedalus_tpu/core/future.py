@@ -38,16 +38,20 @@ class CompiledWithFallback:
         self.fn = lifted_jit(fn)
         self.eager = eager
         self.describe = describe
-        self.jit_ok = True
+        # "compiled" until the permanent fallback, "eager" after
+        self.mode = "compiled"
 
     def __call__(self):
-        if self.jit_ok:
+        if self.mode == "compiled":
             try:
                 return self.fn([f.coeff_data() for f in self.fields])
             except Exception as exc:
-                logger.debug(f"{self.describe}: compiled evaluation failed "
-                             f"({exc!r}); falling back to eager permanently.")
-                self.jit_ok = False
+                # once per instance, and loud: every later call walks the
+                # expression op by op from the host
+                logger.warning(f"{self.describe}: compiled evaluation "
+                               f"failed ({exc!r}); falling back to eager "
+                               "permanently.")
+                self.mode = "eager"
         return self.eager()
 
 

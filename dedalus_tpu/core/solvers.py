@@ -32,6 +32,7 @@ from ..libraries import pencilops
 from ..tools import assembly_cache
 from ..tools import health as health_mod
 from ..tools import metrics as metrics_mod
+from ..tools import tracing
 from ..tools import retrace as retrace_mod
 from ..tools.config import config
 from ..tools.general import is_complex_dtype
@@ -504,7 +505,9 @@ class SolverBase:
         def make_pull(var):
             def pull():
                 if "arrays" not in cache:
-                    cache["arrays"] = scatter_state(layout, variables, X)
+                    # op by op from the host, once per state that is read
+                    with tracing.span("state/scatter"):
+                        cache["arrays"] = scatter_state(layout, variables, X)
                 var.preset_coeff(cache["arrays"][state_key(var)])
             return pull
 
@@ -918,28 +921,17 @@ class InitialValueSolver(SolverBase):
                 self.enforce_hermitian_symmetry()
         first = "compile" not in self.build_phases.seconds
         t_first = time_mod.perf_counter() if first else None
-        with metrics_mod.annotate("dedalus/step"):
+        # the whole host side of one iteration, not only the launch
+        with tracing.span("step", {"iteration": self.iteration}):
             self.timestepper.step(dt)
-        if first:
-            # trace + lower + XLA compile of the step program dominates the
-            # first dispatch; recorded as the cold-start `compile` phase
-            jax.block_until_ready(self.X)
-            self.build_phases.add(
-                "compile", time_mod.perf_counter() - t_first)
-        self.defer_scatter(self.X)
-        self.snapshot_versions()
-        self.problem.sim_time = self.sim_time
-        self.iteration += 1
-        self.dt = dt
-        self._metrics_tick(1)
-        self.health.tick(1)
-        if self._health_error is None:
-            # a poisoned step must not flow into scheduled outputs (no
-            # NaN-filled checkpoint written as a "good" write)
-            self.evaluator.evaluate_scheduled(
-                iteration=self.iteration,
-                wall_time=time_mod.time() - self.start_time,
-                sim_time=self.sim_time, timestep=dt)
+            if first:
+                # trace + lower + XLA compile of the step program dominates
+                # the first dispatch; recorded as the cold-start `compile`
+                # phase
+                jax.block_until_ready(self.X)
+                self.build_phases.add(
+                    "compile", time_mod.perf_counter() - t_first)
+            self._after_advance(1, dt)
 
     def step_many(self, n, dt):
         """
@@ -972,21 +964,28 @@ class InitialValueSolver(SolverBase):
                 self.enforce_hermitian_symmetry()
         first = "compile" not in self.build_phases.seconds
         t_first = time_mod.perf_counter() if first else None
-        with metrics_mod.annotate("dedalus/step_many"):
+        with tracing.span("step_many", {"iteration": self.iteration, "n": n}):
             self.timestepper.step_many(n, dt)
-        if first:
-            jax.block_until_ready(self.X)
-            self.build_phases.add(
-                "compile", time_mod.perf_counter() - t_first)
+            if first:
+                jax.block_until_ready(self.X)
+                self.build_phases.add(
+                    "compile", time_mod.perf_counter() - t_first)
+            self.metrics.inc("step_many_blocks")
+            self._after_advance(n, dt)
+
+    def _after_advance(self, n, dt):
+        """Host bookkeeping after the timestepper advanced n iterations:
+        counters, the cadence-gated probes, scheduled handlers."""
         self.defer_scatter(self.X)
         self.snapshot_versions()
         self.problem.sim_time = self.sim_time
         self.iteration += n
         self.dt = dt
-        self.metrics.inc("step_many_blocks")
         self._metrics_tick(n)
         self.health.tick(n)
         if self._health_error is None:
+            # a poisoned step must not flow into scheduled outputs (no
+            # NaN-filled checkpoint written as a "good" write)
             self.evaluator.evaluate_scheduled(
                 iteration=self.iteration,
                 wall_time=time_mod.time() - self.start_time,
@@ -1045,8 +1044,10 @@ class InitialValueSolver(SolverBase):
         probes = self.timestepper.phase_probes()
         if probes is None:
             return False
-        with metrics_mod.annotate("dedalus/metrics/sample"):
+        # the wait for the steps still queued is theirs, not the sampler's
+        with tracing.span("metrics/drain"):
             jax.block_until_ready(self.X)
+        with tracing.span("metrics/sample"):
             scale = float(getattr(self.timestepper, "stages", 1) or 1)
             proj = self._ensure_project()
             times = {name: m.time_thunk(name, thunk) * s

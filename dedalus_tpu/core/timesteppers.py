@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from ..libraries import pencilops
 from ..tools.jitlift import lifted_jit
 from ..tools.config import config
+from ..tools import tracing
 
 schemes = {}
 
@@ -115,6 +116,21 @@ def _past_times(dt_hist, s):
         acc += dt_hist[j]
         times.append(-acc)
     return times
+
+
+def _ensure_lhs(stepper, key, dt, *coeffs):
+    """Refactor the stepper's LHS for `coeffs` unless `key` is the one it
+    holds: the one place a step refactors, and the `step/factor` span
+    (one per refactorization, live only when something looks)."""
+    if key == stepper._lhs_key:
+        return
+    solver = stepper.solver
+    rd = solver.real_dtype
+    with tracing.span("step/factor", {"dt": float(dt)}):
+        stepper._lhs_key = key
+        stepper._lhs_aux = stepper._factor(
+            solver.M_mat, solver.L_mat,
+            *(jnp.asarray(c, dtype=rd) for c in coeffs))
 
 
 class MultistepIMEX:
@@ -319,11 +335,7 @@ class MultistepIMEX:
             *self.compute_coefficients(self.dt_hist, order))
         key = (round(float(a[0]), 14), round(float(b[0]), 14))
         rd = self.solver.real_dtype
-        if key != self._lhs_key:
-            self._lhs_key = key
-            self._lhs_aux = self._factor(solver.M_mat, solver.L_mat,
-                                         jnp.asarray(a[0], dtype=rd),
-                                         jnp.asarray(b[0], dtype=rd))
+        _ensure_lhs(self, key, dt, a[0], b[0])
         if self._split:
             Fn, MXn, LXn = self._eval_parts(
                 solver.M_mat, solver.L_mat, solver.X,
@@ -368,11 +380,7 @@ class MultistepIMEX:
         rd = solver.real_dtype
         a, b, c = self.compute_coefficients(self.dt_hist, s)
         key = (round(float(a[0]), 14), round(float(b[0]), 14))
-        if key != self._lhs_key:
-            self._lhs_key = key
-            self._lhs_aux = self._factor(solver.M_mat, solver.L_mat,
-                                         jnp.asarray(a[0], dtype=rd),
-                                         jnp.asarray(b[0], dtype=rd))
+        _ensure_lhs(self, key, dt, a[0], b[0])
         X, self.F_hist, self.MX_hist, self.LX_hist = self._advance_n(
             solver.M_mat, solver.L_mat, solver.X,
             jnp.asarray(solver.sim_time, dtype=rd), solver.rhs_extra(),
@@ -698,13 +706,7 @@ class RungeKuttaIMEX:
         self.iteration = 0
 
     def _ensure_factor(self, dt):
-        solver = self.solver
-        key = round(float(dt), 14)
-        if key != self._lhs_key:
-            self._lhs_key = key
-            self._lhs_aux = self._factor(
-                solver.M_mat, solver.L_mat,
-                jnp.asarray(float(dt), dtype=solver.real_dtype))
+        _ensure_lhs(self, round(float(dt), 14), dt, float(dt))
 
     def step(self, dt, wall_time=None):
         solver = self.solver

@@ -6,6 +6,8 @@ Flow diagnostics and adaptive timestep control
 import logging
 import numpy as np
 
+from ..tools import tracing
+
 logger = logging.getLogger(__name__)
 
 
@@ -249,7 +251,9 @@ class CFL:
     def compute_timestep(self):
         iteration = self.solver.iteration
         if iteration % self.cadence == 0:
-            freq_max = self.compute_max_frequency()
+            # the pull of u["g"] and the NumPy reduction
+            with tracing.span("cfl"):
+                freq_max = self.compute_max_frequency()
             self._last_freq_max = float(freq_max)
             if freq_max == 0.0:
                 dt = self.max_dt

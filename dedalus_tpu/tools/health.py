@@ -50,6 +50,7 @@ import numpy as np
 from .config import config
 from .exceptions import SolverHealthError
 from . import metrics as metrics_mod
+from . import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -332,7 +333,7 @@ class HealthMonitor:
         if X is None:
             X = solver.X
         import jax
-        with metrics_mod.annotate("dedalus/health/check"):
+        with tracing.span("health/check"):
             stats = jax.device_get(self._ensure_probe()(X))
         self.checks += 1
         fields = {}
@@ -398,7 +399,7 @@ class HealthMonitor:
         if not leaves:
             return 0
         probe = self._ensure_value_probe()
-        with metrics_mod.annotate(f"dedalus/health/{phase}"):
+        with tracing.span(f"health/{phase}"):
             return int(jax.device_get(probe(leaves)))
 
     def check_values(self, tree, phase="adjoint", context=None):
@@ -423,7 +424,7 @@ class HealthMonitor:
         if not leaves:
             return 0
         probe = self._ensure_value_probe()
-        with metrics_mod.annotate(f"dedalus/health/{phase}"):
+        with tracing.span(f"health/{phase}"):
             bad = int(jax.device_get(probe(leaves)))
         if bad:
             solver = self.solver

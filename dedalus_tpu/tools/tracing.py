@@ -26,13 +26,28 @@ tier composes per request:
       result_send                   record + result frames on the wire
       error                         terminal error frame (code attr)
 
+      step | step_many              one solver iteration (or block), host
+        step/factor                   side: LHS refactorization launch
+        step/handlers                 scheduled handlers that fell due
+          handler/eval                  task program launch (mode attr)
+            state/scatter                 state vector into the fields
+          handler/pull                  results to the host (bytes attr)
+          handler/write                 HDF5 write
+        metrics/drain                 the sampler's wait for queued steps
+        metrics/sample  health/check  the cadence-gated probes
+      cfl                           CFL.compute_timestep when due
+
 Spans are recorded HOST-SIDE ONLY — never inside jit-traced code — so
 tracing changes no compiled program: with tracing disabled the step HLO
 is bit-identical (machine-checked by the progcheck `traced_step` census
 program + DTP107), and with tracing enabled the cost is a few host
-timestamps per request boundary. The `span()` fast path when disabled
-is a shared no-op context manager: zero allocation, zero branches
-inside traced code, nothing registered anywhere.
+timestamps per span. A span is LIVE (`live()`) when the [tracing] switch
+is on OR a `jax.profiler` trace is being captured: whoever starts a
+profiler gets the step-loop spans in the ring and, as `dedalus/<name>`
+rows, on the host plane of the same xplane, with no switch of this
+package to set. The `span()` fast path when nothing looks is a shared
+no-op context manager: zero allocation, zero branches inside traced
+code, nothing registered anywhere.
 
 Cross-thread propagation: the server's reader thread opens the trace,
 the worker thread resumes it (`resume(ctx)` pushes the context onto the
@@ -61,11 +76,13 @@ import threading
 import time
 import uuid
 
+import jax
+
 from .config import config
 from .lint.threadcheck import named_lock
 
 __all__ = ["Span", "LogHistogram", "TraceRecorder", "TraceContext",
-           "enabled", "enable", "disable", "trace_sink", "recorder",
+           "enabled", "live", "enable", "disable", "trace_sink", "recorder",
            "new_trace",
            "span", "resume", "add_span", "current_context",
            "chrome_trace_events", "chrome_trace", "trace_record",
@@ -236,8 +253,19 @@ _enabled = config.getboolean("tracing", "TRACE_DEFAULT", fallback=False)
 _sink = (config.get("tracing", "TRACE_FILE", fallback="").strip() or None)
 
 
+_profiling = jax.profiler.TraceAnnotation.is_enabled
+
+
 def enabled():
+    """The [tracing] switch: request traces are opened, flushed and kept
+    for the operator (the serving code tests this)."""
     return _enabled
+
+
+def live():
+    """Whether a span opened now is recorded: the [tracing] switch is on,
+    or a `jax.profiler` trace is being captured (two flag reads)."""
+    return _enabled or _profiling()
 
 
 def enable(sink=None):
@@ -338,7 +366,8 @@ def _parent_ids(parent):
 
 class _NoopSpan:
     """Shared do-nothing context manager: the `span()` fast path when
-    tracing is disabled (no allocation per call)."""
+    tracing is disabled and no profiler captures (no allocation per
+    call)."""
 
     __slots__ = ()
 
@@ -385,7 +414,6 @@ class _LiveSpan:
         _stack().append((trace_id, self.span_id))
         self._pushed = True
         try:
-            import jax
             self._ann = jax.profiler.TraceAnnotation(
                 f"dedalus/{self.name}", trace_id=trace_id)
             self._ann.__enter__()
@@ -411,7 +439,7 @@ class _LiveSpan:
                     stack.remove((self.trace_id, self.span_id))
                 except ValueError:
                     pass
-        if _enabled:
+        if live():
             recorder().record(Span(self.trace_id, self.span_id,
                                    self._parent, self.name, self._t0, dur,
                                    attrs=self.attrs))
@@ -422,8 +450,9 @@ def span(name, attrs=None, parent=None):
     """Context manager recording one span around the `with` body. Parent
     resolution: explicit `parent` (TraceContext / Span / (trace, span)
     pair) > this thread's innermost open span > a fresh one-span trace.
-    When tracing is off, returns a shared no-op (zero per-call cost)."""
-    if not _enabled:
+    When nothing looks (`live()` false), returns a shared no-op (zero
+    allocation per call)."""
+    if not (_enabled or _profiling()):
         return _NOOP
     return _LiveSpan(name, attrs, parent)
 
