@@ -208,8 +208,8 @@ class Handler:
         runner = cache["runner"]
         label = self._span_label()
         # the task program's inputs (the first read of a field after a
-        # step scatters the state: `state/scatter`) and its launch, or the
-        # eager walk after a fallback
+        # step launches the solver's scatter program: `state/scatter`) and
+        # its own launch, or the eager walk after a fallback
         with tracing.span("handler/eval", {"handler": label}) as span:
             arrays = runner()
             span.set(mode=runner.mode)

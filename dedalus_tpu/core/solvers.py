@@ -465,49 +465,66 @@ class SolverBase:
 
     # ---------------------------------------------------------------- fields
 
-    def gather_fields(self, fields=None):
-        """One jitted program per field set (memoized): eager per-op
-        dispatch of the reshape/transpose chain costs ~0.5 s of every cold
-        start, while a single traced program is one dispatch AND lands in
-        the persistent XLA cache for the next process."""
-        fields = fields or self.variables
-        arrays = {state_key(v): v.coeff_data() for v in fields}
+    def _state_program(self, cache, fields, body):
+        """`body(layout, fields, arg)` — `gather_state` or `scatter_state` —
+        as one jitted program per field set, memoized on the solver under
+        the attribute `cache`: launched op by op from the host, the
+        reshape/transpose/slice chain costs ~0.5 s of every cold start
+        (gather) and was 48 dispatches, 15-27 ms of every handler read on
+        the chip (scatter of RB's 8 variables), while a single traced
+        program is one dispatch AND lands in the persistent XLA cache for
+        the next process."""
         key = tuple(state_key(v) for v in fields)
-        programs = self.__dict__.setdefault("_gather_programs", {})
+        programs = self.__dict__.setdefault(cache, {})
         fn = programs.get(key)
         if fn is None:
             from ..tools.jitlift import lifted_jit
-            layout = self.layout
-            fields = list(fields)
-            # memoized in _gather_programs just above (cache-subscript
-            # guard the static pass cannot see)
+            layout, fields = self.layout, list(fields)
+            # memoized in `programs` just above (cache-subscript guard the
+            # static pass cannot see)
             fn = programs[key] = lifted_jit(  # dedalus-lint: disable=DTL003
-                lambda arrs: gather_state(layout, fields, arrs))
-        return fn(arrays)
+                lambda arg: body(layout, fields, arg))
+        return fn
+
+    def gather_fields(self, fields=None):
+        """The fields' coefficients as the (G, S) state vector, through the
+        memoized gather program."""
+        fields = fields or self.variables
+        arrays = {state_key(v): v.coeff_data() for v in fields}
+        return self._state_program("_gather_programs", fields,
+                                   gather_state)(arrays)
+
+    def _scatter_program(self, fields):
+        """The twin of `gather_fields`' program: X -> {state_key: coeffs}."""
+        return self._state_program("_scatter_programs", fields,
+                                   scatter_state)
 
     def scatter_fields(self, X, fields=None):
-        """Eager scatter: counts as a mutation so a co-resident IVP solver's
-        dirty tracking re-gathers this data."""
+        """Scatter X into the fields now, through the memoized scatter
+        program: counts as a mutation so a co-resident IVP solver's dirty
+        tracking re-gathers this data."""
         fields = fields or self.variables
-        arrays = scatter_state(self.layout, fields, X)
+        arrays = self._scatter_program(fields)(X)
         for v in fields:
             v.preset_coeff(arrays[state_key(v)])
             v.mark_modified()
 
     def defer_scatter(self, X):
         """
-        Install lazy pulls: fields fetch their slice of X only when accessed
-        (keeps the no-IO stepping loop free of per-step scatter work).
+        Install lazy pulls: the first field of this state that is accessed
+        launches the scatter program once, for all variables; a state
+        nobody reads launches nothing (keeps the no-IO stepping loop free
+        of per-step scatter work). X is not donated.
         """
         cache = {}
-        layout, variables = self.layout, self.variables
+        variables = self.variables
 
         def make_pull(var):
             def pull():
                 if "arrays" not in cache:
-                    # op by op from the host, once per state that is read
+                    # one launch per state that is read
                     with tracing.span("state/scatter"):
-                        cache["arrays"] = scatter_state(layout, variables, X)
+                        cache["arrays"] = self._scatter_program(variables)(X)
                 var.preset_coeff(cache["arrays"][state_key(var)])
             return pull
 

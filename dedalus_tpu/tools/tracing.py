@@ -30,7 +30,7 @@ tier composes per request:
         step/factor                   side: LHS refactorization launch
         step/handlers                 scheduled handlers that fell due
           handler/eval                  task program launch (mode attr)
-            state/scatter                 state vector into the fields
+            state/scatter                 one launch: X into the fields
           handler/pull                  results to the host (bytes attr)
           handler/write                 HDF5 write
         metrics/drain                 the sampler's wait for queued steps
