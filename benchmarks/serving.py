@@ -713,10 +713,11 @@ def main():
     if not quick:
         rows.append(run_problem(
             "rb256x64_serving",
-            # the headline RB configuration is the BANDED path (bench.py /
-            # coldstart.py); the default-config dense fallback would make
-            # the first step itself seconds of wall time and measure the
-            # matsolver, not the pool
+            # "banded" is forced here, as in coldstart.py (bench.py passes
+            # nothing: `auto` is dense under 1 GiB of pencil matrices, so
+            # RB 256x64 runs DenseOps there, and banded above); on a CPU
+            # the dense path would make the first step itself seconds of
+            # wall time and measure the matsolver, not the pool
             {"problem": "rayleigh_benard",
              "params": {"Nx": 256, "Nz": 64, "matsolver": "banded"}},
             rb_ics(), dt=0.01, steps=3, warm_runs=WARM_RUNS))

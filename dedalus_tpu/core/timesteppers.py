@@ -19,6 +19,8 @@ transforms -> batched LU solve -> scatter); the LHS factorization
 coefficients change (reference: core/timesteppers.py:123-128,160-168).
 """
 
+import contextlib
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -126,11 +128,19 @@ def _ensure_lhs(stepper, key, dt, *coeffs):
         return
     solver = stepper.solver
     rd = solver.real_dtype
-    with tracing.span("step/factor", {"dt": float(dt)}):
+    # the run's first factorization is cold start: waited for and booked
+    # as the build's `factor` phase (with the upload of M and L), so that
+    # `compile` is what is left of the first step
+    first = stepper._lhs_key is None
+    booked = solver.build_phases.scope("factor") if first \
+        else contextlib.nullcontext()
+    with tracing.span("step/factor", {"dt": float(dt)}), booked:
         stepper._lhs_key = key
         stepper._lhs_aux = stepper._factor(
             solver.M_mat, solver.L_mat,
             *(jnp.asarray(c, dtype=rd) for c in coeffs))
+        if first:
+            jax.block_until_ready(stepper._lhs_aux)  # dedalus-lint: disable=DTL001
 
 
 class MultistepIMEX:

@@ -66,9 +66,11 @@ def build_rb_solver(Nx, Nz, dtype, mesh=None, matsolver=None):
     problem.add_equation("b(z=Lz) = 0")
     problem.add_equation("u(z=Lz) = 0")
     problem.add_equation("integ(p) = 0")
-    # matsolver=None defers to [linear algebra] MATRIX_SOLVER; callers on
-    # the headline banded configuration (bench/coldstart/serving) pass
-    # "banded" explicitly so their numbers do not depend on ambient config
+    # matsolver=None defers to [linear algebra] MATRIX_SOLVER, whose
+    # `auto` keeps dense pencils while their matrices stay under
+    # BANDED_CUTOFF_BYTES (1 GiB: RB 256x64 is 142 MB, so bench.py, which
+    # passes nothing, runs DenseOps) and goes banded above (RB 2048x1024);
+    # coldstart.py and serving.py pass "banded" explicitly
     solver = problem.build_solver(d3.RK222, matsolver=matsolver)
     b.fill_random("g", seed=42, distribution="normal", scale=1e-3)
     b["g"] += (Lz - z)

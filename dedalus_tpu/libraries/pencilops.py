@@ -41,6 +41,7 @@ from .matsolvers import BatchedInverseRefined, get_solver, refined_ladder
 from ..tools.compat import shard_map
 from ..tools.config import config
 from ..tools.array import zeropad
+from ..tools import tracing
 
 
 # ------------------------------------------------------- pencil-mesh routing
@@ -281,6 +282,13 @@ class DenseOps(AdjointSolveOps):
         return np.asarray(host_mat[g])
 
 
+def device_memory_bytes():
+    """Memory of the default device, where the backend reports a limit
+    (a TPU does, the CPU does not: None)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
 @jax.tree_util.register_pytree_node_class
 class BandedMatrix:
     """
@@ -358,6 +366,12 @@ class BandedOps(AdjointSolveOps):
             fusion = resolve_fusion()
         plan = fusion
         self._fused_solve = plan.solve
+        # `auto` (not an explicit `on`) yields to the device's memory at
+        # first factor, when the group count is known (_ensure_q)
+        self._fused_solve_auto = plan.solve and (
+            not config.has_section("fusion")
+            or config["fusion"].get("FUSED_SOLVE", "auto").strip().lower()
+            == "auto")
         self._fused_matvec = plan.matvec
         self._pallas = plan.pallas
         # solve-composition/precision plan (libraries/solvecomp.py):
@@ -443,13 +457,18 @@ class BandedOps(AdjointSolveOps):
             self._blk_idx[o] = (np.where(valid, d, 0), valid)
 
     def _ensure_q(self, G, itemsize):
-        """Finalize the re-blocking once the group count is known (first
-        factor): 'auto' doubles q while the persistent factor slab
+        """What waits for the group count (first factor): the re-blocking,
+        then whether the precomposed operators fit the device."""
+        self._reblock(G, itemsize)
+        self._ensure_fused_fits(G, itemsize)
+
+    def _reblock(self, G, itemsize):
+        """Finalize the re-blocking once the group count is known:
+        'auto' doubles q while the persistent factor slab
         (panelLU + U12, 2 * 2q*q per block row) stays under
         BANDED_Q_BUDGET_GB and q <= 256, on TPU backends only."""
         if self._min_q_cfg != "auto":
             return
-        import jax
         if jax.default_backend() != "tpu":
             return
         budget = float(config["linear algebra"].get(
@@ -465,6 +484,35 @@ class BandedOps(AdjointSolveOps):
             q *= 2
         if q != self.q:
             self._set_q(q)
+
+    def _ensure_fused_fits(self, G, itemsize):
+        """[fusion] FUSED_SOLVE = auto, decided against the device the
+        factors will live on: the precomposed substitution operators are
+        7q^2 numbers per block row (FwdOp 4q^2 + BwdOp 3q^2) against the
+        LAPACK-packed pivoted factors' 4q^2. Where the operators, the two
+        band stores and the Woodbury blocks together pass three quarters of
+        the device's memory, the factors stay packed and the solve is the
+        pivoted substitution. RB 2048x1024 f32 on a 16 GB v5e: 8.6 GB of
+        operators + 4.4 of bands + 1.1 of Woodbury blocks = 14.1 GB
+        resident, and a stage solve's own temporaries are 3.5 GB (compiled
+        for a described v5e, PR 28); packed, 4.5 + 4.4 + 1.1 = 10.0 GB. A
+        quarter of the device is what the step's temporaries take there.
+        An explicit `on`, a restructured composition or the Pallas kernel
+        (both consume the operators) are never overridden; a backend that
+        reports no limit (the CPU) keeps the operators."""
+        if not self._fused_solve_auto or self._composition != "sequential" \
+                or self._pallas:
+            return
+        limit = device_memory_bytes()
+        if not limit:
+            return
+        q = self.q
+        nb = -(-self.n_store // q)
+        bands = 2 * G * self.nd * self.n_store
+        woodbury = 3 * G * self.t * nb * q
+        operators = 7 * G * nb * q * q
+        if (bands + woodbury + operators) * itemsize > 0.75 * limit:
+            self._fused_solve = self._fused_solve_auto = False
 
     # ------------------------------------------------------------ host side
 
@@ -644,6 +692,25 @@ class BandedOps(AdjointSolveOps):
         lu, _, lastP = jax.lax.linalg.lu(A11_f)
         return (perms, panelLU, U12, lastP, lu)
 
+    @staticmethod
+    def _batch_minor(interior):
+        """The packed pivoted factors as they are kept: the step-stacked
+        (steps, G, flat) arrays of `_factor_interior` with the GROUP axis
+        last, (steps, flat, G). The substitution's block operations are
+        batched over groups on (2q x q) blocks far smaller than an MXU
+        tile, and the TPU's layout assignment wants the batch in the
+        lanes for them; handed (steps, G, flat) it re-lays-out the WHOLE
+        stacked store before the scan (RB 2048x1024: `copy` of
+        f32[16,256,64,2048] into {2,3,1,0:T(8,128)}, 4.0 GB with the lane
+        padding, "Used 16.49G of 15.75G hbm": compiled for a described
+        v5e, PR 28). Stored this way the resident layout is the one the
+        scan body reads."""
+        perms, panelLU, U12, lastP, lastLU = interior
+        if panelLU is None:
+            return interior
+        return (jnp.swapaxes(perms, 1, 2), jnp.swapaxes(panelLU, 1, 2),
+                jnp.swapaxes(U12, 1, 2), lastP, lastLU)
+
     def _precompose_subst(self, interior):
         """Precomposed matmul-substitution operators (FUSED_SOLVE,
         core/fusedstep.py). At factor time each panel's unit-lower and
@@ -815,8 +882,9 @@ class BandedOps(AdjointSolveOps):
             yw = op_flat.reshape(G, 2 * q, 2 * q) @ wf
             return yw[:, q:], yw[:, :q].reshape(G, q * k)
 
-        w_f, ys = jax.lax.scan(fwd, fb[0].reshape(G, q, k),
-                               (fb[1:], fsub["FwdOp"]))
+        with jax.named_scope("dedalus/matsolve/banded.fwd"):
+            w_f, ys = jax.lax.scan(fwd, fb[0].reshape(G, q, k),
+                                   (fb[1:], fsub["FwdOp"]))
         x_last = lastOp @ w_f
         zero = jnp.zeros_like(x_last)
 
@@ -827,8 +895,9 @@ class BandedOps(AdjointSolveOps):
             x = op_flat.reshape(G, q, 3 * q) @ z
             return (x, x1), x.reshape(G, q * k)
 
-        _, xs_rev = jax.lax.scan(bwd, (x_last, zero),
-                                 (ys, fsub["BwdOp"]), reverse=True)
+        with jax.named_scope("dedalus/matsolve/banded.bwd"):
+            _, xs_rev = jax.lax.scan(bwd, (x_last, zero),
+                                     (ys, fsub["BwdOp"]), reverse=True)
         x = jnp.concatenate([xs_rev.reshape(NB - 1, G, q, k),
                              x_last[None]], axis=0)
         return jnp.moveaxis(x, 0, 1).reshape(G, self.n_pad, k)
@@ -856,9 +925,11 @@ class BandedOps(AdjointSolveOps):
             return jnp.moveaxis(x[None], 0, 1).reshape(G, self.n_pad, k)
 
         # forward: eliminate with pivots; carry the updated next block
+        # the factors are stored group-minor (_batch_minor)
         def fwd(w_cur, xs):
-            f_next, perm, lu_flat = xs
-            lu_i = lu_flat.reshape(G, 2 * q, q)
+            f_next, perm_t, lu_t = xs
+            perm = perm_t.T
+            lu_i = jnp.moveaxis(lu_t.reshape(2 * q, q, G), 2, 0)
             w = jnp.concatenate([w_cur, f_next.reshape(G, q, k)], axis=1)
             w = jnp.take_along_axis(w, perm[:, :, None], axis=1)  # (G,2q,k)
             L1_i = jnp.tril(lu_i[:, :q, :], -1) + eye_q
@@ -867,8 +938,9 @@ class BandedOps(AdjointSolveOps):
             w_next = w[:, q:] - lu_i[:, q:, :] @ y
             return w_next, y.reshape(G, q * k)
 
-        w_f, ys = jax.lax.scan(fwd, fb[0].reshape(G, q, k),
-                               (fb[1:], perms, panelLU))
+        with jax.named_scope("dedalus/matsolve/banded.fwd"):
+            w_f, ys = jax.lax.scan(fwd, fb[0].reshape(G, q, k),
+                                   (fb[1:], perms, panelLU))
         w = jnp.take_along_axis(w_f, lastP[:, :, None], axis=1)
         x_last = last_solve(w)                                    # (G,q,k)
 
@@ -877,17 +949,18 @@ class BandedOps(AdjointSolveOps):
 
         def bwd(carry, xs):
             x1, x2 = carry                                        # x_{i+1}, x_{i+2}
-            y_flat, lu_flat, U12_flat = xs
+            y_flat, lu_t, U12_t = xs
             y_i = y_flat.reshape(G, q, k)
-            lu_i = lu_flat.reshape(G, 2 * q, q)
-            U12_i = U12_flat.reshape(G, q, 2 * q)
+            lu_i = jnp.moveaxis(lu_t.reshape(2 * q, q, G), 2, 0)
+            U12_i = jnp.moveaxis(U12_t.reshape(q, 2 * q, G), 2, 0)
             rhs = y_i - U12_i @ jnp.concatenate([x1, x2], axis=1)
             x = jsl.solve_triangular(jnp.triu(lu_i[:, :q, :]), rhs,
                                      lower=False)
             return (x, x1), x.reshape(G, q * k)
 
-        _, xs_rev = jax.lax.scan(bwd, (x_last, zero), (ys, panelLU, U12),
-                                 reverse=True)
+        with jax.named_scope("dedalus/matsolve/banded.bwd"):
+            _, xs_rev = jax.lax.scan(bwd, (x_last, zero),
+                                     (ys, panelLU, U12), reverse=True)
         x = jnp.concatenate([xs_rev.reshape(NB - 1, G, q, k),
                              x_last[None]], axis=0)
         return jnp.moveaxis(x, 0, 1).reshape(G, self.n_pad, k)
@@ -911,6 +984,19 @@ class BandedOps(AdjointSolveOps):
         if C <= 1:
             return 1, G
         Gc = -(-G // C)  # rebalance: padding stays below one chunk width
+        # The chunk width is a whole number of TPU tiles of the stacked
+        # factors, so that they are resident in the layout the block-row
+        # scans slice. Precomposed operators are (C, steps, Gc, flat): Gc
+        # is the sublane dim, a multiple of 8 (for Gc = 57 the TPU makes
+        # `steps` the sublane dim instead, {3,1,2,0:T(8,128)}, and every
+        # solve starts by copying the WHOLE store back to {3,2,1,0}). The
+        # packed pivoted factors are (C, steps, flat, Gc) (_batch_minor):
+        # Gc is the lane dim, a multiple of 128 (64 would pad to 128 and
+        # double the store). Compiled for a described v5e, PR 28.
+        tile = 8 if self._fused_solve else 128
+        if G > tile and Gc % tile:
+            Gc = -(-Gc // tile) * tile
+            C = -(-G // Gc)
         return C, Gc
 
     @staticmethod
@@ -938,6 +1024,8 @@ class BandedOps(AdjointSolveOps):
             bands = bands.at[:, self.kl, self.n:].set(tail)
         interior = self._factor_interior(bands)
         fsub = self._precompose_subst(interior) if fused else None
+        if not fused:
+            interior = self._batch_minor(interior)
         if fused:
             # the fused solve consumes only fsub — dropping the pivoted
             # factors here (not just from the host-side aux) keeps the
@@ -954,10 +1042,14 @@ class BandedOps(AdjointSolveOps):
             E = jnp.zeros((G, self.n_pad, self.t), dtype=dtype)
             E = E.at[:, self.pin_pos, jnp.arange(self.t)].set(1.0)
             Yb = self._solve_interior(interior, E, fsub=fsub)     # (G, n_pad, t)
-            # capacitance: I + (Vt - E^T) Y
-            Cap = (jnp.eye(self.t, dtype=dtype)
-                   + jnp.einsum("gtn,gnk->gtk", Vt, Yb)
-                   - Yb[:, self.pin_pos, :])
+            # capacitance I + (Vt - E^T) Y = Vt Y: the pinned rows of B~
+            # are unit rows, so E^T Y = I exactly. Summed as written, the
+            # two identities cancel against Vt Y, whose rows carry the
+            # implicit coefficient b = dt*gamma: at dt = 5e-4 float32 kept
+            # two digits of it (RB 64x32 against float64: 2.8e-2; 3.1e-4
+            # since; CPU, PR 28). For the same reason _solve_core subtracts
+            # the right-hand side's own pin values, which y[pins] equals.
+            Cap = jnp.einsum("gtn,gnk->gtk", Vt, Yb)
             # stored (G, t, n_pad): a trailing dim of t ~ 16 pads 8x under
             # TPU (8, 128) tiling; n_pad-minor tiles cleanly
             YbT = jnp.swapaxes(Yb, 1, 2)
@@ -1126,32 +1218,28 @@ class BandedOps(AdjointSolveOps):
         out_bytes = G * self.NB * (2 * self.q * self.q) * 2 * itemsize
         return out_bytes > thresh
 
-    def factor_lincomb_incremental(self, a, M, L, b_scale):
-        """factor_lincomb(a, M, b, L) as C separate device dispatches: each
-        chunk is combined + factored by a small jitted program whose result
-        is written into donated (C, Gc, ...) stores, so the full-batch scan
-        temps never coexist with the finished factors. Returns the same
-        chunked aux `solve` already consumes. Host-level: call OUTSIDE jit."""
+    def incremental_chunk_program(self, M, L):
+        """The ONE device program of the incremental factorization and what
+        it is fed: `(write, store_shapes, C, Gc)`. `write(store, i, mb, lb,
+        mv, lv, a, b)` combines and factors chunk i's (Gc, ...) slabs of M
+        and L and writes the result into the donated (C, Gc, ...) `store`;
+        `store_shapes` is that store as a pytree of ShapeDtypeStructs. M
+        and L may be abstract (only shapes, dtypes, `dsel` and the
+        presence of `Vt` are read), so the fit check compiles the program
+        for a described chip with no array anywhere
+        (tests/test_chip_compile.py)."""
         import functools
-        if b_scale is None:
-            raise ValueError("factor_lincomb_incremental requires b_scale "
-                             "(the coefficient multiplying L).")
-        b = b_scale
         G = M.bands.shape[0]
         dtype = M.bands.dtype
         self._ensure_q(G, dtype.itemsize)
         C, Gc = self._pick_chunks(G, dtype.itemsize)
-        C = max(C, 2)  # incremental mode implies chunked aux layout
-        Gc = -(-G // C)
-        self._g_chunks = C
+        if C < 2:  # incremental mode implies chunked aux layout
+            C, Gc = 2, -(-G // 2)
         dM = np.asarray(M.dsel)
         dL = np.asarray(L.dsel)
         has_mv = M.Vt is not None
         has_lv = L.Vt is not None
         rd = np.dtype(dtype)
-        a = jnp.asarray(a, dtype=rd)
-        b = jnp.asarray(b, dtype=rd)
-
         ns = self.n_store
 
         def chunk_core(mb, lb, mv, lv, a, b):
@@ -1168,15 +1256,38 @@ class BandedOps(AdjointSolveOps):
             jax.ShapeDtypeStruct((Gc, self.t, ns), dtype)
             if has_lv else None,
             jax.ShapeDtypeStruct((), rd), jax.ShapeDtypeStruct((), rd))
-        store = jax.tree.map(
-            lambda s: jnp.zeros((C,) + s.shape, dtype=s.dtype), shapes)
+        store_shapes = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct((C,) + s.shape, s.dtype), shapes)
 
         @functools.partial(jax.jit, donate_argnums=0)
         def write(store, i, mb, lb, mv, lv, a, b):
-            core = chunk_core(mb, lb, mv, lv, a, b)
-            return jax.tree.map(
-                lambda s, c: jax.lax.dynamic_update_index_in_dim(s, c, i, 0),
-                store, core)
+            with jax.named_scope("dedalus/matsolve/banded.factor"):
+                core = chunk_core(mb, lb, mv, lv, a, b)
+                return jax.tree.map(
+                    lambda s, c: jax.lax.dynamic_update_index_in_dim(
+                        s, c, i, 0),
+                    store, core)
+
+        return write, store_shapes, C, Gc
+
+    def factor_lincomb_incremental(self, a, M, L, b_scale):
+        """factor_lincomb(a, M, b, L) as C separate device dispatches: each
+        chunk is combined + factored by a small jitted program whose result
+        is written into donated (C, Gc, ...) stores, so the full-batch scan
+        temps never coexist with the finished factors. Returns the same
+        chunked aux `solve` already consumes. Host-level: call OUTSIDE jit.
+        Each dispatch is a `factor/chunk` span (attrs `chunk`, `chunks`)."""
+        if b_scale is None:
+            raise ValueError("factor_lincomb_incremental requires b_scale "
+                             "(the coefficient multiplying L).")
+        G = M.bands.shape[0]
+        rd = np.dtype(M.bands.dtype)
+        write, store_shapes, C, Gc = self.incremental_chunk_program(M, L)
+        self._g_chunks = C
+        a = jnp.asarray(a, dtype=rd)
+        b = jnp.asarray(b_scale, dtype=rd)
+        store = jax.tree.map(
+            lambda s: jnp.zeros(s.shape, dtype=s.dtype), store_shapes)
 
         def chunk_of(arr, i):
             if arr is None:
@@ -1189,10 +1300,10 @@ class BandedOps(AdjointSolveOps):
             return sl
 
         for i in range(C):
-            store = write(store, i,
-                          chunk_of(M.bands, i), chunk_of(L.bands, i),
-                          chunk_of(M.Vt, i) if has_mv else None,
-                          chunk_of(L.Vt, i) if has_lv else None, a, b)
+            with tracing.span("factor/chunk", {"chunk": i, "chunks": C}):
+                store = write(store, i,
+                              chunk_of(M.bands, i), chunk_of(L.bands, i),
+                              chunk_of(M.Vt, i), chunk_of(L.Vt, i), a, b)
         jax.block_until_ready(store)
         return self._aux_from_core(store, {"ab": (a, b)})
 
@@ -1224,13 +1335,14 @@ class BandedOps(AdjointSolveOps):
             y = self._solve_interior(auxc.get("interior"), fp[..., None],
                                      fsub=fsub)[..., 0]
         if self.t:
-            Vy = (jnp.einsum("gtn,gn->gt", auxc["Vt"], y)
-                  - y[:, self.pin_pos])
-            if fsub is not None and "CapInv" in fsub:
-                z = jnp.einsum("gij,gj->gi", fsub["CapInv"], Vy)
-            else:
-                z = jsl.lu_solve(auxc["Cap"], Vy)
-            y = y - jnp.einsum("gtn,gt->gn", auxc["YbT"], z)
+            with jax.named_scope("dedalus/matsolve/banded.woodbury"):
+                Vy = (jnp.einsum("gtn,gn->gt", auxc["Vt"], y)
+                      - fp[:, self.pin_pos])
+                if fsub is not None and "CapInv" in fsub:
+                    z = jnp.einsum("gij,gj->gi", fsub["CapInv"], Vy)
+                else:
+                    z = jsl.lu_solve(auxc["Cap"], Vy)
+                y = y - jnp.einsum("gtn,gt->gn", auxc["YbT"], z)
         return y
 
     def _solve_once(self, aux, rhs):
@@ -1315,15 +1427,19 @@ class BandedOps(AdjointSolveOps):
                 # f64 residual matvec against the assembled M/L (never
                 # the low-dtype factors) — the correction solve runs in
                 # the solve dtype, the polish at native precision
-                r = rhs - self._aux_matvec(aux, x, mats)
+                # (`banded.refine` is the sweep's own arithmetic; its
+                # matvecs and correction solve keep their inner scopes)
+                with jax.named_scope("dedalus/matsolve/banded.refine"):
+                    r = rhs - self._aux_matvec(aux, x, mats)
                 dx = self._solve_once(aux, r)
-                if tol > 0.0:
-                    # tolerance-terminated: converged groups freeze
-                    # (masked update — fixed trip count, retrace-free)
-                    rn = jnp.max(jnp.abs(r), axis=1, keepdims=True)
-                    bn = jnp.max(jnp.abs(rhs), axis=1, keepdims=True)
-                    return jnp.where(rn > tol * bn, x + dx, x), None
-                return x + dx, None
+                with jax.named_scope("dedalus/matsolve/banded.refine"):
+                    if tol > 0.0:
+                        # tolerance-terminated: converged groups freeze
+                        # (masked update — fixed trip count, retrace-free)
+                        rn = jnp.max(jnp.abs(r), axis=1, keepdims=True)
+                        bn = jnp.max(jnp.abs(rhs), axis=1, keepdims=True)
+                        return jnp.where(rn > tol * bn, x + dx, x), None
+                    return x + dx, None
 
             x, _ = jax.lax.scan(sweep, x, None, length=sweeps)
             return x

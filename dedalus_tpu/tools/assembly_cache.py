@@ -420,6 +420,16 @@ class AssemblyCache:
         meta["key"] = key
         meta["array_names"] = sorted(arrays)
         path = self._path(key)
+        nbytes = sum(np.asarray(a).nbytes for a in arrays.values())
+        if nbytes > self.max_bytes:
+            # an entry over the whole budget would be written, synced and
+            # evicted at once: RB 2048x1024 wrote 8.4 GB per build that way
+            # (v5e host, PR 28: 52.7 GiB of disk writes in six runs)
+            logger.info(
+                f"assembly cache: entry {str(key)[:12]} of "
+                f"{nbytes / 1e6:.0f} MB passes ASSEMBLY_CACHE_MAX_MB "
+                f"({self.max_bytes / 1e6:.0f}); not stored")
+            return False
         try:
             self.directory.mkdir(parents=True, exist_ok=True)
             fd, tmp = tempfile.mkstemp(dir=str(self.directory),

@@ -166,6 +166,21 @@ def test_corrupted_entry_falls_back_to_fresh(cache_dir):
     assert build_rb().build_phases.cache == "hit"
 
 
+@pytest.mark.parametrize("max_mb, stored", [(1.0, True), (0.5, False)])
+def test_entry_over_the_whole_budget_is_not_written(tmp_path, max_mb,
+                                                    stored):
+    """An entry larger than ASSEMBLY_CACHE_MAX_MB used to be written,
+    synced and evicted at once (RB 2048x1024: 8.4 GB of disk writes per
+    build); it is refused before the first byte."""
+    cache = assembly_cache.AssemblyCache(tmp_path / "asm", max_mb=max_mb)
+    arrays = {"bands": np.zeros(100_000)}              # 0.8 MB
+    assert cache.store("k" * 40, {"kind": "banded"}, arrays) is stored
+    files = list((tmp_path / "asm").glob("*")) \
+        if (tmp_path / "asm").is_dir() else []
+    assert bool(files) == stored
+    assert (cache.load("k" * 40) is not None) == stored
+
+
 def test_key_stability_and_resolve(cache_dir, monkeypatch):
     solver = build_rb()
     key1 = assembly_cache.solver_key(solver, ("M", "L"))

@@ -147,6 +147,91 @@ def test_sharded_step_compiles_for_four_v5e_chips(topo):
     assert counts["all-gather"] == 0, f"full-state gathers: {counts}"
 
 
+# ---- the fit of RB 2048x1024 (chipbench cell rb2048x1024.block10) ----
+#
+# The record of the fit, in place of benchmarks/memcheck_rb.py and its log
+# (a CPU build, which knows nothing of (8, 128) tiles or of the layouts the
+# TPU assigns): the programs that hold the 10 GB of bands and factors,
+# compiled for the described v5e at the published shapes from
+# ShapeDtypeStructs. Only the pencil's STRUCTURE is assembled, from four
+# pencils of the same 8206 unknowns (Nx = 8); G = 1024 is a shape.
+
+NORTH_STAR_G = 1024
+V5E_HBM_BYTES = 15.75 * 2 ** 30     # what the compiler's own error states
+
+
+@pytest.fixture(scope="module")
+def north_star(topo):
+    from dedalus_tpu.libraries import pencilops
+    mp = pytest.MonkeyPatch()
+    # FUSED_SOLVE = auto asks the device for its memory: a v5e's answer
+    mp.setattr(pencilops, "device_memory_bytes", lambda: V5E_HBM_BYTES)
+    try:
+        solver, _ = build_rb_solver(8, 1024, np.float32, matsolver="banded")
+        ops, ts = solver.ops, solver.timestepper
+        one_chip = SingleDeviceSharding(topo.devices[0])
+        G, S = NORTH_STAR_G, solver.pencil_shape[1]
+        sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731,E501
+            tuple(shape), dtype, sharding=one_chip)
+        grow = lambda a: None if a is None else sds(           # noqa: E731
+            (G,) + a.shape[1:], a.dtype)
+        M, L = (pencilops.BandedMatrix(grow(A.bands), grow(A.Vt), A.dsel)
+                for A in (solver.M_mat, solver.L_mat))
+        write, store, C, Gc = ops.incremental_chunk_program(M, L)
+        store = jax.tree.map(lambda a: sds(a.shape, a.dtype), store)
+        chunk = lambda a: None if a is None else sds(          # noqa: E731
+            (Gc,) + a.shape[1:], a.dtype)
+        X, scalar = sds((G, S)), sds(())
+        aux = ops._aux_from_core(store, {"ab": (scalar, scalar)})
+        yield {
+            "ops": ops, "C": C, "Gc": Gc, "S": S, "aux": aux,
+            "incremental": ops.use_incremental_factor(G, 4),
+            "factor_chunk": (write, (store, sds((), jnp.int32),
+                                     chunk(M.bands), chunk(L.bands),
+                                     chunk(M.Vt), chunk(L.Vt), scalar,
+                                     scalar)),
+            "mx0": (ts._mx0, (M, X)),
+            "stage_solve": (ts._stage_solve, (2, X, [X, X], [X, X], scalar,
+                                              aux, M, L)),
+        }
+    finally:
+        mp.undo()
+
+
+def test_north_star_shapes_and_choices(north_star):
+    """What the defaults resolve to for 1024 pencils on a v5e: q stays
+    the structural 32 (257 block rows), the factorization is incremental
+    in 8 chunks of 128 groups (the lane width: the packed factors are
+    kept group-minor), and FUSED_SOLVE = auto keeps them packed."""
+    ops = north_star["ops"]
+    assert (north_star["S"], ops.q, ops.NB, ops.n_pad) == (8206, 32, 257,
+                                                           8224)
+    assert north_star["incremental"]
+    assert (north_star["C"], north_star["Gc"]) == (8, 128)
+    aux = north_star["aux"]
+    assert "interior" in aux and "fsub" not in aux
+    perms, panelLU, U12, _, _ = aux["interior"]
+    assert panelLU.shape == U12.shape == (8, 256, 2048, 128)
+    assert perms.shape == (8, 256, 64, 128)
+
+
+@pytest.mark.parametrize("program", ["factor_chunk", "mx0", "stage_solve"])
+def test_north_star_program_fits_a_v5e(north_star, program):
+    """The compiler refuses a program that does not fit ("Used 16.49G of
+    15.75G hbm" is how the packed factors in (C, steps, Gc, flat) form
+    answered); arguments + temporaries + outputs that are not aliased
+    are what it holds at its peak."""
+    compiled, _ = _compile_f32(*north_star[program])
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert held < V5E_HBM_BYTES
+    # and the step's programs leave room for the state, the RHS's grid
+    # fields and the transform plans beside them
+    if program == "stage_solve":
+        assert held < 0.9 * V5E_HBM_BYTES
+
+
 @pytest.mark.xfail(strict=True, raises=ValueError,
                    reason="TPU lowering refuses pallas_substitution: 'the "
                           "last two dimensions of your block shape are "
