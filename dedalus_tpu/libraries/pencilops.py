@@ -489,11 +489,12 @@ class BandedOps(AdjointSolveOps):
         """[fusion] FUSED_SOLVE = auto, decided against the device the
         factors will live on: the precomposed substitution operators are
         7q^2 numbers per block row (FwdOp 4q^2 + BwdOp 3q^2) against the
-        LAPACK-packed pivoted factors' 4q^2. Where the operators, the two
-        band stores and the Woodbury blocks together pass three quarters of
-        the device's memory, the factors stay packed and the solve is the
-        pivoted substitution. RB 2048x1024 f32 on a 16 GB v5e: 8.6 GB of
-        operators + 4.4 of bands + 1.1 of Woodbury blocks = 14.1 GB
+        packed pivoted factors' 4q^2 (_factor_interior). Where the
+        operators, the two band stores and the Woodbury blocks together
+        pass three quarters of the device's memory, the factors stay
+        packed and the solve is the pivoted substitution. RB 2048x1024 f32
+        on a 16 GB v5e: 8.6 GB of operators + 4.4 of bands + 1.1 of
+        Woodbury blocks = 14.1 GB
         resident, and a stage solve's own temporaries are 3.5 GB (compiled
         for a described v5e, PR 28); packed, 4.5 + 4.4 + 1.1 = 10.0 GB. A
         quarter of the device is what the step's temporaries take there.
@@ -627,6 +628,38 @@ class BandedOps(AdjointSolveOps):
             out[o] = blk
         return out[0], out[-1], out[1]
 
+    @staticmethod
+    def _triangle_inverses(top):
+        """A panel's (G, q, q) LU top — L1 below the diagonal (unit lower),
+        U11 on and above it — with both triangles inverted in place: the
+        strict lower triangle of L1^-1 (unit lower stays unit lower) and
+        the upper triangle of U11^-1 (upper stays upper).
+
+        Each inverse gets one Newton step, X + X (I - A X), in plain
+        multiply-adds (no dot: on the TPU a dot is MXU passes). What
+        `solve_triangular` against the identity returns there is not good
+        enough to be STORED: an error in an entry of the inverse reaches
+        the solution multiplied by the block's condition number. RB
+        2048x1024 on the v5e, float32, with the inverses as returned: dense
+        residual check 4.8e-6, wall values off by 0.06 after ten steps and
+        0.26 after fifty; with the step: 2.0e-8 and 1.0e-6, which is what
+        the substitution they replace read (2.0e-8, 8.8e-7; my chip runs,
+        PR 29). On the CPU the step moves nothing (1e-8 either way)."""
+        eye = jnp.eye(top.shape[-1], dtype=top.dtype)
+        eyes = jnp.broadcast_to(eye, top.shape)
+        L1, U11 = jnp.tril(top, -1) + eye, jnp.triu(top)
+        L1inv = jsl.solve_triangular(L1, eyes, lower=True, unit_diagonal=True)
+        U11inv = jsl.solve_triangular(U11, eyes, lower=False)
+
+        def product(A, B):
+            return (A[..., :, :, None] * B[..., None, :, :]).sum(axis=-2)
+
+        def polished(A, X):
+            return X + product(X, eye - product(A, X))
+
+        return (jnp.tril(polished(L1, L1inv), -1)
+                + jnp.triu(polished(U11, U11inv)))
+
     def _factor_interior(self, bands):
         """
         Blocked banded LU with windowed partial pivoting (the batched-TPU
@@ -639,11 +672,26 @@ class BandedOps(AdjointSolveOps):
         a (q x 2q) U12 block per step. Unconditionally stable where the
         no-pivot block elimination breaks on constraint rows.
 
-        Factors are stored LAPACK-packed — the raw (2q x q) panel LU holds
-        L1 (unit-lower), U11 (upper) and L2 in one array — halving
-        persistent factor memory vs separate L1/L2/U11 blocks.
+        Factors are stored packed, one (2q x q) panel per block row, in
+        LAPACK's slots but not with LAPACK's contents: the top q x q holds
+        the INVERSES of the panel's triangles — the strict lower triangle
+        of L1^-1 (unit lower stays unit lower, its diagonal implied) and
+        the upper triangle of U11^-1 (upper stays upper) — and the bottom
+        q x q holds L2 as factored. The triangles never change between
+        factorizations, so they are inverted here, once per block row, and
+        every substitution body multiplies (`_solve_interior`); on the TPU
+        `solve_triangular` at q = 32 is "invert the block, then multiply"
+        anyway, so the sweep does the arithmetic it always did, without
+        redoing the inversion in each of its 16,384 bodies a step (RB
+        2048x1024, PR 29; `_triangle_inverses` says what a stored inverse
+        needs that a used-at-once one did not). U12 itself stays a
+        `solve_triangular` against L1: formed as L1^-1 @ T on the CPU it
+        cost RB 8x512 in float32 a factor 17 in the continuity residual.
+        Still 4q^2 numbers + 2q pivots a block row. The last block (one
+        per solve, no scan body) keeps its plain LAPACK LU.
 
-        Returns aux tuple (perms, panelLU, U12, lastP, lastLU).
+        Returns aux tuple (perms, panelLU, U12, lastP, lastLU), stacked
+        (steps, G, flat) with panels and U12 flattened row-major.
         """
         G = bands.shape[0]
         q, NB = self.q, self.NB
@@ -670,17 +718,22 @@ class BandedOps(AdjointSolveOps):
                 chunk_flat.reshape(G, nd, q))
             panel = jnp.concatenate([A11, Lo_i], axis=1)          # (G,2q,q)
             lu, _, perm = jax.lax.linalg.lu(panel)
-            L1 = jnp.tril(lu[:, :q, :], -1) + eye_q               # (G,q,q)
-            L2 = lu[:, q:, :]                                     # (G,q,q)
+            top, L2 = lu[:, :q, :], lu[:, q:, :]                  # (G,q,q)
             T = jnp.concatenate(
                 [A12, jnp.concatenate([D_n, Up_n], axis=2)], axis=1)  # (G,2q,2q)
             T = jnp.take_along_axis(T, perm[:, :, None], axis=1)
-            U12 = jsl.solve_triangular(L1, T[:, :q, :], lower=True,
+            # a substitution where the backend has one (the CPU); on the
+            # TPU the product by L1^-1, whose inversion its compiler
+            # shares with the one in _triangle_inverses
+            U12 = jsl.solve_triangular(jnp.tril(top, -1) + eye_q,
+                                       T[:, :q, :], lower=True,
                                        unit_diagonal=True)        # (G,q,2q)
             Tn = T[:, q:, :] - L2 @ U12                           # (G,q,2q)
             carry = (Tn[:, :, :q],
                      jnp.concatenate([Tn[:, :, q:], zero_qq], axis=2))
-            return carry, (perm, lu.reshape(G, 2 * q * q),
+            packed = jnp.concatenate(
+                [self._triangle_inverses(top), L2], axis=1)
+            return carry, (perm, packed.reshape(G, 2 * q * q),
                            U12.reshape(G, 2 * q * q))
 
         chunks = jnp.moveaxis(bands.reshape(G, nd, NB, q), 2, 0)  # (NB,G,nd,q)
@@ -692,13 +745,18 @@ class BandedOps(AdjointSolveOps):
         lu, _, lastP = jax.lax.linalg.lu(A11_f)
         return (perms, panelLU, U12, lastP, lu)
 
-    @staticmethod
-    def _batch_minor(interior):
-        """The packed pivoted factors as they are kept: the step-stacked
-        (steps, G, flat) arrays of `_factor_interior` with the GROUP axis
-        last, (steps, flat, G). The substitution's block operations are
-        batched over groups on (2q x q) blocks far smaller than an MXU
-        tile, and the TPU's layout assignment wants the batch in the
+    def _batch_minor(self, interior):
+        """The packed pivoted factors as they are kept and as the scan
+        bodies of `_solve_interior` read them: GROUP axis last and each
+        block TRANSPOSED — perms (steps, 2q, G); the panel (steps, q*2q,
+        G), a (q cols, 2q rows, G) view whose first q rows hold the two
+        triangular inverses and whose last q hold L2 (`_factor_interior`);
+        U12 (steps, 2q*q, G), a (2q cols, q rows, G) view. A block product
+        is then a sum over the view's LEADING axis of (rows, G) slabs
+        scaled by a row of the right-hand side: groups in the lanes, block
+        rows in the sublanes, nothing moved. The substitution's block
+        operations are batched over groups on blocks far smaller than an
+        MXU tile, and the TPU's layout assignment wants the batch in the
         lanes for them; handed (steps, G, flat) it re-lays-out the WHOLE
         stacked store before the scan (RB 2048x1024: `copy` of
         f32[16,256,64,2048] into {2,3,1,0:T(8,128)}, 4.0 GB with the lane
@@ -708,16 +766,23 @@ class BandedOps(AdjointSolveOps):
         perms, panelLU, U12, lastP, lastLU = interior
         if panelLU is None:
             return interior
-        return (jnp.swapaxes(perms, 1, 2), jnp.swapaxes(panelLU, 1, 2),
-                jnp.swapaxes(U12, 1, 2), lastP, lastLU)
+        q = self.q
+        steps, G = panelLU.shape[:2]
+
+        def blocks_T(a, rows, cols):
+            a = jnp.transpose(a.reshape(steps, G, rows, cols), (0, 3, 2, 1))
+            return a.reshape(steps, cols * rows, G)
+
+        return (jnp.swapaxes(perms, 1, 2), blocks_T(panelLU, 2 * q, q),
+                blocks_T(U12, q, 2 * q), lastP, lastLU)
 
     def _precompose_subst(self, interior):
         """Precomposed matmul-substitution operators (FUSED_SOLVE,
-        core/fusedstep.py). At factor time each panel's unit-lower and
-        upper blocks are inverted (one batched triangular solve against
-        the identity over every block row at once) and FOLDED with the
-        window permutation and the elimination update into per-step
-        GEMM operators:
+        core/fusedstep.py). At factor time each panel's inverted
+        unit-lower and upper blocks (the packed panel holds the inverses:
+        `_factor_interior`; the last block's are taken here) are FOLDED
+        with the window permutation and the elimination update into
+        per-step GEMM operators:
 
             fwd:  [y_i; w_next] = FwdOp_i @ [w; f_{i+1}]
                   FwdOp_i = [[L1inv P_top], [P_bot - L2 L1inv P_top]]
@@ -754,9 +819,10 @@ class BandedOps(AdjointSolveOps):
         fsub = {"lastOp": inv_upper(lastLU) @ inv_lower(lastLU) @ lastPmat}
         if panelLU is not None:
             steps, G = panelLU.shape[:2]
+            # the panel's top already holds both inverses (_factor_interior)
             lu = panelLU.reshape(steps * G, 2 * q, q)
-            L1inv = inv_lower(lu[:, :q, :])
-            U11inv = inv_upper(lu[:, :q, :])
+            L1inv = jnp.tril(lu[:, :q, :], -1) + eye
+            U11inv = jnp.triu(lu[:, :q, :])
             Pmat = jax.nn.one_hot(perms.reshape(steps * G, 2 * q), 2 * q,
                                   dtype=dtype, axis=-1)
             top = L1inv @ Pmat[:, :q, :]                      # (., q, 2q)
@@ -903,67 +969,86 @@ class BandedOps(AdjointSolveOps):
         return jnp.moveaxis(x, 0, 1).reshape(G, self.n_pad, k)
 
     def _solve_interior(self, interior_aux, f, fsub=None):
-        """Solve B~ x = f for f (G, n_pad, k) via the pivoted block factors."""
+        """Solve B~ x = f for f (G, n_pad, k) via the pivoted block factors
+        as `_batch_minor` keeps them. Both block-row scans run group-minor
+        from end to end: a block row of the right-hand side is (k, q, G),
+        and a scan body is selects, multiplies and sums over (rows, G)
+        slabs of the stored blocks — the window permutation a select
+        against an iota, the triangular solves products by the inverses
+        `_factor_interior` stored — so that the TPU program of a body holds
+        no gather, no custom call and no re-laid-out copy of a factor
+        slice (tests/test_chip_compile.py asks the compiler)."""
         if fsub is not None:
             return self._solve_interior_fused(interior_aux, f, fsub)
         perms, panelLU, U12, lastP, lastLU = interior_aux
         G, _, k = f.shape
         q, NB = self.q, self.NB
         eye_q = jnp.eye(q, dtype=f.dtype)
-        # flattened (steps, G, q*k) stacking: see _factor_interior layout note
-        fb = jnp.moveaxis(f.reshape(G, NB, q, k), 1, 0).reshape(NB, G, q * k)
 
         def last_solve(w):
+            w = jnp.take_along_axis(w, lastP[:, :, None], axis=1)
             y = jsl.solve_triangular(jnp.tril(lastLU, -1) + eye_q, w,
                                      lower=True, unit_diagonal=True)
             return jsl.solve_triangular(jnp.triu(lastLU), y, lower=False)
 
         if NB == 1:
-            w = jnp.take_along_axis(fb[0].reshape(G, q, k),
-                                    lastP[:, :, None], axis=1)
-            x = last_solve(w)
-            return jnp.moveaxis(x[None], 0, 1).reshape(G, self.n_pad, k)
+            return last_solve(f)
+
+        def apply(blockT, x):
+            """block @ x for a block kept transposed, (cols, rows, G), and
+            x (k, cols, G): the sum over cols of a (rows, G) slab times a
+            row of x."""
+            return (blockT[None] * x[:, :, None, :]).sum(axis=1)
+
+        def permute(w, perm):
+            """w[:, perm[i, g], g] for w (k, 2q, G), perm (2q, G): per row
+            exactly one term of the sum is selected (bit for bit the
+            gathered value; `where`, so no inf or nan crosses rows)."""
+            j = jax.lax.broadcasted_iota(perm.dtype, (2 * q, 1, 1), 0)
+            return jnp.where(perm[None] == j, w[:, :, None, :], 0).sum(axis=1)
+
+        col = jax.lax.broadcasted_iota(jnp.int32, (q, q, 1), 0)
+        row = jax.lax.broadcasted_iota(jnp.int32, (q, q, 1), 1)
+        # right-hand side block rows, flattened (steps, k*q, G): see the
+        # layout note of _factor_interior
+        fb = jnp.transpose(f.reshape(G, NB, q, k), (1, 3, 2, 0))
+        fb = fb.reshape(NB, k * q, G)
 
         # forward: eliminate with pivots; carry the updated next block
-        # the factors are stored group-minor (_batch_minor)
         def fwd(w_cur, xs):
-            f_next, perm_t, lu_t = xs
-            perm = perm_t.T
-            lu_i = jnp.moveaxis(lu_t.reshape(2 * q, q, G), 2, 0)
-            w = jnp.concatenate([w_cur, f_next.reshape(G, q, k)], axis=1)
-            w = jnp.take_along_axis(w, perm[:, :, None], axis=1)  # (G,2q,k)
-            L1_i = jnp.tril(lu_i[:, :q, :], -1) + eye_q
-            y = jsl.solve_triangular(L1_i, w[:, :q], lower=True,
-                                     unit_diagonal=True)
-            w_next = w[:, q:] - lu_i[:, q:, :] @ y
-            return w_next, y.reshape(G, q * k)
+            f_next, perm, panel = xs
+            panel = panel.reshape(q, 2 * q, G)
+            w = permute(jnp.concatenate(
+                [w_cur, f_next.reshape(k, q, G)], axis=1), perm)
+            L1inv_strict = jnp.where(row > col, panel[:, :q], 0)
+            y = w[:, :q] + apply(L1inv_strict, w[:, :q])
+            w_next = w[:, q:] - apply(panel[:, q:], y)
+            return w_next, y.reshape(k * q, G)
 
         with jax.named_scope("dedalus/matsolve/banded.fwd"):
-            w_f, ys = jax.lax.scan(fwd, fb[0].reshape(G, q, k),
+            w_f, ys = jax.lax.scan(fwd, fb[0].reshape(k, q, G),
                                    (fb[1:], perms, panelLU))
-        w = jnp.take_along_axis(w_f, lastP[:, :, None], axis=1)
-        x_last = last_solve(w)                                    # (G,q,k)
+        x_last = last_solve(jnp.transpose(w_f, (2, 1, 0)))        # (G,q,k)
+        x_last = jnp.transpose(x_last, (2, 1, 0))                 # (k,q,G)
 
         # backward: x_i = U11_i^-1 (y_i - U12_i @ [x_{i+1}; x_{i+2}])
-        zero = jnp.zeros_like(x_last)
-
         def bwd(carry, xs):
             x1, x2 = carry                                        # x_{i+1}, x_{i+2}
-            y_flat, lu_t, U12_t = xs
-            y_i = y_flat.reshape(G, q, k)
-            lu_i = jnp.moveaxis(lu_t.reshape(2 * q, q, G), 2, 0)
-            U12_i = jnp.moveaxis(U12_t.reshape(q, 2 * q, G), 2, 0)
-            rhs = y_i - U12_i @ jnp.concatenate([x1, x2], axis=1)
-            x = jsl.solve_triangular(jnp.triu(lu_i[:, :q, :]), rhs,
-                                     lower=False)
-            return (x, x1), x.reshape(G, q * k)
+            y_flat, panel, U12_i = xs
+            rhs = y_flat.reshape(k, q, G) - apply(
+                U12_i.reshape(2 * q, q, G),
+                jnp.concatenate([x1, x2], axis=1))
+            U11inv = jnp.where(
+                row <= col, panel.reshape(q, 2 * q, G)[:, :q], 0)
+            x = apply(U11inv, rhs)
+            return (x, x1), x.reshape(k * q, G)
 
         with jax.named_scope("dedalus/matsolve/banded.bwd"):
-            _, xs_rev = jax.lax.scan(bwd, (x_last, zero),
+            _, xs_rev = jax.lax.scan(bwd, (x_last, jnp.zeros_like(x_last)),
                                      (ys, panelLU, U12), reverse=True)
-        x = jnp.concatenate([xs_rev.reshape(NB - 1, G, q, k),
+        x = jnp.concatenate([xs_rev.reshape(NB - 1, k, q, G),
                              x_last[None]], axis=0)
-        return jnp.moveaxis(x, 0, 1).reshape(G, self.n_pad, k)
+        return jnp.transpose(x, (3, 0, 2, 1)).reshape(G, self.n_pad, k)
 
     def _pick_chunks(self, G, itemsize):
         """(C, Gc): chunk count and width for the G-chunked factorization,

@@ -21,6 +21,8 @@ without one). A compile that passes is not a chip run: `chip_smoke.py`
 is that.
 """
 
+import re
+
 import numpy as np
 import pytest
 import jax
@@ -230,6 +232,27 @@ def test_north_star_program_fits_a_v5e(north_star, program):
     # fields and the transform plans beside them
     if program == "stage_solve":
         assert held < 0.9 * V5E_HBM_BYTES
+
+
+def test_north_star_sweep_bodies_are_straight_line(north_star):
+    """The engagement check of PR 29, asked of the chip's compiler: the
+    packed path has ONE pair of scan bodies, and on the v5e they hold no
+    gather (the pivots are a select against an iota), no custom call (the
+    panel keeps the triangular inverses: no `InvertDiagBlocks`) and no
+    copy of a slice of the group-minor store (the products read it as it
+    lies). A body is every line of the optimised HLO whose op_name lies
+    under `dedalus/matsolve/banded.fwd` or `banded.bwd` and inside a
+    `while`: the fused computations' own instructions carry it too."""
+    _, text = _compile_f32(*north_star["stage_solve"])
+    store_copy = re.compile(r"= \w+\[[\d,]*(2048|64),128\]\S* copy\(")
+    # both scans are there, under the scopes the benchmark's readers sum
+    for scan in ("banded.fwd/while/body", "banded.bwd/while/body"):
+        lines = [ln for ln in text.splitlines() if scan in ln]
+        assert lines, scan
+        for ln in lines:
+            assert " gather(" not in ln, ln
+            assert "custom-call(" not in ln, ln
+            assert not store_copy.search(ln), ln
 
 
 @pytest.mark.xfail(strict=True, raises=ValueError,
