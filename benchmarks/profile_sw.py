@@ -146,8 +146,7 @@ def main():
     from dedalus_tpu.core.fusedstep import resolve_fusion
     plan = resolve_fusion()
     res["fusion"] = {"solve": plan.solve, "matvec": plan.matvec,
-                     "transforms": plan.transforms, "donate": plan.donate,
-                     "pallas": plan.pallas}
+                     "transforms": plan.transforms, "donate": plan.donate}
     gap = (res["step_ms"] - accounted) / max(accounted, 1e-9)
     res["accounted_gap_frac"] = round(gap, 4)
     # generous slack: CPU medians on a loaded box wobble ~20%

@@ -14,8 +14,6 @@ subcommands — `python -m dedalus_tpu <command> --help` documents each:
     serve         warm-pool solver daemon (dedalus_tpu/service/)
     submit        submit one run to a serve daemon
     route         spec-hash router fronting a replica fleet
-    tune          pre-tune solve-plan decisions offline
-                  (tools/autotune.py; docs/performance.md#autotuning)
 """
 
 import argparse
@@ -114,19 +112,10 @@ def _format_plan(record):
         parts.append(f"chunks={plan['transpose_chunks']}")
     if plan.get("solver_key"):
         parts.append(f"key={plan['solver_key']}")
-    # how the plan was chosen (tools/autotune.py): tuned decisions name
-    # their evidence kind + margin inline; rows from before plan_source
-    # existed simply omit the column
-    source = plan.get("plan_source")
-    if source:
-        cell = source
-        tuning = plan.get("tuning")
-        if source == "tuned" and isinstance(tuning, dict):
-            detail = [str(tuning.get("evidence_kind") or "")]
-            if tuning.get("margin") is not None:
-                detail.append(f"{tuning['margin']}x")
-            cell += f" ({', '.join(d for d in detail if d)})"
-        parts.append(f"source={cell}")
+    # how the plan was chosen; rows from before plan_source existed
+    # simply omit the column
+    if plan.get("plan_source"):
+        parts.append(f"source={plan['plan_source']}")
     return (f"plan[v{plan.get('plan_version', '?')}]: "
             + (", ".join(parts) or "(empty)"))
 
@@ -457,44 +446,6 @@ def report(args):
                   f"[{record.get('backend') or '?'}]: "
                   + (", ".join(cells) or "no cost data"))
             print(f"    {_format_plan(record)}")
-        elif kind == "autotune":
-            # tuning-decision rows (tools/autotune.py run_tune): one line
-            # per (backend, shape) decision — chosen plan, margin over
-            # the runner-up, tuning wall, cache verdict — then the
-            # per-cell evidence so a rejected candidate reads off the
-            # report without opening the JSONL
-            n_other += 1
-            print(f"(autotune) {record.get('config', '?')} "
-                  f"[{record.get('backend', '?')}"
-                  f"/{record.get('device_kind', '?')}]: chosen "
-                  f"{record.get('chosen_label', '?')} "
-                  f"(margin {record.get('margin', '?')}x, "
-                  f"wall {record.get('tuning_wall_sec', '?')}s, "
-                  f"cache {record.get('cache', '?')}, "
-                  f"{record.get('evidence_kind', '?')}, "
-                  f"sig {str(record.get('signature', ''))[:12]})")
-            for cell in record.get("cells") or []:
-                if not isinstance(cell, dict):
-                    continue
-                label = (f"{cell.get('composition', '?')}/"
-                         f"{cell.get('solve_dtype', '?')}"
-                         + ("+pallas" if cell.get("pallas") else ""))
-                if cell.get("skipped"):
-                    print(f"    {label}: skipped ({cell['skipped']})")
-                elif cell.get("error"):
-                    print(f"    {label}: ERROR {cell['error']}")
-                else:
-                    rate = cell.get("steps_per_sec",
-                                    cell.get("solves_per_sec", "?"))
-                    unit = "steps/s" if "steps_per_sec" in cell \
-                        else "solves/s"
-                    line = f"    {label}: {rate} {unit}"
-                    err = cell.get("rel_err")
-                    if isinstance(err, (int, float)):
-                        line += f", err {err:.1e}"
-                    if cell.get("reference"):
-                        line += " (reference)"
-                    print(line)
         else:
             n_other += 1
             ident = record.get("metric") or record.get("config") or "record"
@@ -767,19 +718,6 @@ def postmortem(args):
         print(line)
 
 
-def tune(args):
-    """Pre-tune solve-plan decisions offline (tools/autotune.py): run
-    the step-level candidate sweep for one benchmark problem, persist
-    the winning decision in the assembly cache (warming every later
-    build and the whole serving fleet sharing that cache), and append a
-    `kind: autotune` evidence row to benchmarks/results.jsonl."""
-    from .tools.autotune import run_tune
-    sys.exit(run_tune(problem=args.problem, force=args.force,
-                      quick=args.quick, as_json=args.json,
-                      record=not args.no_record, steps=args.steps,
-                      budget=args.budget))
-
-
 def lint(argv):
     """Static analysis (tools/lint): the DTL AST rule set plus, under
     --programs, the DTP compiled-program contract census
@@ -870,25 +808,6 @@ def build_parser():
                                           "dump (tools/health.py)")
     p.add_argument("directory", help="post-mortem directory or record file")
     p.set_defaults(func=postmortem)
-    p = sub.add_parser("tune", help="pre-tune solve-plan decisions "
-                                    "offline (tools/autotune.py; "
-                                    "docs/performance.md#autotuning)")
-    p.add_argument("--problem", default="rb256x64",
-                   choices=("rb256x64", "rb64x32", "diffusion64"),
-                   help="benchmark problem to tune (default rb256x64)")
-    p.add_argument("--force", action="store_true",
-                   help="re-measure and overwrite any cached decision")
-    p.add_argument("--json", action="store_true",
-                   help="print the decision row as JSON")
-    p.add_argument("--quick", action="store_true",
-                   help="reduced-budget smoke run (no results row)")
-    p.add_argument("--steps", type=int, default=None, metavar="N",
-                   help="override [autotune] TUNE_STEPS")
-    p.add_argument("--budget", type=float, default=None, metavar="SEC",
-                   help="override [autotune] TUNE_BUDGET_SEC")
-    p.add_argument("--no-record", action="store_true",
-                   help="do not append to benchmarks/results.jsonl")
-    p.set_defaults(func=tune)
     # pass-through subcommands: listed here so the top-level --help names
     # them, but main() dispatches them before this parser ever runs
     for name, helptext in (

@@ -48,11 +48,6 @@ Fusion layers (config section [fusion], resolved once per solver build):
                     (resilience snapshot ring, async checkpoint capture,
                     phase-probe caches) copy when
                     `timestepper.donates_histories` is set.
-  PALLAS          — experimental: the fused banded substitution as ONE
-                    Pallas kernel per pencil group (forward + backward
-                    sweeps with the precomposed inverses in a single
-                    kernel, no HBM round-trips between block rows).
-                    Interpret-mode on CPU; requires FUSED_SOLVE.
 
 Every fused solve still routes through `pencilops.AdjointSolveOps.solve`
 (the custom_vjp funnel), so `DifferentiableIVP` adjoints keep working;
@@ -73,7 +68,7 @@ from ..tools.config import config
 logger = logging.getLogger(__name__)
 
 __all__ = ["FusionPlan", "resolve_fusion", "cache_token", "FusedEvalPlan",
-           "pallas_substitution", "guard_histories"]
+           "guard_histories"]
 
 
 def guard_histories(ts, hists=None):
@@ -95,25 +90,23 @@ def guard_histories(ts, hists=None):
 class FusionPlan:
     """Resolved fusion switches (immutable per solver build)."""
 
-    __slots__ = ("solve", "matvec", "transforms", "donate", "pallas")
+    __slots__ = ("solve", "matvec", "transforms", "donate")
 
-    def __init__(self, solve, matvec, transforms, donate, pallas):
+    def __init__(self, solve, matvec, transforms, donate):
         self.solve = bool(solve)
         self.matvec = bool(matvec)
         self.transforms = bool(transforms)
         self.donate = bool(donate)
-        self.pallas = bool(pallas)
 
     def token(self):
         """Stable content token for cache keys (tools/assembly_cache.py):
         the RESOLVED composition structure, so an `auto` that lands
         differently on another backend keys differently too."""
-        return ("fusion-v1", self.solve, self.matvec, self.transforms,
-                self.pallas)
+        return ("fusion-v1", self.solve, self.matvec, self.transforms)
 
     def __repr__(self):
-        on = [k for k in ("solve", "matvec", "transforms", "donate",
-                          "pallas") if getattr(self, k)]
+        on = [k for k in ("solve", "matvec", "transforms", "donate")
+              if getattr(self, k)]
         return f"FusionPlan({'+'.join(on) or 'off'})"
 
 
@@ -133,33 +126,36 @@ def _flag(section, key, default, auto_value):
     return auto_value
 
 
-def resolve_fusion(decision=None):
+def _refuse_removed_options():
+    """A user file that still sets an option PR 30 removed must not be
+    silently ignored: the build it asked for (a tuned plan, the Pallas
+    substitution kernel) is not the build it would get."""
+    removed = []
+    if config.has_section("autotune"):
+        removed += [f"[autotune] {key}" for key in config["autotune"]] \
+            or ["[autotune]"]
+    if config.has_option("fusion", "PALLAS"):
+        removed.append("[fusion] PALLAS")
+    if removed:
+        raise ValueError(
+            f"{', '.join(removed)}: removed in PR 30 with the autotuner and "
+            f"the Pallas substitution kernel (every build runs the one plan "
+            f"`auto` resolves); delete the setting from your dedalus_tpu.cfg")
+
+
+def resolve_fusion():
     """Resolve the [fusion] config against the active backend. `auto`
     semantics are profile-driven (module docstring): solve/matvec/donate
     fuse everywhere; transform composition defaults on only where MMT
-    GEMMs beat the DCT/FFT fast paths (accelerator backends).
-
-    `decision` (a tools.autotune.Decision) supplies MEASURED auto values
-    for the tunable flags: PALLAS (the substitution kernel is a
-    first-class autotuner candidate — `auto` means off unless a tuned
-    decision selected it) and FUSED_TRANSFORMS when the decision pins
-    one. Explicit on/off still wins, exactly as before."""
+    GEMMs beat the DCT/FFT fast paths (accelerator backends)."""
+    _refuse_removed_options()
     section = config["fusion"] if config.has_section("fusion") else None
     accel = jax.default_backend() == "tpu"
-    cell = getattr(decision, "cell", None) or {}
-    transforms_auto = cell.get("fused_transforms")
-    if transforms_auto is None:
-        transforms_auto = accel
-    solve = _flag(section, "FUSED_SOLVE", "auto", True)
     return FusionPlan(
-        solve=solve,
+        solve=_flag(section, "FUSED_SOLVE", "auto", True),
         matvec=_flag(section, "FUSED_MATVEC", "auto", True),
-        transforms=_flag(section, "FUSED_TRANSFORMS", "auto",
-                         bool(transforms_auto)),
+        transforms=_flag(section, "FUSED_TRANSFORMS", "auto", accel),
         donate=_flag(section, "DONATE_STEP", "auto", True),
-        # the Pallas substitution consumes the precomposed inverses
-        pallas=_flag(section, "PALLAS", "auto",
-                     bool(cell.get("pallas", False))) and solve,
     )
 
 
@@ -501,92 +497,3 @@ def build_eval_plan(solver):
             cache.discard(key)
         eval_plan.store(solver, cache)
     return eval_plan
-
-
-# ------------------------------------------------------- Pallas substitution
-#
-# The experimental end state of the fused solve: the ENTIRE blocked
-# substitution (forward elimination + backward substitution over NB block
-# rows, with the precomposed panel inverses) as one kernel per pencil
-# group — block-row intermediates never round-trip through HBM between
-# scan steps. CPU runs interpret mode (the tested configuration); the
-# TPU compiler refuses the kernel's (1, n_pad) block shapes today
-# (ROADMAP D4, tests/test_chip_compile.py), and PALLAS=on there raises
-# that error uncaught. Requires FUSED_SOLVE (the
-# kernel consumes the precomposed inverses) and the unchunked single-RHS
-# solve shape; callers fall back to the XLA scan path otherwise.
-
-def pallas_substitution(fsub, fp, q):
-    """Fused banded substitution: solve B~ y = fp, one RHS column per
-    group, as ONE kernel instance per pencil group — the forward and
-    backward sweeps run over the precomposed FwdOp/BwdOp/lastOp GEMM
-    operators (libraries/pencilops.BandedOps._precompose_subst) with all
-    block-row intermediates held in kernel registers/VMEM, never
-    round-tripping through HBM between block rows.
-
-    fsub: {"FwdOp": (NB-1, G, 4q^2), "BwdOp": (NB-1, G, 3q^2),
-           "lastOp": (G, q, q)}; fp (G, n_pad). Returns y (G, n_pad).
-    """
-    from jax.experimental import pallas as pl
-
-    G, n_pad = fp.shape
-    NB = n_pad // q
-    interpret = jax.default_backend() != "tpu"
-
-    def kernel(fwd_ref, bwd_ref, last_ref, fp_ref, out_ref):
-        f = fp_ref[0]                                     # (NB, q)
-        fwd_ops = fwd_ref[0]                              # (NB-1, 4q^2)
-        bwd_ops = bwd_ref[0]                              # (NB-1, 3q^2)
-        last_op = last_ref[0]                             # (q, q)
-        w0 = f[0]
-        ys0 = jnp.zeros((max(NB - 1, 1), q), dtype=f.dtype)
-
-        def fwd(i, carry):
-            w, ys = carry
-            wf = jnp.concatenate([w, jax.lax.dynamic_index_in_dim(
-                f, i + 1, axis=0, keepdims=False)])
-            op = jax.lax.dynamic_index_in_dim(
-                fwd_ops, i, axis=0, keepdims=False).reshape(2 * q, 2 * q)
-            yw = op @ wf
-            ys = jax.lax.dynamic_update_index_in_dim(ys, yw[:q], i, axis=0)
-            return yw[q:], ys
-
-        w, ys = jax.lax.fori_loop(0, NB - 1, fwd, (w0, ys0))
-        x_last = last_op @ w
-        xs0 = jax.lax.dynamic_update_index_in_dim(
-            jnp.zeros((NB, q), dtype=f.dtype), x_last, NB - 1, axis=0)
-
-        def bwd(j, carry):
-            xs, x1, x2 = carry
-            i = NB - 2 - j
-            y = jax.lax.dynamic_index_in_dim(ys, i, axis=0, keepdims=False)
-            op = jax.lax.dynamic_index_in_dim(
-                bwd_ops, i, axis=0, keepdims=False).reshape(q, 3 * q)
-            x = op @ jnp.concatenate([y, x1, x2])
-            xs = jax.lax.dynamic_update_index_in_dim(xs, x, i, axis=0)
-            return xs, x, x1
-
-        xs, _, _ = jax.lax.fori_loop(
-            0, NB - 1, bwd, (xs0, x_last, jnp.zeros_like(x_last)))
-        out_ref[0] = xs.reshape(n_pad)
-
-    # group axis g is the pallas grid; step-stacked operators transpose
-    # group-major first so each kernel instance reads one contiguous slab
-    fwd_g = jnp.moveaxis(fsub["FwdOp"], 1, 0)   # (G, NB-1, 4q^2)
-    bwd_g = jnp.moveaxis(fsub["BwdOp"], 1, 0)
-    fpb = fp.reshape(G, NB, q)
-
-    def spec(a):
-        nd = a.ndim
-        return pl.BlockSpec((1,) + a.shape[1:],
-                            lambda g, nd=nd: (g,) + (0,) * (nd - 1))
-
-    return pl.pallas_call(
-        kernel,
-        grid=(G,),
-        in_specs=[spec(fwd_g), spec(bwd_g), spec(fsub["lastOp"]),
-                  spec(fpb)],
-        out_specs=pl.BlockSpec((1, n_pad), lambda g: (g, 0)),
-        out_shape=jax.ShapeDtypeStruct((G, n_pad), fp.dtype),
-        interpret=interpret,
-    )(fwd_g, bwd_g, fsub["lastOp"], fpb).reshape(G, n_pad)

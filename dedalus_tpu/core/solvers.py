@@ -89,18 +89,6 @@ class SolverBase:
         per-group scipy walk otherwise.
         """
         names = self.matrices
-        # consult the empirical autotuner FIRST (tools/autotune.py): a
-        # tuned decision — warm from the memo/assembly cache (zero
-        # probes) or measured once here under the [autotune] budget —
-        # feeds the three plan resolutions below, so the plan is still
-        # resolved exactly ONCE per build, BEFORE solver_key seals it
-        # into the cache/pool keys. [autotune] itself is validated at
-        # every build (bad MODE fails loud even when off); explicit
-        # solve knobs disable the tuned path (`plan_source: config`)
-        from ..tools import autotune
-        atp = autotune.resolve_autotune()
-        tuned = autotune.consult(self, atp) \
-            if (self.cache_ok and not self.lazy_ok) else None
         # resolve the [fusion] composition ONCE, before anything keys on
         # or compiles under it: solver_key's fusion token, BandedOps'
         # switches, the timestepper's donation contract and the eval plan
@@ -108,14 +96,14 @@ class SolverBase:
         # benchmarks flip flags in-process) can never split one solver
         # across two compositions
         from . import fusedstep
-        self._fusion_plan = fusedstep.resolve_fusion(decision=tuned)
+        self._fusion_plan = fusedstep.resolve_fusion()
         # resolve the [distributed] transpose chunking ONCE too, for the
         # same reason: the chunk structure shapes every compiled sharded
         # walk, and solver_key/pool_key token it so pooled compiled
         # programs can never alias across chunk configs (a bad config
         # value fails the build here, not mid-trace)
         from ..parallel.transposes import resolve_transpose_chunks
-        self._transpose_chunks = resolve_transpose_chunks(decision=tuned)
+        self._transpose_chunks = resolve_transpose_chunks()
         # resolve the solve composition + precision ladder ONCE as well
         # ([fusion] SOLVE_COMPOSITION/SPIKE_CHUNKS + the [precision]
         # section, libraries/solvecomp.py): the composition restructures
@@ -123,17 +111,11 @@ class SolverBase:
         # store dtype, so both token the assembly/pool keys; a bad
         # config value fails the build here, not mid-trace
         from ..libraries import solvecomp
-        self._solve_plan = solvecomp.resolve_solve_plan(decision=tuned)
+        self._solve_plan = solvecomp.resolve_solve_plan()
         # provenance: how THIS build's plan was chosen, stamped into
         # plan_provenance() so every results row names its selector
-        if tuned is not None:
-            self._plan_source = "tuned"
-            self._tuning = tuned.provenance()
-        else:
-            self._plan_source = ("config"
-                                 if solvecomp.solve_knobs_pinned()
-                                 else "default")
-            self._tuning = None
+        self._plan_source = ("config" if solvecomp.solve_knobs_pinned()
+                             else "default")
         G, S = self.pencil_shape
         dense_bytes = G * S * S * np.dtype(self.pencil_dtype).itemsize
         lazy_bytes = int(config["linear algebra"].get(
@@ -1147,8 +1129,7 @@ class InitialValueSolver(SolverBase):
         if fusion is not None:
             block["fusion"] = {
                 "solve": fusion.solve, "matvec": fusion.matvec,
-                "transforms": fusion.transforms, "donate": fusion.donate,
-                "pallas": fusion.pallas}
+                "transforms": fusion.transforms, "donate": fusion.donate}
         solve = getattr(self, "_solve_plan", None)
         if solve is not None:
             block["solve_composition"] = solve.composition
@@ -1161,13 +1142,9 @@ class InitialValueSolver(SolverBase):
         key = getattr(self, "assembly_key", None)
         if key:
             block["solver_key"] = str(key)[:16]
-        # how the plan was chosen: `tuned` (empirical autotuner decision,
-        # with its measured evidence), `config` (user-pinned solve
-        # knobs), or `default` (the hand-coded auto heuristics)
+        # how the plan was chosen: `config` (user-pinned solve knobs) or
+        # `default` (what `auto` resolves to)
         block["plan_source"] = getattr(self, "_plan_source", "default")
-        tuning = getattr(self, "_tuning", None)
-        if tuning is not None:
-            block["tuning"] = tuning
         return block
 
     def _precision_summary(self):

@@ -158,22 +158,12 @@ class AdjointSolveOps:
         rule; linearizing first marks them. The linearization point is
         zeros, so every primal-side value is a DCE-able constant and the
         compiled backward contains just the transposed solve."""
-        # the experimental Pallas substitution is not differentiable
-        # (jax.vjp cannot trace through pallas_call): transpose against
-        # the XLA-scan fused path instead — identical linear algebra on
-        # the same precomposed operators, so the adjoint contract holds
-        # under every [fusion] composition
-        pallas = getattr(self, "_pallas", False)
-        self._pallas = False
-        try:
-            with jax.named_scope(f"dedalus/matsolve/{self.kind}.solve_T"):
-                _, f_vjp = jax.vjp(
-                    lambda r: self._solve_impl(aux, r, mats),
-                    jnp.zeros_like(rhs))
-                (out,) = f_vjp(rhs)
-                return out
-        finally:
-            self._pallas = pallas
+        with jax.named_scope(f"dedalus/matsolve/{self.kind}.solve_T"):
+            _, f_vjp = jax.vjp(
+                lambda r: self._solve_impl(aux, r, mats),
+                jnp.zeros_like(rhs))
+            (out,) = f_vjp(rhs)
+            return out
 
 
 def shard_groups(fn, G, *args):
@@ -220,13 +210,9 @@ class DenseOps(AdjointSolveOps):
         # restructure — accepted as no-ops so one [fusion] config drives
         # mixed dense/banded fleets); the precision ladder routes the
         # solve through the refined low-dtype inverse + f64 residual
-        # polish (matsolvers.refined_ladder). The bare-ops fallback goes
-        # through the TUNER-AWARE resolver (dense ops carry no system
-        # size at construction, so 0 = "no registered shape"): a bare
-        # build and a solver build must never silently pick different
-        # plans for the same shape (tools/autotune.py).
+        # polish (matsolvers.refined_ladder).
         if solve_plan is None:
-            solve_plan = solvecomp.resolve_solve_plan_for_ops("dense", 0)
+            solve_plan = solvecomp.resolve_solve_plan()
         self._solve_plan = solve_plan
         self._composition = "sequential"
         if solve_plan.dtype != "native":
@@ -373,30 +359,18 @@ class BandedOps(AdjointSolveOps):
             or config["fusion"].get("FUSED_SOLVE", "auto").strip().lower()
             == "auto")
         self._fused_matvec = plan.matvec
-        self._pallas = plan.pallas
         # solve-composition/precision plan (libraries/solvecomp.py):
         # like `fusion`, resolved once per solver build and passed in so
         # a mid-build config edit can never split one solver across two
         # compositions; the plan token rides the assembly/pool keys.
-        # The bare-ops fallback goes through the TUNER-AWARE resolver
-        # keyed on this structure's system size, so a bare BandedOps and
-        # a tuned solver build can never silently pick different plans
-        # for the same shape (tools/autotune.py).
         if solve_plan is None:
-            solve_plan = solvecomp.resolve_solve_plan_for_ops(
-                "banded", structure.S)
+            solve_plan = solvecomp.resolve_solve_plan()
         self._solve_plan = solve_plan
         if solve_plan.composition != "sequential" and not plan.solve:
             raise ValueError(
                 f"[fusion] SOLVE_COMPOSITION = {solve_plan.composition} "
                 "requires FUSED_SOLVE: the restructured sweeps run over "
                 "the precomposed FwdOp/BwdOp GEMM operators")
-        if self._pallas and solve_plan.composition != "sequential":
-            raise ValueError(
-                "[fusion] PALLAS covers the sequential substitution "
-                f"only; SOLVE_COMPOSITION = {solve_plan.composition} "
-                "already removes the per-block-row HBM round-trips the "
-                "kernel exists to avoid")
         self._composition = solve_plan.composition if plan.solve \
             else "sequential"
         self._spike_chunks_cfg = solve_plan.spike_chunks
@@ -498,11 +472,10 @@ class BandedOps(AdjointSolveOps):
         resident, and a stage solve's own temporaries are 3.5 GB (compiled
         for a described v5e, PR 28); packed, 4.5 + 4.4 + 1.1 = 10.0 GB. A
         quarter of the device is what the step's temporaries take there.
-        An explicit `on`, a restructured composition or the Pallas kernel
-        (both consume the operators) are never overridden; a backend that
-        reports no limit (the CPU) keeps the operators."""
-        if not self._fused_solve_auto or self._composition != "sequential" \
-                or self._pallas:
+        An explicit `on` or a restructured composition (it consumes the
+        operators) is never overridden; a backend that reports no limit
+        (the CPU) keeps the operators."""
+        if not self._fused_solve_auto or self._composition != "sequential":
             return
         limit = device_memory_bytes()
         if not limit:
@@ -1411,14 +1384,8 @@ class BandedOps(AdjointSolveOps):
             # whole inner solve low; _solve_once casts the result back
             # and _solve_impl refines against the f64 M/L matvec
             fp = fp.astype(fsub["lastOp"].dtype)
-        if fsub is not None and "FwdOp" in fsub and self._pallas:
-            # experimental: the whole substitution as one Pallas kernel
-            # per group (no block-row round-trips; core/fusedstep.py)
-            from ..core.fusedstep import pallas_substitution
-            y = pallas_substitution(fsub, fp, self.q)
-        else:
-            y = self._solve_interior(auxc.get("interior"), fp[..., None],
-                                     fsub=fsub)[..., 0]
+        y = self._solve_interior(auxc.get("interior"), fp[..., None],
+                                 fsub=fsub)[..., 0]
         if self.t:
             with jax.named_scope("dedalus/matsolve/banded.woodbury"):
                 Vy = (jnp.einsum("gtn,gn->gt", auxc["Vt"], y)

@@ -47,8 +47,7 @@ solve GEMM runs low, then polishes with the existing f64
 residual-matvec refinement loop (fixed trip count, residual-tolerance
 masked — retrace-free) back to a configurable tolerance. `REFINE_SWEEPS
 = auto` scales the sweep count to the dtype gap; accuracy is recorded
-per benchmark row (benchmarks/fusion.py) and in the `precision`
-telemetry block.
+in the `precision` telemetry block.
 
 Everything here is pure jnp, traced inside the existing
 `AdjointSolveOps.solve` custom_vjp funnel (so adjoints transpose the
@@ -64,8 +63,7 @@ import jax.numpy as jnp
 from ..tools.config import config
 
 __all__ = ["SolvePlan", "resolve_solve_plan", "solve_plan_token",
-           "solve_knobs_pinned", "apply_decision",
-           "resolve_solve_plan_for_ops", "low_dtype", "spike_chunk_count",
+           "solve_knobs_pinned", "low_dtype", "spike_chunk_count",
            "ascan_apply", "spike_precompose", "spike_apply",
            "COMPOSITIONS", "SOLVE_DTYPES"]
 
@@ -77,9 +75,9 @@ SOLVE_DTYPES = ("native", "f32", "bf16")
 # fused tolerance class is calibrated against exactly that count).
 # f32: 2 sweeps measured to hold the rb256x64 trajectory at the f64
 # class (state err ~1e-14, probe residual ~1e-12) while keeping the
-# ladder's speedup (benchmarks/fusion.py sweep rows); raise REFINE_TOL/
-# REFINE_SWEEPS for stiffer operators. bf16's weaker per-sweep
-# contraction (~eps_bf16 * cond) needs the deeper schedule.
+# ladder's speedup; raise REFINE_TOL/REFINE_SWEEPS for stiffer
+# operators. bf16's weaker per-sweep contraction (~eps_bf16 * cond)
+# needs the deeper schedule.
 _AUTO_SWEEPS = {"native": None, "f32": 2, "bf16": 6}
 
 
@@ -129,19 +127,14 @@ def _choice(section, key, default, allowed):
     return val
 
 
-# the tunable solve knobs: any non-auto value here means the user has
-# PINNED the plan, and the empirical autotuner (tools/autotune.py) must
-# stand down for that build (`plan_source: config`)
-_TUNABLE_KEYS = (("fusion", "SOLVE_COMPOSITION"),
-                 ("fusion", "SPIKE_CHUNKS"),
-                 ("precision", "SOLVE_DTYPE"),
-                 ("precision", "REFINE_SWEEPS"))
-
-
 def solve_knobs_pinned():
-    """True when any tunable solve knob carries an explicit (non-auto)
-    value — explicit config always beats a tuned decision."""
-    for section, key in _TUNABLE_KEYS:
+    """True when any solve knob carries an explicit (non-auto) value:
+    the build's `plan_source` is then `config`, else `default`
+    (core/solvers.plan_provenance)."""
+    for section, key in (("fusion", "SOLVE_COMPOSITION"),
+                         ("fusion", "SPIKE_CHUNKS"),
+                         ("precision", "SOLVE_DTYPE"),
+                         ("precision", "REFINE_SWEEPS")):
         raw = config[section].get(key, "auto") \
             if config.has_section(section) else "auto"
         if raw.strip().lower() not in ("auto", ""):
@@ -149,38 +142,14 @@ def solve_knobs_pinned():
     return False
 
 
-def apply_decision(plan, cell):
-    """A tuned plan: `cell` (an autotune decision's plan cell) layered
-    over the heuristic `plan`. tol/mmt_dtype are not tuned and carry
-    over; sweeps fall back to the dtype's auto schedule when the cell
-    does not pin them."""
-    dtype = cell.get("solve_dtype") or plan.dtype
-    if dtype == "f64":
-        dtype = "native"
-    sweeps = cell.get("refine_sweeps")
-    if sweeps is None:
-        sweeps = _AUTO_SWEEPS.get(dtype, plan.sweeps)
-    return SolvePlan(composition=cell.get("composition")
-                     or plan.composition,
-                     spike_chunks=cell.get("spike_chunks",
-                                           plan.spike_chunks) or 0,
-                     dtype=dtype, sweeps=sweeps, tol=plan.tol,
-                     mmt_dtype=plan.mmt_dtype)
-
-
-def resolve_solve_plan(decision=None):
+def resolve_solve_plan():
     """Resolve `[fusion] SOLVE_COMPOSITION`/`SPIKE_CHUNKS` and the
     `[precision]` section against the active backend. Called once per
     solver build (core/solvers._build_pencil_system) BEFORE
     assembly_cache.solver_key seals the result into the cache/pool keys.
     `auto` semantics: composition stays `sequential` (the measured
-    default — benchmarks/fusion.py sweeps the alternatives and records
-    where each wins), SOLVE_DTYPE stays native, REFINE_SWEEPS scales to
-    the dtype gap, REFINE_TOL 0 (fixed sweeps, always applied).
-
-    `decision` (a tools.autotune.Decision) supplies the measured tuned
-    cell AHEAD of those heuristics — but only when every tunable knob is
-    auto: explicit config always wins."""
+    default), SOLVE_DTYPE stays native, REFINE_SWEEPS scales to
+    the dtype gap, REFINE_TOL 0 (fixed sweeps, always applied)."""
     comp = _choice("fusion", "SOLVE_COMPOSITION", "auto",
                    ("auto",) + COMPOSITIONS)
     if comp == "auto":
@@ -239,30 +208,8 @@ def resolve_solve_plan(decision=None):
                   ("auto",) + SOLVE_DTYPES)
     if mmt == "auto":
         mmt = "native"
-    plan = SolvePlan(composition=comp, spike_chunks=spike_chunks,
+    return SolvePlan(composition=comp, spike_chunks=spike_chunks,
                      dtype=dtype, sweeps=sweeps, tol=tol, mmt_dtype=mmt)
-    cell = getattr(decision, "cell", None)
-    if cell is not None and not solve_knobs_pinned():
-        plan = apply_decision(plan, cell)
-    return plan
-
-
-def resolve_solve_plan_for_ops(kind, n):
-    """Tuner-aware plan resolution for BARE ops constructions
-    (BandedOps/DenseOps built without a solver threading a plan in,
-    libraries/pencilops.py fallback paths): the same heuristics as
-    `resolve_solve_plan`, but layered with any in-process autotune
-    decision registered for (`kind`, system size `n`) — so a bare-ops
-    build and a solver build can never silently pick different plans for
-    the same shape."""
-    decision = None
-    if not solve_knobs_pinned():
-        try:
-            from ..tools import autotune
-            decision = autotune.ops_decision(kind, n)
-        except Exception:
-            decision = None
-    return resolve_solve_plan(decision=decision)
 
 
 def solve_plan_token():

@@ -58,7 +58,7 @@ AUTO_CHUNKS_CPU = 2
 _ACCELERATOR_BACKENDS = ("tpu", "gpu", "cuda", "rocm")
 
 
-def resolve_transpose_chunks(value=None, decision=None):
+def resolve_transpose_chunks(value=None):
     """
     Resolve the transpose chunk count ONCE (per solver build / pipeline
     construction): `[distributed] TRANSPOSE_CHUNKS` = 'auto' (backend
@@ -67,21 +67,12 @@ def resolve_transpose_chunks(value=None, decision=None):
     pool key (tools/assembly_cache.py) — pooled compiled programs depend
     on the chunk structure, so two chunk configs must never alias one
     entry. Raises ValueError on anything else.
-
-    `decision` (a tools.autotune.Decision) supplies a MEASURED value for
-    the `auto` branch when its cell pins one; an explicit config integer
-    still wins.
     """
     if value is None:
         value = cfg_get("distributed", "TRANSPOSE_CHUNKS", "auto")
     if isinstance(value, str):
         text = value.strip().lower()
         if text == "auto":
-            cell = getattr(decision, "cell", None) or {}
-            tuned = cell.get("transpose_chunks")
-            if isinstance(tuned, int) and not isinstance(tuned, bool) \
-                    and tuned >= 1:
-                return int(tuned)
             backend = jax.default_backend()
             return (AUTO_CHUNKS_ACCELERATOR
                     if backend in _ACCELERATOR_BACKENDS

@@ -46,8 +46,7 @@ from .config import config
 
 logger = logging.getLogger(__name__)
 
-__all__ = ["AssemblyCache", "pool_key", "resolve", "solver_key", "clear",
-           "store_tuning", "load_tuning"]
+__all__ = ["AssemblyCache", "pool_key", "resolve", "solver_key", "clear"]
 
 FORMAT_VERSION = 2
 
@@ -512,49 +511,6 @@ def clear():
     cache = resolve()
     if cache is not None:
         cache.clear()
-
-
-# --------------------------------------------------- tuning payload codec
-
-def store_tuning(cache, signature, record):
-    """Persist one autotune decision record (tools/autotune.py) as a
-    `tuning` payload under the tuner's shape signature. The record is
-    pure JSON riding the meta line (no arrays), but it gets the same
-    atomic-write + LRU + quarantine machinery as every matrix payload —
-    and the same cross-process reach, so one replica's tuning decision
-    warms every solver build (and the whole serving fleet) that shares
-    the cache directory."""
-    meta = {"kind": "tuning", "tuning": record}
-    try:
-        return cache.store(signature, meta, {})
-    except TypeError:
-        # non-JSON-serializable evidence must not break solver builds:
-        # the decision simply does not persist (memo still serves it
-        # in-process)
-        logger.warning(
-            f"assembly cache: tuning record {str(signature)[:12]} not "
-            "serializable; decision not persisted")
-        return False
-
-
-def load_tuning(cache, signature):
-    """The persisted tuning record for one shape signature, or None.
-    Structural corruption quarantines at load (AssemblyCache.load);
-    a parseable entry of the wrong kind quarantines here. SEMANTIC
-    validation of the record belongs to the caller
-    (tools/autotune.load_decision), which quarantines via discard."""
-    payload = cache.load(signature)
-    if payload is None:
-        return None
-    meta = payload["meta"]
-    if meta.get("kind") != "tuning" or not isinstance(
-            meta.get("tuning"), dict):
-        logger.warning(
-            f"assembly cache entry {str(signature)[:12]} is not a "
-            "tuning payload; quarantined")
-        cache.discard(signature)
-        return None
-    return meta["tuning"]
 
 
 # -------------------------------------------------- solver payload codecs

@@ -804,7 +804,7 @@ def _census_diffusion_step():
     donation-honored anchor — the declared 3 history buffers (F/MX/LX)
     must alias outputs."""
     from ...extras.bench_problems import build_diffusion_solver
-    with _pinned_config("fusion", DONATE_STEP="on", PALLAS="off"):
+    with _pinned_config("fusion", DONATE_STEP="on"):
         solver = build_diffusion_solver(48)
         solver.step(1e-3)
         rec = _solver_record(
@@ -820,8 +820,7 @@ def _census_rb_fused():
     precomposed-GEMM substitution — triangular/pivot solves forbidden."""
     from ...extras.bench_problems import build_rb_solver
     with _pinned_config("fusion", FUSED_SOLVE="on", FUSED_MATVEC="auto",
-                        FUSED_TRANSFORMS="off", DONATE_STEP="auto",
-                        PALLAS="off"):
+                        FUSED_TRANSFORMS="off", DONATE_STEP="auto"):
         solver, _ = build_rb_solver(16, 32, np.float64, matsolver="banded")
         solver.step(1e-3)
         rec = _solver_record(
@@ -838,8 +837,7 @@ def _census_rb_unfused():
     callback contract applies)."""
     from ...extras.bench_problems import build_rb_solver
     with _pinned_config("fusion", FUSED_SOLVE="off", FUSED_MATVEC="off",
-                        FUSED_TRANSFORMS="off", DONATE_STEP="off",
-                        PALLAS="off"):
+                        FUSED_TRANSFORMS="off", DONATE_STEP="off"):
         solver, _ = build_rb_solver(16, 32, np.float64, matsolver="banded")
         solver.step(1e-3)
         rec = _solver_record(
@@ -857,8 +855,7 @@ def _census_tau_ascan():
     small banded problem keeps this in the fast tier-1 subset."""
     import math
     from ...extras.bench_problems import build_tau_ivp
-    with _pinned_config("fusion", FUSED_SOLVE="on", SOLVE_COMPOSITION="ascan",
-                        PALLAS="off"):
+    with _pinned_config("fusion", FUSED_SOLVE="on", SOLVE_COMPOSITION="ascan"):
         solver, u, x, z = build_tau_ivp(8, 32, matsolver="banded")
         solver.step(1e-3)
         bound = math.ceil(math.log2(solver.ops.NB)) + 1
@@ -879,7 +876,7 @@ def _census_rb_spike():
     from ...extras.bench_problems import build_rb_solver
     from ...libraries import solvecomp
     with _pinned_config("fusion", FUSED_SOLVE="on", SOLVE_COMPOSITION="spike",
-                        SPIKE_CHUNKS="auto", PALLAS="off"):
+                        SPIKE_CHUNKS="auto"):
         solver, _ = build_rb_solver(16, 32, np.float64, matsolver="banded")
         solver.step(1e-3)
         chunks = solvecomp.spike_chunk_count(
@@ -900,7 +897,7 @@ def _census_rb_ladder():
     from ...extras.bench_problems import build_rb_solver
     from ...libraries import solvecomp
     with _pinned_config("fusion", FUSED_SOLVE="on", SOLVE_COMPOSITION="spike",
-                        SPIKE_CHUNKS="auto", PALLAS="off"):
+                        SPIKE_CHUNKS="auto"):
         with _pinned_config("precision", SOLVE_DTYPE="f32",
                             REFINE_SWEEPS="auto"):
             solver, _ = build_rb_solver(16, 32, np.float64,
@@ -915,67 +912,6 @@ def _census_rb_ladder():
                 f"(C={chunks}, {sweeps} refinement sweeps)",
                 extra_meta={"fused_solve": True,
                             "max_scan_length": max(chunks, sweeps)})
-    return [rec]
-
-
-@census("rb_step_tuned", fast=False)
-def _census_rb_tuned():
-    """The banded RB step built under an AUTOTUNED plan decision
-    (tools/autotune.py): a seeded spike/f32 decision is consulted from
-    the in-process memo at build time — zero microbench probes, the
-    warm-path contract — and the resulting tuned step program must
-    honor the same compiled contracts as the hand-picked plans: no
-    full-state gather (DTP101), no triangular/pivot custom calls in the
-    fused solve (DTP102), and the scan depth bounded by the decision's
-    own chunk/sweep schedule (DTP106)."""
-    from ...extras.bench_problems import build_rb_solver
-    from ...libraries import solvecomp
-    from ...tools import autotune
-    with _pinned_config("fusion", FUSED_SOLVE="on",
-                        SOLVE_COMPOSITION="auto", SPIKE_CHUNKS="auto",
-                        PALLAS="off"):
-        with _pinned_config("precision", SOLVE_DTYPE="auto",
-                            REFINE_SWEEPS="auto"):
-            with _pinned_config("autotune", MODE="off"):
-                # plan-independent signature probe (matrices and shape
-                # do not depend on the solve plan)
-                ref, _ = build_rb_solver(16, 32, np.float64,
-                                         matsolver="banded")
-                sig = autotune.solver_signature(ref)
-            autotune.seed_decision(sig, {
-                "composition": "spike", "solve_dtype": "f32",
-                "refine_sweeps": 2, "spike_chunks": 0, "pallas": False,
-                "fused_transforms": None, "transpose_chunks": None},
-                evidence_kind="seeded")
-            try:
-                with _pinned_config("autotune", MODE="cached"):
-                    before = autotune.probe_count()
-                    solver, _ = build_rb_solver(16, 32, np.float64,
-                                                matsolver="banded")
-                    probes = autotune.probe_count() - before
-            finally:
-                autotune.clear_memo()
-    if probes:
-        raise AssertionError(
-            f"tuned build ran {probes} microbench probe(s); a cached "
-            "decision must build probe-free")
-    if getattr(solver, "_plan_source", None) != "tuned" \
-            or solver._solve_plan.composition != "spike" \
-            or solver._solve_plan.dtype != "f32":
-        raise AssertionError(
-            f"seeded decision not applied: source="
-            f"{getattr(solver, '_plan_source', None)}, "
-            f"plan={solver._solve_plan!r}")
-    solver.step(1e-3)
-    chunks = solvecomp.spike_chunk_count(
-        solver.ops.NB - 1, solver._solve_plan.spike_chunks)
-    sweeps = solver._solve_plan.sweeps or 0
-    rec = _solver_record(
-        "rb_step_tuned", solver,
-        "banded RB RK222 step under a seeded autotune decision "
-        f"(spike/f32 ladder, C={chunks}, {sweeps} sweeps, zero probes)",
-        extra_meta={"fused_solve": True,
-                    "max_scan_length": max(chunks, sweeps)})
     return [rec]
 
 
@@ -999,7 +935,7 @@ def _census_traced_step():
         return text, prog.jaxpr(*args), meta, ledger, _plan_of(solver)
 
     was_on = tracing.enabled()
-    with _pinned_config("fusion", DONATE_STEP="on", PALLAS="off"):
+    with _pinned_config("fusion", DONATE_STEP="on"):
         try:
             tracing.disable()
             off_text, _, _, _, _ = compiled_step()
@@ -1221,7 +1157,7 @@ def _census_pool_step():
     in-process solves — a pool-only regression must fail the census, not
     surface as a served memory blowup."""
     from ...service.pool import SolverPool
-    with _pinned_config("fusion", DONATE_STEP="on", PALLAS="off"):
+    with _pinned_config("fusion", DONATE_STEP="on"):
         pool = SolverPool(size=1)
         entry, verdict, _ = pool.acquire(
             {"problem": "diffusion", "params": {"size": 32}})

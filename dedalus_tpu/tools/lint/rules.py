@@ -33,7 +33,7 @@ HOT_PATH_MODULES = (
     # dispatch pipeline (member IO belongs in core/ensemble seat APIs,
     # reply-phase IO after the boundary probe)
     "service/batching.py",
-    # the fused-step module's grid_eval / pallas kernels compile into the
+    # the fused-step module's grid_eval bodies compile into the
     # step program through the evaluator call graph (no in-module jit
     # wrapper for the structural pass to see) — a stray sync here lands
     # inside every fused step
@@ -63,13 +63,6 @@ HOT_PATH_MODULES = (
     # request (or every health probe) a sync it has no business paying
     "service/router.py",
     "service/fleet.py",
-    # the autotuner's consult runs inside every solver build and its
-    # decision feeds the plan the step program compiles under: config
-    # must be read at build/CLI time only (DTL008 — a tuned step that
-    # re-read [autotune] per step would retrace), and the microbench
-    # harness synchronizes via explicit np.asarray host gathers on
-    # probe results, never via stray syncs a step path could inherit
-    "tools/autotune.py",
 )
 
 # Device-state attribute names (the gathered pencil/fleet state and its

@@ -172,12 +172,12 @@ def pytest_configure(config):
         "tier-1 by default")
     # fusion: fused spectral step tests (core/fusedstep.py +
     # libraries/pencilops.py fused paths). Tier-1 by default; rides the
-    # same hard watchdog — a wedged fused-vs-unfused fleet comparison or
-    # pallas interpret loop must not eat the tier-1 budget silently.
+    # same hard watchdog — a wedged fused-vs-unfused fleet comparison
+    # must not eat the tier-1 budget silently.
     config.addinivalue_line(
         "markers",
         "fusion: fused spectral step tests (core/fusedstep.py: "
-        "precomposed solve/matvec/transform fusion, donation, pallas); "
+        "precomposed solve/matvec/transform fusion, donation); "
         "tier-1 by default")
     # distributed: overlapped chunked transpose pipeline + 2-D
     # batch x pencil mesh composition tests. Tier-1 by default; rides

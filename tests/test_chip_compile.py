@@ -106,14 +106,40 @@ def _compile_f32(program, args):
     return compiled, text
 
 
+def _fits_a_v5e(compiled):
+    """One program's arguments + temporaries fit one v5e chip (16 GB)."""
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 16e9
+
+
+def _pencil_sharded(solver, mesh):
+    """What parallel.distribute_solver records, minus the device_put a
+    described device cannot take (the step bodies read the mesh at trace
+    time: timesteppers._mesh_pin, field.mesh_transforms); returns where
+    each argument then lives."""
+    solver.dist.mesh = mesh
+    G = solver.pencil_shape[0]
+
+    def place(a):
+        lead = np.ndim(a) and np.shape(a)[0] == G
+        return NamedSharding(mesh, P("x") if lead else P())
+    return place
+
+
+def _moves_by_all_to_all(text):
+    """Pencils move by all-to-all, never by a full-state all-gather: the
+    tests/test_collectives.py assertion, asked of the TPU compiler."""
+    counts = collective_counts(text)
+    assert counts["all-to-all"] >= 2, f"transposes missing: {counts}"
+    assert counts["all-gather"] == 0, f"full-state gathers: {counts}"
+
+
 @pytest.mark.parametrize("program", ["step", "factor", "step_many"])
 @pytest.mark.parametrize("ops", ["DenseOps", "BandedOps"])
 def test_rb_program_compiles_for_v5e(rb_programs, ops, program):
     compiled, _ = _compile_f32(*rb_programs[ops][program])
-    mem = compiled.memory_analysis()
-    # one program's arguments + temporaries must fit one v5e chip (16 GB)
-    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-            + mem.output_size_in_bytes) < 16e9
+    _fits_a_v5e(compiled)
 
 
 def test_dense_step_took_the_tpu_branch(rb_programs):
@@ -132,21 +158,9 @@ def test_sharded_step_compiles_for_four_v5e_chips(topo):
     tests/test_collectives.py assertion, asked of the TPU compiler."""
     mesh = Mesh(np.array(topo.devices), ("x",))
     solver, _ = build_rb_solver(NX, NZ, np.float32)
-    # what parallel.distribute_solver records, minus the device_put a
-    # described device cannot take: the step bodies read the mesh at
-    # trace time (timesteppers._mesh_pin, field.mesh_transforms)
-    solver.dist.mesh = mesh
-    G = solver.pencil_shape[0]
-
-    def place(a):
-        lead = np.ndim(a) and np.shape(a)[0] == G
-        return NamedSharding(mesh, P("x") if lead else P())
-
-    program, args = _programs(solver, place)["step"]
-    compiled, text = _compile_f32(program, args)
-    counts = collective_counts(text)
-    assert counts["all-to-all"] >= 2, f"transposes missing: {counts}"
-    assert counts["all-gather"] == 0, f"full-state gathers: {counts}"
+    program, args = _programs(solver, _pencil_sharded(solver, mesh))["step"]
+    _, text = _compile_f32(program, args)
+    _moves_by_all_to_all(text)
 
 
 # ---- the fit of RB 2048x1024 (chipbench cell rb2048x1024.block10) ----
@@ -253,25 +267,3 @@ def test_north_star_sweep_bodies_are_straight_line(north_star):
             assert " gather(" not in ln, ln
             assert "custom-call(" not in ln, ln
             assert not store_copy.search(ln), ln
-
-
-@pytest.mark.xfail(strict=True, raises=ValueError,
-                   reason="TPU lowering refuses pallas_substitution: 'the "
-                          "last two dimensions of your block shape are "
-                          "divisible by 8 and 128 respectively, or be equal "
-                          "to the respective dimensions of the overall "
-                          "array' — block shape (1, 528), array shape "
-                          "(128, 528) (ROADMAP D4)")
-def test_pallas_substitution_compiles_for_v5e(topo):
-    """Banded RB shapes (G=128 groups, q=16, NB=33 block rows, f32).
-    Turns green the day the kernel is repaired — or goes with it."""
-    from dedalus_tpu.core.fusedstep import pallas_substitution
-    one_chip = SingleDeviceSharding(topo.devices[0])
-    G, q, NB = 128, 16, 33
-    sds = lambda *shape: jax.ShapeDtypeStruct(   # noqa: E731
-        shape, jnp.float32, sharding=one_chip)
-    fsub = {"FwdOp": sds(NB - 1, G, 4 * q * q),
-            "BwdOp": sds(NB - 1, G, 3 * q * q),
-            "lastOp": sds(G, q, q)}
-    jax.jit(lambda f, fp: pallas_substitution(f, fp, q)).lower(
-        fsub, sds(G, NB * q)).compile()

@@ -222,7 +222,7 @@ def test_report_plan_provenance_and_backfill(tmp_path):
     fixture = tmp_path / "mixed.jsonl"
     plan = {"plan_version": 1,
             "fusion": {"solve": True, "matvec": True, "transforms": False,
-                       "donate": True, "pallas": False},
+                       "donate": True},
             "solve_composition": "sequential", "solve_dtype": "native",
             "spike_chunks": 0, "transpose_chunks": 2,
             "solver_key": "f760738c9e28c192"}

@@ -3,17 +3,17 @@ Fused spectral step (core/fusedstep.py + libraries/pencilops fused
 paths): fused-vs-unfused equivalence across schemes (SBDF2 + RK222),
 problems (diffusion + Rayleigh-Benard) and pencil paths (dense +
 banded); composition under EnsembleSolver vmap and DifferentiableIVP
-adjoints; donation safety against the snapshot-rewind machinery; the
-Pallas substitution kernel in interpret mode; assembly-cache fusion-key
-invalidation; and the fused phase row in the metrics vocabulary.
+adjoints; donation safety against the snapshot-rewind machinery;
+assembly-cache fusion-key invalidation; the [fusion] config discipline
+(typo'd values and removed options fail the build); and the fused phase
+row in the metrics vocabulary.
 
 Tolerance contract under test (documented in docs/performance.md and
 the [fusion] config): FUSED_MATVEC and the dense-path fused layers are
 BITWISE identical to the legacy step; the precomposed banded
 substitution (FUSED_SOLVE) moves solutions at the eps*cond(block) level
 and the refinement polish keeps trajectories within ~1e-12 relative of
-the backward-stable sweeps (measured 7e-16 on the rb256x64 headline,
-benchmarks/fusion.py rows).
+the backward-stable sweeps (measured 7e-16 on the rb256x64 headline).
 """
 
 import pathlib
@@ -37,7 +37,7 @@ from test_banded import build_rb  # noqa: E402
 pytestmark = pytest.mark.fusion
 
 FUSION_KEYS = ("FUSED_SOLVE", "FUSED_MATVEC", "FUSED_TRANSFORMS",
-               "DONATE_STEP", "PALLAS")
+               "DONATE_STEP")
 
 
 @pytest.fixture
@@ -49,8 +49,7 @@ def fusion_cfg():
 
     def set_flags(**kw):
         for key in FUSION_KEYS:
-            config["fusion"][key] = kw.get(key.lower(), "auto"
-                                           if key != "PALLAS" else "off")
+            config["fusion"][key] = kw.get(key.lower(), "auto")
 
     yield set_flags
     for key, val in saved.items():
@@ -276,45 +275,39 @@ def test_donation_snapshot_rewind_bitwise(fusion_cfg):
         assert np.array_equal(np.asarray(solver.X), x_ref)
 
 
-# ------------------------------------------------------------ pallas path
+# ------------------------------------------------------- config discipline
 
-def test_pallas_substitution_interpret_matches(fusion_cfg):
-    """[fusion] PALLAS routes the banded substitution through the fused
-    Pallas kernel (interpret mode on CPU) and matches the XLA scan path
-    at the ulp level."""
-    x_xla, solver = rb_states(3, d3.RK222, {}, fusion_cfg)
-    assert solver.ops.NB > 1   # the kernel covers the multi-block sweep
-    x_pal, solver_p = rb_states(3, d3.RK222, {"pallas": "on"}, fusion_cfg)
-    assert solver_p.ops._pallas
-    scale = np.max(np.abs(x_xla))
-    assert np.max(np.abs(x_pal - x_xla)) <= 1e-12 * scale
+@pytest.mark.parametrize("key", FUSION_KEYS)
+def test_typoed_flag_fails_the_build(key, fusion_cfg):
+    """A typo'd [fusion] flag must not silently resolve to auto: the
+    build fails with a ValueError naming the key."""
+    fusion_cfg(**{key.lower(): "offf"})
+    with pytest.raises(ValueError, match=key):
+        build_diffusion(d3.SBDF2)
 
 
-def test_pallas_adjoint_falls_back_to_scan(fusion_cfg):
-    """The Pallas kernel is not differentiable, so solve_transpose (the
-    custom_vjp backward of every fused solve) transposes the XLA-scan
-    fused path instead — the adjoint contract holds with PALLAS on, and
-    the transpose bit-matches the pallas-off one (same precomposed
-    operators, same program)."""
-    fusion_cfg(pallas="on")
-    solver = build_rb(8, 32, matsolver="banded", timestepper=d3.RK222)
-    assert solver.ops._pallas
-    ops = solver.ops
-    # factor once through the step machinery (RK holds per-stage auxes),
-    # then transpose-solve against the first stage factorization
-    solver.step(0.01)
-    aux = solver.timestepper._lhs_aux[0]
-    rhs = jnp.asarray(np.random.default_rng(5).standard_normal(
-        solver.pencil_shape))
-    out_pal = np.asarray(ops.solve_transpose(aux, rhs))
-    assert np.isfinite(out_pal).all()
-    assert ops._pallas   # restored after the transpose trace
+@pytest.mark.parametrize("section,key", [
+    ("fusion", "PALLAS"), ("autotune", "MODE"), ("autotune", "TUNE_STEPS"),
+    ("autotune", "TUNE_BUDGET_SEC")])
+def test_removed_option_fails_the_build(section, key, fusion_cfg):
+    """A user file that still sets an option PR 30 removed (the
+    autotuner's section, the Pallas substitution kernel's switch) fails
+    the build with a ValueError naming the key and the PR, instead of
+    being silently ignored."""
     fusion_cfg()
-    solver2 = build_rb(8, 32, matsolver="banded", timestepper=d3.RK222)
-    solver2.step(0.01)
-    out_xla = np.asarray(solver2.ops.solve_transpose(
-        solver2.timestepper._lhs_aux[0], rhs))
-    assert np.array_equal(out_pal, out_xla)
+    had_section = config.has_section(section)
+    if not had_section:
+        config.add_section(section)
+    config[section][key] = "off"
+    try:
+        with pytest.raises(ValueError, match=rf"\[{section}\] {key}.*PR 30"):
+            build_diffusion(d3.SBDF2)
+    finally:
+        if had_section:
+            config[section].pop(key)
+        else:
+            config.remove_section(section)
+    build_diffusion(d3.SBDF2)   # and the build is whole again without it
 
 
 # ----------------------------------------------- metrics + retrace hygiene

@@ -168,7 +168,7 @@ def to_host(solver):
 
 
 def test_dtl001_covers_fusedstep_module(tmp_path):
-    """core/fusedstep.py is a declared hot module (its grid_eval/pallas
+    """core/fusedstep.py is a declared hot module (its grid_eval
     bodies compile into the step program through the evaluator call
     graph): a stray sync there fires whole-file, and host-side
     precomposition stays quiet."""

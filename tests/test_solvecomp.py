@@ -39,7 +39,7 @@ pytestmark = pytest.mark.solvecomp
 SOLVE_KEYS = (("fusion", "SOLVE_COMPOSITION"), ("fusion", "SPIKE_CHUNKS"),
               ("precision", "SOLVE_DTYPE"), ("precision", "REFINE_SWEEPS"),
               ("precision", "REFINE_TOL"), ("precision", "MMT_DTYPE"),
-              ("fusion", "FUSED_SOLVE"), ("fusion", "PALLAS"))
+              ("fusion", "FUSED_SOLVE"))
 
 
 @pytest.fixture
@@ -52,11 +52,10 @@ def solve_cfg():
 
     def set_cfg(composition="auto", solve_dtype="auto", sweeps="auto",
                 tol="auto", spike_chunks="auto", mmt="auto",
-                fused_solve="auto", pallas="off"):
+                fused_solve="auto"):
         config["fusion"]["SOLVE_COMPOSITION"] = composition
         config["fusion"]["SPIKE_CHUNKS"] = spike_chunks
         config["fusion"]["FUSED_SOLVE"] = fused_solve
-        config["fusion"]["PALLAS"] = pallas
         config["precision"]["SOLVE_DTYPE"] = solve_dtype
         config["precision"]["REFINE_SWEEPS"] = sweeps
         config["precision"]["REFINE_TOL"] = tol
@@ -417,28 +416,92 @@ def test_solver_and_pool_keys_rekey(solve_cfg):
     assert len({k[1] for k in keys}) == len(keys)
 
 
-def test_config_validation(solve_cfg):
+@pytest.mark.parametrize("bad,match", [
+    (dict(composition="logdepth"), "SOLVE_COMPOSITION"),
+    (dict(solve_dtype="f16"), "SOLVE_DTYPE"),
+    (dict(sweeps="-1"), "REFINE_SWEEPS"),
+    (dict(spike_chunks="1"), "SPIKE_CHUNKS"),
+    (dict(tol="many"), "REFINE_TOL"),
+    (dict(mmt="f8"), "MMT_DTYPE")])
+def test_config_validation(solve_cfg, bad, match):
     """Unknown [fusion]/[precision] values raise ValueError (never
-    silent auto) — every knob at the per-build resolve, and the resolve
-    really runs at build time (one build-level probe); incompatible
-    combinations fail loudly at ops construction."""
-    for bad, match in ((dict(composition="logdepth"), "SOLVE_COMPOSITION"),
-                       (dict(solve_dtype="f16"), "SOLVE_DTYPE"),
-                       (dict(sweeps="-1"), "REFINE_SWEEPS"),
-                       (dict(spike_chunks="1"), "SPIKE_CHUNKS"),
-                       (dict(tol="many"), "REFINE_TOL"),
-                       (dict(mmt="f8"), "MMT_DTYPE")):
-        solve_cfg(**bad)
-        with pytest.raises(ValueError, match=match):
-            solvecomp.resolve_solve_plan()
+    silent auto) at the per-build resolve, knob by knob."""
+    solve_cfg(**bad)
+    with pytest.raises(ValueError, match=match):
+        solvecomp.resolve_solve_plan()
+
+
+def test_config_validation_runs_at_build(solve_cfg):
+    """The resolve really runs inside every solver build."""
     solve_cfg(composition="logdepth")
     with pytest.raises(ValueError, match="SOLVE_COMPOSITION"):
-        build_diffusion()   # the resolve runs inside every solver build
-    # composition without the fused operators it restructures
+        build_diffusion()
+
+
+def test_composition_needs_fused_solve(solve_cfg):
+    """A restructured composition without the fused operators it
+    restructures fails loudly at ops construction."""
     solve_cfg(composition="ascan", fused_solve="off")
     with pytest.raises(ValueError, match="FUSED_SOLVE"):
         build_rb(8, 32, matsolver="banded")
-    # the Pallas kernel covers the sequential substitution only
-    solve_cfg(composition="spike", pallas="on")
-    with pytest.raises(ValueError, match="PALLAS"):
-        build_rb(8, 32, matsolver="banded")
+
+
+def test_solve_knobs_pinned(solve_cfg):
+    assert not solvecomp.solve_knobs_pinned()
+    solve_cfg(sweeps="3")
+    assert solvecomp.solve_knobs_pinned()
+    solve_cfg(sweeps="auto")
+    assert not solvecomp.solve_knobs_pinned()
+
+
+def test_plan_source_and_rekey(solve_cfg):
+    """plan_source names the selector — `default` with every solve knob
+    at auto, `config` once one is pinned — and the pinned knob re-keys
+    solver_key/pool_key."""
+    from dedalus_tpu.tools import assembly_cache
+    solver = build_rb(8, 32, matsolver="banded")
+    prov = solver.plan_provenance()
+    assert solver._plan_source == prov["plan_source"] == "default"
+    assert "tuning" not in prov
+    key_default = assembly_cache.solver_key(solver, list(solver.matrices))
+    pool_default = assembly_cache.pool_key(solver)
+
+    solve_cfg(composition="ascan")
+    pinned = build_rb(8, 32, matsolver="banded")
+    assert pinned._plan_source == "config"
+    assert pinned._solve_plan.composition == "ascan"
+    assert pinned.plan_provenance()["plan_source"] == "config"
+    assert assembly_cache.solver_key(pinned, list(pinned.matrices)) != \
+        key_default
+    assert assembly_cache.pool_key(pinned) != pool_default
+
+
+@pytest.mark.parametrize("case", ["dense", "banded_fused", "banded_packed",
+                                  "banded_pinned"])
+def test_plan_provenance_is_exact(solve_cfg, case):
+    """plan_provenance() of a build with every solve knob at auto, per
+    representation — dense, banded as auto fuses it on the CPU, banded
+    with FUSED_SOLVE = off (the packed store) — is exactly the resolved
+    plan with `plan_source: default` and nothing else; one pinned solve
+    knob turns the source to `config`."""
+    solve_cfg(fused_solve="off" if case == "banded_packed" else "auto",
+              solve_dtype="f32" if case == "banded_pinned" else "auto")
+    solver = build_rb(8, 32, matsolver="dense" if case == "dense"
+                      else "banded")
+    assert solver.ops.kind == ("dense" if case == "dense" else "banded")
+    prov = solver.plan_provenance()
+    key = prov.pop("solver_key", None)
+    assert key is None or key == str(solver.assembly_key)[:16]
+    pinned = case == "banded_pinned"
+    assert prov == {
+        "plan_version": 1,
+        "fusion": {"solve": case != "banded_packed", "matvec": True,
+                   "transforms": False, "donate": True},
+        "solve_composition": "sequential",
+        "solve_dtype": "f32" if pinned else "native",
+        "refine_sweeps": 2 if pinned else None,
+        "spike_chunks": 0,
+        "transpose_chunks": 2,
+        "plan_source": "config" if pinned else "default"}
+    if case.startswith("banded"):
+        assert solver.ops._fused_solve == (case != "banded_packed")
