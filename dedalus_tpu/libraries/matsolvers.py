@@ -35,6 +35,23 @@ def add_solver(cls):
     return cls
 
 
+def batched_matvec(A, x):
+    """(G, S, S) . (G, S) -> (G, S) in the operands' own dtype, said as
+    an elementwise multiply and a sum over the contracted axis, not as a
+    dot. A matrix-vector product reads every entry of A once and has no
+    reuse to offer the MXU. The TPU keeps a (G, S, S) stack pencil-minor
+    (`{0,2,1:T(8,128)}`: the group axis in the lanes), a dot wants it
+    `{2,1,0}`, and layout assignment then copies the whole stack in
+    front of the dot on EVERY call: at RB 256x64 (G=128, S=526, 142 MB a
+    stack) 0.47 ms each, `unscoped/copy.605/641/643/653` in the single
+    step and `copy.1214` in the scan body (PERF_LEDGER.jsonl, PR 30:
+    56% and 27% of `device_ms_per_step`). The multiply-reduce is what
+    XLA's own algebraic simplifier turned most of these dots into; it
+    reads the stack as it lies. Same arithmetic: f32 products summed in
+    f32, where the dot at `highest` emulates f32 in six bf16 passes."""
+    return jnp.sum(A * x[:, None, :], axis=-1)
+
+
 @add_solver
 class BatchedLUFactorized:
     """Batched dense LU with partial pivoting (default; the TPU analogue of
@@ -55,9 +72,10 @@ class BatchedLUFactorized:
 
 @add_solver
 class BatchedInverse:
-    """Precomputed batched inverse: each solve is one batched matmul on the
-    MXU (reference SparseInverse/DenseInverse, libraries/matsolvers.py:223).
-    Fastest per-step for moderate S; factorization cost is ~3x LU."""
+    """Precomputed batched inverse: each solve is one batched product, one
+    read of the stored inverse (reference SparseInverse/DenseInverse,
+    libraries/matsolvers.py:223). Fastest per-step for moderate S;
+    factorization cost is ~3x LU."""
 
     @staticmethod
     def factor(matrices):
@@ -65,7 +83,7 @@ class BatchedInverse:
 
     @staticmethod
     def solve(inv, rhs):
-        return jnp.einsum("gij,gj->gi", inv, rhs)
+        return batched_matvec(inv, rhs)
 
     @staticmethod
     def solve_multi(inv, rhs):

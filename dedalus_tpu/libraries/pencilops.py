@@ -37,7 +37,8 @@ import jax.scipy.linalg as jsl
 from jax.sharding import PartitionSpec
 
 from . import solvecomp
-from .matsolvers import BatchedInverseRefined, get_solver, refined_ladder
+from .matsolvers import (BatchedInverseRefined, batched_matvec, get_solver,
+                         refined_ladder)
 from ..tools.compat import shard_map
 from ..tools.config import config
 from ..tools.array import zeropad
@@ -225,15 +226,14 @@ class DenseOps(AdjointSolveOps):
 
     def matvec(self, A, X):
         with jax.named_scope("dedalus/matsolve/dense.matvec"):
-            return jnp.einsum("gij,gj->gi", A, X)
+            return batched_matvec(A, X)
 
     def matvec_pair(self, M, L, X):
         """(M @ X, L @ X) — the fused-step pair surface (core/fusedstep).
         Dense matvecs share nothing to factor out, so this is the two
-        einsums (bitwise identical to separate calls by construction)."""
+        products (bitwise identical to separate calls by construction)."""
         with jax.named_scope("dedalus/matsolve/dense.matvec_pair"):
-            return (jnp.einsum("gij,gj->gi", M, X),
-                    jnp.einsum("gij,gj->gi", L, X))
+            return batched_matvec(M, X), batched_matvec(L, X)
 
     def lincomb(self, a, A, b, B):
         return a * A + b * B

@@ -106,6 +106,15 @@ def _compile_f32(program, args):
     return compiled, text
 
 
+def _compile_once(programs, name):
+    """`_compile_f32` of one entry of a `_programs` dict, kept in the dict:
+    the tests that read one program's text share its one compile."""
+    done = programs.setdefault("compiled", {})
+    if name not in done:
+        done[name] = _compile_f32(*programs[name])
+    return done[name]
+
+
 def _fits_a_v5e(compiled):
     """One program's arguments + temporaries fit one v5e chip (16 GB)."""
     mem = compiled.memory_analysis()
@@ -138,8 +147,62 @@ def _moves_by_all_to_all(text):
 @pytest.mark.parametrize("program", ["step", "factor", "step_many"])
 @pytest.mark.parametrize("ops", ["DenseOps", "BandedOps"])
 def test_rb_program_compiles_for_v5e(rb_programs, ops, program):
-    compiled, _ = _compile_f32(*rb_programs[ops][program])
+    compiled, _ = _compile_once(rb_programs[ops], program)
     _fits_a_v5e(compiled)
+
+
+# ---- the dense stacks are read where they lie (PR 32) ----
+#
+# The TPU keeps a (G, S, S) operator stack pencil-minor. A product that
+# reaches XLA as a dot wants it row-major and layout assignment copies the
+# whole stack in front of it on every call: four 142 MB copies in RB
+# 256x64's `step` and one inside `step_many`'s scan body were 56% and 27%
+# of the device step (PERF_LEDGER.jsonl, PR 30: `unscoped/copy.*`) and
+# carried no scope, so no layer metric saw them.
+# `matsolvers.batched_matvec` says the product as a multiply and a sum.
+
+_PLUMBING = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+
+
+def _stack_copies(text, G, S):
+    """The instructions of an optimised program that make a second
+    (G, S, S) array in memory: a `copy`, or a fusion whose OUTPUT is
+    stack-shaped."""
+    made = re.compile(rf"= f32\[{G},{S},{S}\]\S* (copy|fusion)\(")
+    return [ln for ln in text.splitlines() if made.search(ln)]
+
+
+def _stack_is_read_in_place(text, G, S):
+    """No instruction of the optimised program copies a stack, and every
+    instruction that takes a stack as an operand, the fusions' own
+    instructions included, carries a `dedalus/matsolve/` scope: what
+    reads 142 MB is counted by `solve_ms_per_step`, and `solve_roofline`
+    cannot lose its denominator to an op without a name."""
+    assert not _stack_copies(text, G, S)
+    stacks = set(re.findall(rf"%(\S+) = f32\[{G},{S},{S}\]\S* [\w-]+\(",
+                            text))
+    assert stacks, "no stack in this program"
+    readers = 0
+    for ln in text.splitlines():
+        _, _, rest = ln.partition(" = ")
+        op = re.search(r" ([a-z][\w-]*)\(", " " + rest)
+        if not op or op.group(1) in _PLUMBING:
+            continue
+        if stacks & set(re.findall(r"%([\w.-]+)", rest)):
+            readers += 1
+            assert "dedalus/matsolve/" in ln, ln
+    assert readers, "nothing reads the stacks"
+
+
+@pytest.mark.parametrize("program", ["step", "step_many"])
+def test_dense_step_programs_copy_no_stack(rb_programs, program):
+    _, args = rb_programs["DenseOps"][program]
+    G, S = args[2].shape
+    compiled, text = _compile_once(rb_programs["DenseOps"], program)
+    _stack_is_read_in_place(text, G, S)
+    # and nothing stack-sized among the temporaries: 351 MB (`step`) and
+    # 175 MB (`step_many`) while the copies were there, 2.2 MB without
+    assert compiled.memory_analysis().temp_size_in_bytes < 50e6
 
 
 def test_dense_step_took_the_tpu_branch(rb_programs):
@@ -161,6 +224,8 @@ def test_sharded_step_compiles_for_four_v5e_chips(topo):
     program, args = _programs(solver, _pencil_sharded(solver, mesh))["step"]
     _, text = _compile_f32(program, args)
     _moves_by_all_to_all(text)
+    G, S = solver.pencil_shape
+    assert not _stack_copies(text, G // 4, S)    # a chip's 32 pencils
 
 
 # ---- the fit of RB 2048x1024 (chipbench cell rb2048x1024.block10) ----

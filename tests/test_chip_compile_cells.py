@@ -30,8 +30,8 @@ from dedalus_tpu.tools.config import config
 
 sys.path.insert(0, str(pathlib.Path(__file__).parent))
 from test_chip_compile import (  # noqa: E402,F401
-    NX, NZ, _compile_f32, _fits_a_v5e, _moves_by_all_to_all, _pencil_sharded,
-    _programs, topo)
+    NX, NZ, _compile_f32, _compile_once, _fits_a_v5e, _moves_by_all_to_all,
+    _pencil_sharded, _programs, _stack_copies, _stack_is_read_in_place, topo)
 
 EXAMPLES = pathlib.Path(__file__).parent.parent / "examples"
 PROGRAMS = ["step", "factor", "step_many"]
@@ -61,8 +61,18 @@ def shear_programs(topo):
 
 @pytest.mark.parametrize("program", PROGRAMS)
 def test_shear_program_compiles_for_v5e(shear_programs, program):
-    compiled, _ = _compile_f32(*shear_programs[program])
+    compiled, _ = _compile_once(shear_programs, program)
     _fits_a_v5e(compiled)
+
+
+@pytest.mark.parametrize("program", ["step", "step_many"])
+def test_shear_step_programs_copy_no_stack(shear_programs, program):
+    """The control of PR 32: at 65,536 pencils of 20 the compiler had
+    already made every product a multiply-reduce over the resident
+    `f32[65536,20,20]` stacks (no copy at the parent either); this pins
+    it. `factor` is not held to it: the LU's own layouts, once per dt."""
+    _, text = _compile_once(shear_programs, program)
+    _stack_is_read_in_place(text, *shear_programs[program][1][2].shape)
 
 
 @pytest.fixture(scope="module")
@@ -144,6 +154,8 @@ def test_sharded_step_many_compiles_for_four_v5e_chips(topo):
         "step_many"]
     _, text = _compile_f32(program, args)
     _moves_by_all_to_all(text)
+    G, S = solver.pencil_shape
+    assert not _stack_copies(text, G // 4, S)
 
 
 def test_sharded_shear_step_compiles_for_four_v5e_chips(topo):
@@ -154,3 +166,5 @@ def test_sharded_shear_step_compiles_for_four_v5e_chips(topo):
     program, args = _programs(solver, _pencil_sharded(solver, mesh))["step"]
     _, text = _compile_f32(program, args)
     _moves_by_all_to_all(text)
+    G, S = solver.pencil_shape
+    assert not _stack_copies(text, G // 4, S)

@@ -332,8 +332,10 @@ def test_fused_phase_row_and_zero_retraces(fusion_cfg):
     # the fused row overlaps the decomposition: excluded from the sum
     wall = record["loop_wall_sec"]
     decomp = sum(record["phase_total_sec"][p] for p in SUM_PHASES)
+    # the record rounds the fraction to four decimals (tools/metrics.py):
+    # near 0.04, where a loaded host puts it, that alone is 1.3e-3 relative
     assert record["phase_sum_frac"] == pytest.approx(
-        decomp / wall, rel=1e-3)
+        decomp / wall, rel=1e-3, abs=1e-4)
     lines = "\n".join(format_phase_table(record))
     assert "fused" in lines and "excluded from sum" in lines
     assert retrace_mod.sentinel.post_arm_retraces == 0
