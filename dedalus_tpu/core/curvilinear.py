@@ -19,11 +19,14 @@ data therefore becomes the real matrix Re(C) (x) I2 + Im(C) (x) J acting on
 (component, pair-slot) jointly.
 """
 
+import collections
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 
 from ..tools.array import match_precision
+from ..tools.jitlift import discovering
 
 PAIR_J = np.array([[0.0, -1.0], [1.0, 0.0]])
 # trace label of the coordinate <-> spin rotation inside a transform (the
@@ -139,6 +142,65 @@ def apply_component_pair_matrix(data, C, tdim, az_axis, real):
     return out.reshape(tshape + spatial)
 
 
+# (id(stack), group width) -> (stack, table or None): what `stack_diagonal`
+# decided, once per host stack (the reference to the stack guards the id
+# against reuse, as the registry of tools/jitlift.py does)
+_diagonals = {}
+# (way, id(stack)) -> how often a `gblocks` term was traced that way
+_gblocks_applied = collections.Counter()
+
+
+def stack_diagonal(stack, group_width):
+    """
+    For a (G, N, N) host stack whose non-zeros all lie on the main diagonals
+    (the sphere's ladder and Laplacian stacks: diagonal in l), the
+    (G * group_width, N) table of those diagonals, one row per slot of the
+    packed group axis; None for any other stack. Decided from the stack's
+    own entries, once per stack object: producers cache their stacks, so the
+    table's identity is as stable as the stack's and `match_precision`
+    lifts it the same way.
+    """
+    if not isinstance(stack, np.ndarray):
+        return None
+    key = (id(stack), group_width)
+    entry = _diagonals.get(key)
+    if entry is None or entry[0] is not stack:
+        table = None
+        if stack.ndim == 3 and stack.shape[1] == stack.shape[2]:
+            diagonal = np.einsum("gii->gi", stack)
+            if np.count_nonzero(stack) == np.count_nonzero(diagonal):
+                table = np.repeat(diagonal, group_width, axis=0)
+        entry = _diagonals[key] = (stack, table)
+    return entry[1]
+
+
+def tally_gblocks(stack, group_width):
+    """Count one traced application of a `gblocks` term's stack, by the way
+    `apply_group_stack` takes for it (core/operators.apply_term)."""
+    if not discovering():
+        diagonal = stack_diagonal(stack, group_width) is not None
+        _gblocks_applied["diagonal" if diagonal else "matmul", id(stack)] += 1
+
+
+def gblocks_tally(since=None):
+    """{way: {"stacks": distinct stacks, "applications": traced
+    applications}} of the `gblocks` terms traced in this process, way =
+    "diagonal" (a multiply by the stack's diagonals) or "matmul" (the
+    batched product); `since` = an earlier `gblocks_snapshot()` counts only
+    what was traced after it."""
+    counts = _gblocks_applied - (since or collections.Counter())
+    tally = {}
+    for way in ("diagonal", "matmul"):
+        applied = [n for (w, _), n in counts.items() if w == way]
+        tally[way] = {"stacks": len(applied), "applications": sum(applied)}
+    return tally
+
+
+def gblocks_snapshot():
+    """The counts so far, for `gblocks_tally(since=...)`."""
+    return collections.Counter(_gblocks_applied)
+
+
 def apply_group_stack(data, stack, axis_groups, axis_target, group_width):
     """
     Apply per-group matrices along a coupled axis: out[..., g, ..., j, ...] =
@@ -146,17 +208,24 @@ def apply_group_stack(data, stack, axis_groups, axis_target, group_width):
     on `axis_groups` (packed as G * group_width entries; the width slots
     broadcast) and the matrix is applied along `axis_target`.
 
-    This is the zero-padded batched matmul that replaces the reference's
-    per-m Python loops (core/transforms.py:1260-1288) — on TPU a single MXU
-    einsum over the m batch.
+    One algorithm whose cost follows the structure the stack shows
+    (`stack_diagonal`): a stack of diagonal matrices is an elementwise
+    multiply by its diagonals, which reads G*N numbers where the product
+    streams G*N*N; any other stack is the zero-padded batched matmul that
+    replaces the reference's per-m Python loops
+    (core/transforms.py:1260-1288), one einsum over the m batch.
     """
-    stack = match_precision(stack, data.dtype)
-    G = stack.shape[0]
     d = jnp.moveaxis(data, (axis_groups, axis_target), (-2, -1))
-    lead = d.shape[:-2]
-    d = d.reshape(lead + (G, group_width, d.shape[-1]))
-    out = jnp.einsum("gji,...gpi->...gpj", stack, d)
-    out = out.reshape(lead + (G * group_width, out.shape[-1]))
+    table = stack_diagonal(stack, group_width)
+    if table is not None:
+        out = d * match_precision(table, data.dtype)
+    else:
+        stack = match_precision(stack, data.dtype)
+        G = stack.shape[0]
+        lead = d.shape[:-2]
+        d = d.reshape(lead + (G, group_width, d.shape[-1]))
+        out = jnp.einsum("gji,...gpi->...gpj", stack, d)
+        out = out.reshape(lead + (G * group_width, out.shape[-1]))
     return jnp.moveaxis(out, (-2, -1), (axis_groups, axis_target))
 
 

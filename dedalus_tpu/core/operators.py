@@ -187,14 +187,16 @@ def apply_tensor_factor(data, factor, tshape_in, tshape_out):
 
 
 # trace label of a per-group stack applied in coefficient space (ladder,
-# cosine, Laplacian stacks of a curvilinear basis): the stack products of
-# a right-hand side that are not transforms
+# cosine, Laplacian stacks of a curvilinear basis): what a right-hand side
+# applies per group that is no transform, be it the batched product or,
+# for a stack of diagonal matrices, the multiply by its diagonals
+# (curvilinear.apply_group_stack)
 GROUP_STACK_SCOPE = "dedalus/evaluator/group_stack"
 
 
 def apply_term(data, tensor_factor, axis_descrs, tshape_in, tshape_out, tdim_out):
     """Device-side application of one operator term to coeff data."""
-    from .curvilinear import apply_group_stack
+    from .curvilinear import apply_group_stack, tally_gblocks
     out = data
     tdim_in = len(tshape_in)
     for axis, descr in enumerate(axis_descrs):
@@ -215,6 +217,7 @@ def apply_term(data, tensor_factor, axis_descrs, tshape_in, tshape_out, tdim_out
             with jax.named_scope(GROUP_STACK_SCOPE):
                 out = apply_group_stack(out, stack, gaxis, tdim_in + axis,
                                         width)
+            tally_gblocks(stack, width)
     if tensor_factor is not None:
         out = apply_tensor_factor(out, tensor_factor, tshape_in, tshape_out)
     elif tshape_in != tuple(tshape_out):

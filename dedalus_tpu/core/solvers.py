@@ -26,6 +26,7 @@ from .subsystems import (PencilLayout, build_subproblems, build_matrices,
                          build_banded_arrays, gather_state, scatter_state,
                          row_valid_masks, merge_conditional_equations,
                          active_member, state_key)
+from .curvilinear import gblocks_snapshot, gblocks_tally
 from .future import EvalContext, ev
 from . import timesteppers as timesteppers_mod
 from ..libraries import pencilops
@@ -926,25 +927,29 @@ class InitialValueSolver(SolverBase):
             self._after_advance(1, dt)
 
     def _first_advance(self):
-        """(start, factor seconds so far) before the run's first advance,
-        None before any later one."""
+        """(start, factor seconds so far, `gblocks` applications traced so
+        far) before the run's first advance, None before any later one."""
         if "compile" in self.build_phases.seconds:
             return None
         return (time_mod.perf_counter(),
-                self.build_phases.seconds.get("factor", 0.0))
+                self.build_phases.seconds.get("factor", 0.0),
+                gblocks_snapshot())
 
     def _book_compile(self, first):
         """After the first advance: trace + lower + XLA compile of the
         step program dominate it; recorded as the cold-start `compile`
         phase, less the first factorization, which the timestepper books
-        under `factor` (timesteppers._ensure_lhs)."""
+        under `factor` (timesteppers._ensure_lhs). With it, which way the
+        step program applies its `gblocks` stacks
+        (curvilinear.gblocks_tally)."""
         if first is None:
             return
-        start, factor_before = first
+        start, factor_before, gblocks_before = first
         jax.block_until_ready(self.X)
         factored = self.build_phases.seconds.get("factor", 0.0) - factor_before
         self.build_phases.add(
             "compile", time_mod.perf_counter() - start - factored)
+        self.build_phases.group_stacks = gblocks_tally(since=gblocks_before)
 
     def step_many(self, n, dt):
         """

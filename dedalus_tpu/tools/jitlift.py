@@ -34,7 +34,7 @@ import jax.numpy as jnp
 
 from . import retrace as retrace_mod
 
-__all__ = ["device_constant", "lifted_jit", "tracing_active",
+__all__ = ["device_constant", "discovering", "lifted_jit", "tracing_active",
            "tracing_state_known"]
 
 
@@ -201,6 +201,14 @@ def device_constant(array, dtype=None):
         f"{np.shape(_registry.arrays[idx])} constant; inlining into the "
         "program (the producer should cache this array).")
     return _registry.arrays[idx]
+
+
+def discovering():
+    """True inside a lifted program's discovery pass: the abstract trace
+    that finds its constants, after which the program is traced once more.
+    Trace-time tallies skip it so that each traced operation counts once."""
+    mode = getattr(_local, "mode", None)
+    return mode is not None and mode[0] == "discover"
 
 
 class _Mode:

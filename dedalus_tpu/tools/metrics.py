@@ -180,12 +180,16 @@ class BuildPhases:
     annotates the region `dedalus/build/<name>` for profiler traces.
     `record()` flattens to the `<name>_sec` keys telemetry records and
     bench rows carry (`host_assembly_sec`, `structure_sec`, `factor_sec`,
-    `compile_sec`), plus the assembly-cache verdict.
+    `compile_sec`), plus the assembly-cache verdict and, once the first
+    step program is lowered, its `group_stacks` tally.
     """
 
     def __init__(self):
         self.seconds = {}
         self.cache = "off"   # off | miss | hit
+        # which way the first step program applies its `gblocks` stacks
+        # (core/curvilinear.gblocks_tally), once it has been lowered
+        self.group_stacks = None
 
     class _Scope:
         def __init__(self, phases, name):
@@ -219,6 +223,8 @@ class BuildPhases:
         out = {f"{name}_sec": round(self.seconds.get(name, 0.0), 4)
                for name in BUILD_PHASES}
         out["assembly_cache"] = self.cache
+        if self.group_stacks is not None:
+            out["group_stacks"] = self.group_stacks
         return out
 
 

@@ -12,8 +12,9 @@ checked where it costs no chip time.
   (c) the invariants hold, and `dense_residual` bites on a broken factor;
   (d) `swsh_synthesis` against SciPy, every azimuthal order, float64;
   (e) the scopes the per-layer metrics read are in the lowered step, no
-      stack product is left under a bare `dedalus/evaluator/rhs`, and the
-      products are as many as sw_ell255.json's `swsh_shapes` says;
+      stack product is left under a bare `dedalus/evaluator/rhs`, the
+      products are as many as sw_ell255.json's `swsh_shapes` says, and the
+      ladder stacks (diagonal in l) are multiplies by a table;
   (f) the balanced height satisfies the LBVP's equation.
 """
 
@@ -182,8 +183,8 @@ def test_swsh_synthesis_agrees_with_scipy_for_every_order(sw):
 @pytest.fixture(scope="module")
 def stack_products(banded):
     """{scope: count} of the stack products (`apply_group_stack`'s einsum)
-    in the lowered single step, by the innermost scope that names them;
-    and the text."""
+    in the lowered single step, by the innermost scope that names them, and
+    of the multiplies under the group stacks' scope; and the text."""
     solver = banded.solver
     ts, rd = solver.timestepper, solver.real_dtype
     text = ts._step.lower(
@@ -193,9 +194,12 @@ def stack_products(banded):
     paths = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, flags=re.M))
     counts = collections.Counter()
     for line in text.splitlines():
-        op = re.search(r"stablehlo\.dot_general.*loc\((#loc\d+)\)\s*$", line)
-        path = paths.get(op.group(1), "") if op else ""
-        if STACK_PRODUCT in path:
+        op = re.search(r"stablehlo\.(dot_general|multiply).*"
+                       r"loc\((#loc\d+)\)\s*$", line)
+        path = paths.get(op.group(2), "") if op else ""
+        if op and op.group(1) == "multiply":
+            counts["group_stack.multiply"] += SCOPES["group_stack"] in path
+        elif STACK_PRODUCT in path or SCOPES["group_stack"] in path:
             named = [k for k, scope in SCOPES.items() if scope in path]
             counts[named[0] if named else "bare"] += 1
     return counts, text
@@ -207,18 +211,28 @@ def test_new_scopes_are_in_the_lowered_step(stack_products, scope):
     assert scope in stack_products[1]
 
 
-def test_no_stack_product_is_left_under_a_bare_rhs(sw, stack_products):
-    """Every product of a per-m stack carries one of the three scopes, and
-    a step holds as many as `swsh_shapes.calls_per_rhs` counts (the
-    numerator of `swsh_mmt_roofline`): 12 onto the colatitude grid, 8 back
-    from it, 12 in coefficient space."""
-    counts, _ = stack_products
+def test_no_stack_product_is_left_under_a_bare_rhs(sw, banded, stack_products):
+    """Every product of a per-m stack carries a transform's scope, and a
+    step holds as many as `swsh_shapes.calls_per_rhs` counts (the numerator
+    of `swsh_mmt_roofline`): 12 onto the colatitude grid, 8 back from it.
+    The 12 ladder stacks in coefficient space are diagonal in l: 12
+    multiplies by a (Nphi, Ntheta) table under their scope, no product, and
+    no (G, Ntheta, Ntheta) stack among the step's arguments."""
+    counts, text = stack_products
     assert counts["bare"] == 0, counts
     calls = sw.SPEC["swsh_shapes"]["calls_per_rhs"]
     stages = 2                                              # RK222
     assert counts["swsh.bwd"] == stages * sum(calls["bwd"].values()) == 12
     assert counts["swsh.fwd"] == stages * sum(calls["fwd"].values()) == 8
-    assert counts["group_stack"] == 12
+    assert counts["group_stack"] == 0
+    assert counts["group_stack.multiply"] == 12
+    G, N = SIZE["Nphi"] // 2, SIZE["Ntheta"]
+    signature = text.split("@main(")[1].split(") ->")[0]
+    assert f"tensor<{G}x{N * 3 // 2}x{N}x" in signature     # a SWSH stack
+    assert f"tensor<{G}x{N}x{N}x" not in signature
+    assert banded.solver.build_phases.record()["group_stacks"] == {
+        "diagonal": {"stacks": 4, "applications": 12},
+        "matmul": {"stacks": 0, "applications": 0}}
 
 
 @pytest.mark.parametrize("dtype, bound", [("float32", None),
