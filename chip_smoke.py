@@ -44,7 +44,12 @@ TOL_BC = 1e-5          # rehearsal 5.4e-7, chip 2.4e-7: wall errors of b, u
 TOL_CONTINUITY = 1e-5  # rehearsal 2.6e-7, chip 2.3e-7: |trace(grad_u)+tau_p|
 TOL_BANDED = 5e-5      # rehearsal 2.1e-6, chip 4.2e-6: rel. L2 of b coeffs
 TOL_F64 = 2e-5         # rehearsal 6.1e-7 (dd runner steered onto the CPU),
-#                        chip 1.1e-6: rel. L2 of b coeffs vs rb_f32, step 10
+#                        chip 1.1e-6: rel. L2 of b coeffs, rb_f64 against
+#                        rb_f32 at step 10. What it reads is FLOAT32's
+#                        error: it shows that the two runs are the same
+#                        problem, not that rb_f64 is float64. The float64
+#                        guarantee (1e-9 against a native float64 run) is
+#                        held by the benchmark cell rb256x64-f64.block10
 TOL_SHARDED = 5e-5     # rehearsal 0.0 (4 virtual CPU devices, bit-identical)
 
 
@@ -186,12 +191,15 @@ def one_chip_phases(nx, nz, on_tpu):
 
     # the example's own dtype. On the chip this must be the emulated-f64
     # (double-double) runner, not XLA's software f64; compared with rb_f32
-    # at the last single step both runs share, then stepped on to 20.
+    # at the last single step both runs share, then stepped on to 20. The
+    # comparison is as good as rb_f32 is: that the float64 run delivers
+    # float64 is for the cell rb256x64-f64.block10 to say, not this smoke.
     solver, report, checks, snaps = run_rb(
         "rb_f64", nx, nz, np.float64, singles=F64_STEPS, blocks=0,
         snapshot_at=SINGLES)
     report["rel_l2_vs_rb_f32"] = rel_l2(snaps[SINGLES], ref[SINGLES])
-    checks["agrees_with_rb_f32"] = report["rel_l2_vs_rb_f32"] < TOL_F64
+    checks["same_problem_as_rb_f32_to_f32_error"] = \
+        report["rel_l2_vs_rb_f32"] < TOL_F64
     if on_tpu:
         checks["emulated_f64_runner"] = report["emulated_f64"]
     passed.append(finish_phase(report, checks))

@@ -30,15 +30,19 @@ Rayleigh-Benard (tests/test_ddstep.py tracks native f64 at ~1e-10).
 `maybe_dd_runner(solver)` is the explicit hook with the same rules.
 """
 
+import contextlib
 import logging
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..libraries import doubledouble
 from ..libraries.doubledouble import (
-    DD, dd_from_f64, dd_to_f64, dd_split_host, dd_add, dd_sub, dd_neg,
-    dd_mul, dd_mul_f32, dd_matmul, dd_slices_from_f64, dd_zeros)
+    DD, DEFAULT_SLICES, dd_from_f64, dd_to_f64, dd_split_host, dd_add,
+    dd_sub, dd_neg, dd_mul, dd_mul_f32, dd_matmul, dd_slices_from_f64,
+    dd_zeros)
+from ..tools import tracing
 from ..tools.jitlift import lifted_jit, device_constant
 
 logger = logging.getLogger(__name__)
@@ -190,7 +194,10 @@ def dd_transform_axis(basis, data, axis, scale, forward):
     """One-axis grid<->coeff dd transform through the basis's MMT plan."""
     plan = basis.transform_plan(scale, library="matrix")
     M = plan.forward_mat if forward else plan.backward_mat
-    return dd_apply_matrix(M, data, axis)
+    # the dd interpreter never calls the plan's own (labelled) methods
+    with jax.named_scope(f"dedalus/transform/{type(basis).__name__}.dd."
+                         + ("fwd" if forward else "bwd")):
+        return dd_apply_matrix(M, data, axis)
 
 
 def dd_to_layout(data, domain, scales, tdim, layout):
@@ -347,6 +354,9 @@ class DDIVPRunner:
         from .timesteppers import MultistepIMEX, RungeKuttaIMEX
         self.solver = solver
         self.refine = int(refine)
+        self.slices = DEFAULT_SLICES
+        # {int8_dots_per_step, plane_MB} of the first step program run
+        self.program_counts = None
         ts = solver.timestepper
         if isinstance(ts, MultistepIMEX):
             self.kind = "multistep"
@@ -418,7 +428,8 @@ class DDIVPRunner:
         setting initial conditions or editing fields when stepping the
         runner directly; solver.step() does this automatically via its
         dirty tracking)."""
-        self.X = self._gather_dd()
+        with tracing.span("dd/gather"):
+            self.X = self._gather_dd()
 
     def reset_history(self, sim_time):
         """Restart the multistep ramp from `sim_time` with the current
@@ -478,6 +489,10 @@ class DDIVPRunner:
         self._extra_cache = {}
 
         def eval_F_dd(X, t, extra_dd):
+            with jax.named_scope("dedalus/evaluator/dd.rhs"):
+                return eval_F_inner(X, t, extra_dd)
+
+        def eval_F_inner(X, t, extra_dd):
             arrays_hi = scatter_state(layout, variables, X.hi)
             arrays_lo = scatter_state(layout, variables, X.lo)
             subs = {v: DD(arrays_hi[state_key(v)], arrays_lo[state_key(v)])
@@ -520,12 +535,15 @@ class DDIVPRunner:
         M_planes = _consts.matrix_slices(self.M_host)
         L_planes = _consts.matrix_slices(self.L_host)
 
-        def mx(planes_np, X):
-            planes = device_constant(planes_np[0])
-            inv = device_constant(planes_np[1])
+        def matvec(a_planes, X):
             B = DD(X.hi[..., None], X.lo[..., None])        # (G, S, 1)
-            C = dd_matmul(None, B, a_planes=(planes, inv))
+            C = dd_matmul(None, B, a_planes=a_planes)
             return DD(C.hi[..., 0], C.lo[..., 0])
+
+        def mx(planes_np, X):
+            with jax.named_scope("dedalus/matsolve/dd.matvec"):
+                return matvec((device_constant(planes_np[0]),
+                               device_constant(planes_np[1])), X)
 
         # dd A = a0*M + b0*L built from exact dd pairs of M and L; the
         # coefficients are dd SCALARS (dynamic inputs — one compiled
@@ -540,22 +558,28 @@ class DDIVPRunner:
             return dd_add(dd_mul(Mdd, a0), dd_mul(Ldd, b0))
 
         def factor(a0, b0):
-            A = build_A_dd(a0, b0)
-            from ..libraries.doubledouble import _dd_slices
-            planes, inv = _dd_slices(A, axis=-1, slices=8)
-            aux32 = ops.factor(A.hi)
+            # the plane slicing of A; the f32 factorization inside keeps
+            # its own scope (dense.factor)
+            with jax.named_scope("dedalus/matsolve/dd.factor"):
+                A = build_A_dd(a0, b0)
+                planes, inv = doubledouble._dd_slices(
+                    A, axis=-1, slices=self.slices)
+                aux32 = ops.factor(A.hi)
             return {"planes": planes, "inv": inv, "aux32": aux32}
 
         def solve_ir(lhs, rhs):
-            """f32 solve + dd-residual iterative refinement."""
-            x32 = ops.solve(lhs["aux32"], rhs.hi)
-            x = DD(x32, jnp.zeros_like(x32))
-            for _ in range(self.refine):
-                B = DD(x.hi[..., None], x.lo[..., None])
-                Ax = dd_matmul(None, B, a_planes=(lhs["planes"], lhs["inv"]))
-                r = dd_sub(rhs, DD(Ax.hi[..., 0], Ax.lo[..., 0]))
-                dx = ops.solve(lhs["aux32"], r.hi)
-                x = dd_add(x, DD(dx, jnp.zeros_like(dx)))
+            """f32 solve + dd-residual iterative refinement. The f32
+            solves keep their own scopes (dense.solve and the solver
+            class's); the sweeps' A x is dd.residual."""
+            with jax.named_scope("dedalus/matsolve/dd.refine"):
+                x32 = ops.solve(lhs["aux32"], rhs.hi)
+                x = DD(x32, jnp.zeros_like(x32))
+                for _ in range(self.refine):
+                    with jax.named_scope("dedalus/matsolve/dd.residual"):
+                        Ax = matvec((lhs["planes"], lhs["inv"]), x)
+                    r = dd_sub(rhs, Ax)
+                    dx = ops.solve(lhs["aux32"], r.hi)
+                    x = dd_add(x, DD(dx, jnp.zeros_like(dx)))
             return x
 
         def step_body(X, t, F_hist, MX_hist, LX_hist, lhs, a, b, c,
@@ -576,12 +600,13 @@ class DDIVPRunner:
             LX_hist = roll(LX_hist, LXn)
             RHS = None
             s = self.steps
-            for j in range(s):
-                terms = [dd_mul(F_hist[j], c[j]),
-                         dd_mul(MX_hist[j], dd_neg(a[j + 1])),
-                         dd_mul(LX_hist[j], dd_neg(b[j + 1]))]
-                for term in terms:
-                    RHS = term if RHS is None else dd_add(RHS, term)
+            with jax.named_scope("dedalus/step/dd.combine"):
+                for j in range(s):
+                    terms = [dd_mul(F_hist[j], c[j]),
+                             dd_mul(MX_hist[j], dd_neg(a[j + 1])),
+                             dd_mul(LX_hist[j], dd_neg(b[j + 1]))]
+                    for term in terms:
+                        RHS = term if RHS is None else dd_add(RHS, term)
             Xn = solve_ir(lhs, RHS)
             return Xn, F_hist, MX_hist, LX_hist
 
@@ -600,16 +625,19 @@ class DDIVPRunner:
             Xi = X
             for i in range(1, s + 1):
                 ti = dd_add(t, dd_mul(dt, _dd_scalar(cvec[i - 1])))
-                LXs.append(mx(L_planes, Xi))
+                # L X_j only where a later stage reads it (a zero column
+                # of H below the diagonal: RK222's first)
+                LXs.append(mx(L_planes, Xi) if H[i:, i - 1].any() else None)
                 Fs.append(eval_F_dd(Xi, ti, extra_dd))
                 RHS = MX0
-                for j in range(i):
-                    if A[i, j] != 0.0:
-                        RHS = dd_add(RHS, dd_mul(
-                            dd_mul(dt, _dd_scalar(A[i, j])), Fs[j]))
-                    if H[i, j] != 0.0:
-                        RHS = dd_sub(RHS, dd_mul(
-                            dd_mul(dt, _dd_scalar(H[i, j])), LXs[j]))
+                with jax.named_scope("dedalus/step/dd.combine"):
+                    for j in range(i):
+                        if A[i, j] != 0.0:
+                            RHS = dd_add(RHS, dd_mul(
+                                dd_mul(dt, _dd_scalar(A[i, j])), Fs[j]))
+                        if H[i, j] != 0.0:
+                            RHS = dd_sub(RHS, dd_mul(
+                                dd_mul(dt, _dd_scalar(H[i, j])), LXs[j]))
                 Xi = solve_ir(lhs_list[i - 1], RHS)
             return Xi
 
@@ -656,14 +684,53 @@ class DDIVPRunner:
 
     # -------------------------------------------------------------- stepping
 
-    def _lhs_for(self, a0, b0):
+    def _refactor(self, key, dt, factor):
+        """`self._lhs = factor()` unless `key` is the one held: the one
+        place the dd route refactors (an f32 factorization and a plane
+        slicing of A), inside the `step/factor` span. As on the float32
+        route (timesteppers._ensure_lhs) the run's first factorization is
+        cold start: waited for and booked as the build's `factor` phase."""
+        if key == self._lhs_key:
+            return
+        first = self._lhs_key is None
+        booked = self.solver.build_phases.scope("factor") if first \
+            else contextlib.nullcontext()
+        with tracing.span("step/factor", {"dt": float(dt)}), booked:
+            self._lhs = factor()
+            self._lhs_key = key
+            if first:
+                jax.block_until_ready(self._lhs)  # dedalus-lint: disable=DTL001
+
+    def _launch(self, program, *args):
+        """One step program on the device. The first one to run is
+        counted while it is traced: its int8 plane products (a scan block
+        traces one step) and the int8 planes it takes as arguments, the
+        lifted constants and the factored A among them."""
+        if self.program_counts is not None:
+            return program(*args)
+        dots_before = doubledouble.plane_dots_traced
+        out = program(*args)
+        planes = {id(a): a.nbytes
+                  for a in jax.tree.leaves((args, program.constants()))
+                  if getattr(a, "dtype", None) == np.int8}
+        self.program_counts = {
+            "int8_dots_per_step":
+                doubledouble.plane_dots_traced - dots_before,
+            "plane_MB": round(sum(planes.values()) / 1e6, 1)}
+        return out
+
+    def counters(self):
+        """What `build_phases.record()` says of this route."""
+        return dict({"slices": self.slices, "refine": self.refine},
+                    **(self.program_counts or {}))
+
+    def _lhs_for(self, a0, b0, dt):
         """Factored LHS for a0*M + b0*L, cached on the rounded-coefficient
         key (native pattern, timesteppers.py: float noise in recomputed
         coefficients must not trigger spurious refactors)."""
         key = (round(float(a0), 14), round(float(b0), 14))
-        if key != self._lhs_key:
-            self._lhs = self._factor(_dd_scalar(a0), _dd_scalar(b0))
-            self._lhs_key = key
+        self._refactor(key, dt, lambda: self._factor(_dd_scalar(a0),
+                                                     _dd_scalar(b0)))
         return self._lhs
 
     def _t_dd(self):
@@ -687,10 +754,10 @@ class DDIVPRunner:
         a = np.concatenate([np.asarray(a, float), np.zeros(s + 1 - len(a))])
         b = np.concatenate([np.asarray(b, float), np.zeros(s + 1 - len(b))])
         c = np.concatenate([np.asarray(c, float), np.zeros(s - len(c))])
-        lhs = self._lhs_for(a[0], b[0])
-        self.X, self.F_hist, self.MX_hist, self.LX_hist = self._step(
-            self.X, self._t_dd(), self.F_hist, self.MX_hist, self.LX_hist,
-            lhs, _dd_vector(a), _dd_vector(b), _dd_vector(c),
+        lhs = self._lhs_for(a[0], b[0], dt)
+        self.X, self.F_hist, self.MX_hist, self.LX_hist = self._launch(
+            self._step, self.X, self._t_dd(), self.F_hist, self.MX_hist,
+            self.LX_hist, lhs, _dd_vector(a), _dd_vector(b), _dd_vector(c),
             self._extras_dd())
         self.sim_time += dt
         self.iteration += 1
@@ -707,8 +774,8 @@ class DDIVPRunner:
             return
         if self.kind == "rk":
             lhs_list, t_dd = self._rk_prepare(dt)
-            self.X, _ = self._rk_step_n(
-                self.X, t_dd, _dd_scalar(dt), lhs_list,
+            self.X, _ = self._launch(
+                self._rk_step_n, self.X, t_dd, _dd_scalar(dt), lhs_list,
                 self._extras_dd(), n)
             self.sim_time += n * dt
             self.iteration += n
@@ -722,10 +789,10 @@ class DDIVPRunner:
             return
         a, b, c = self.scheme.compute_coefficients([dt] * self.steps,
                                                    self.steps)
-        lhs = self._lhs_for(a[0], b[0])
-        carry = self._step_n(
-            self.X, self._t_dd(), self.F_hist, self.MX_hist, self.LX_hist,
-            lhs, _dd_vector(np.asarray(a, float)),
+        lhs = self._lhs_for(a[0], b[0], dt)
+        carry = self._launch(
+            self._step_n, self.X, self._t_dd(), self.F_hist, self.MX_hist,
+            self.LX_hist, lhs, _dd_vector(np.asarray(a, float)),
             _dd_vector(np.asarray(b, float)),
             _dd_vector(np.asarray(c, float)), self._extras_dd(),
             _dd_scalar(dt), n)
@@ -737,17 +804,15 @@ class DDIVPRunner:
         scheme = self.scheme
         H_diag = [float(scheme.H[i, i]) for i in range(1, scheme.stages + 1)]
         uniq = sorted(set(H_diag))
-        key = ("rk", round(dt, 14))
-        if key != self._lhs_key:
-            self._lhs = self._rk_factor([_dd_scalar(dt * h) for h in uniq])
-            self._lhs_key = key
+        self._refactor(("rk", round(dt, 14)), dt, lambda: self._rk_factor(
+            [_dd_scalar(dt * h) for h in uniq]))
         lhs_list = [self._lhs[uniq.index(h)] for h in H_diag]
         return lhs_list, self._t_dd()
 
     def _rk_advance(self, dt):
         lhs_list, t_dd = self._rk_prepare(dt)
-        self.X = self._rk_step(self.X, t_dd, _dd_scalar(dt), lhs_list,
-                               self._extras_dd())
+        self.X = self._launch(self._rk_step, self.X, t_dd, _dd_scalar(dt),
+                              lhs_list, self._extras_dd())
         self.sim_time += dt
         self.iteration += 1
 

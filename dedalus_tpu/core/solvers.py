@@ -499,19 +499,26 @@ class SolverBase:
         nobody reads launches nothing (keeps the no-IO stepping loop free
         of per-step scatter work). X is not donated.
         """
+        def fetch():
+            # one launch per state that is read
+            with tracing.span("state/scatter"):
+                return self._scatter_program(self.variables)(X)
+
+        self._install_pulls(fetch)
+
+    def _install_pulls(self, fetch):
+        """Lazy pulls on every variable: the first one read calls
+        `fetch()` -> {state_key: coefficient data}, once for all."""
         cache = {}
-        variables = self.variables
 
         def make_pull(var):
             def pull():
                 if "arrays" not in cache:
-                    # one launch per state that is read
-                    with tracing.span("state/scatter"):
-                        cache["arrays"] = self._scatter_program(variables)(X)
+                    cache["arrays"] = fetch()
                 var.preset_coeff(cache["arrays"][state_key(var)])
             return pull
 
-        for v in variables:
+        for v in self.variables:
             v.install_pull(make_pull(v))
 
     def snapshot_versions(self):
@@ -689,6 +696,12 @@ class InitialValueSolver(SolverBase):
             except DDUnsupportedError as exc:
                 logger.info(f"float64 on accelerator: dd path unavailable "
                             f"({exc}); stepping in native XLA f64")
+        # which route keeps a float64 problem's guarantee, for the records
+        if self._dd is not None:
+            self.build_phases.f64_route = "dd"
+            self.build_phases.dd = self._dd.counters
+        elif np.dtype(self.pencil_dtype) in (np.float64, np.complex128):
+            self.build_phases.f64_route = "xla_f64"
         # Profiling (reference: core/solvers.py:546-561,780-806 cProfile
         # phases; here a jax.profiler trace of the run phase + per-phase
         # wall times dumped at log_stats)
@@ -795,56 +808,51 @@ class InitialValueSolver(SolverBase):
         return self._project_state
 
     def _dd_advance(self, n, dt):
-        """Advance n steps on the emulated-f64 (double-double) path: sync
-        user field edits into the dd state, step, and install lazy field
-        pulls that materialize f64 data on access. The f32 Hermitian
+        """Advance n steps on the emulated-f64 (double-double) path, inside
+        the same `step` / `step_many` span and with the same cold-start
+        booking as the float32 route: sync user field edits into the dd
+        state, step, then the shared host bookkeeping. The f32 Hermitian
         re-projection cadence is skipped here — a f32 grid roundtrip would
         truncate the dd state (the dd-supported problem set is Cartesian
         real-storage, which has no Hermitian drift to project out)."""
         dd = self._dd
-        if self.fields_dirty():
-            # user edit or checkpoint restart: re-gather state AND restart
-            # the multistep ramp from the solver's clock (histories predate
-            # the new state; load_state also resets sim_time/iteration)
-            dd.X = dd._gather_dd()
-            dd.reset_history(self.sim_time)
-        elif dd.sim_time != self.sim_time:
-            dd.sim_time = self.sim_time
+        first = self._first_advance()
+        attrs = {"iteration": self.iteration, "route": "dd"}
         if n > 1:
-            dd.step_many(n, dt)   # one lax.scan dispatch per block
-        else:
-            dd.step(dt)
-        self.X = dd.X.hi   # f32 view: finite checks, harness inspection
-        self.sim_time = dd.sim_time
-        layout, variables = self.layout, self.variables
-        Xdd = dd.X
-        cache = {}
+            attrs["n"] = n
+        with tracing.span("step_many" if n > 1 else "step", attrs):
+            if self.fields_dirty():
+                # user edit or checkpoint restart: re-gather state AND
+                # restart the multistep ramp from the solver's clock
+                # (histories predate the new state; load_state also resets
+                # sim_time/iteration)
+                dd.sync_state()
+                dd.reset_history(self.sim_time)
+            elif dd.sim_time != self.sim_time:
+                dd.sim_time = self.sim_time
+            if n > 1:
+                dd.step_many(n, dt)   # one lax.scan dispatch per block
+            else:
+                dd.step(dt)
+            self.X = dd.X.hi   # f32 view: finite checks, health probe
+            self.sim_time = dd.sim_time
+            self._book_compile(first)
+            self._after_advance(n, dt)
 
-        def make_pull(var):
-            def pull():
-                if "arrays" not in cache:
-                    his = scatter_state(layout, variables, Xdd.hi)
-                    los = scatter_state(layout, variables, Xdd.lo)
-                    cache["arrays"] = {
-                        k: (np.asarray(his[k], np.float64)
-                            + np.asarray(los[k], np.float64))
+    def _dd_defer_pull(self, Xdd):
+        """The dd route's `defer_scatter`: the first field of this state
+        that is read pulls hi and lo and sums them in float64 on the host,
+        for all variables (the `dd/pull` span); a state nobody reads pulls
+        nothing."""
+        def fetch():
+            with tracing.span("dd/pull"):
+                his = scatter_state(self.layout, self.variables, Xdd.hi)
+                los = scatter_state(self.layout, self.variables, Xdd.lo)
+                return {k: jnp.asarray(np.asarray(his[k], np.float64)
+                                       + np.asarray(los[k], np.float64))
                         for k in his}
-                var.preset_coeff(jnp.asarray(cache["arrays"][state_key(var)]))
-            return pull
 
-        for v in variables:
-            v.install_pull(make_pull(v))
-        self.snapshot_versions()
-        self.problem.sim_time = self.sim_time
-        self.iteration += n
-        self.dt = dt
-        self.metrics.observe_steps(n)   # dd path: counters only, no probes
-        self.health.tick(n)             # probes the f32 view (dd.X.hi)
-        if self._health_error is None:
-            self.evaluator.evaluate_scheduled(
-                iteration=self.iteration,
-                wall_time=time_mod.time() - self.start_time,
-                sim_time=self.sim_time, timestep=dt)
+        self._install_pulls(fetch)
 
     def _stop_trace(self):
         if self._trace_active:
@@ -989,8 +997,13 @@ class InitialValueSolver(SolverBase):
 
     def _after_advance(self, n, dt):
         """Host bookkeeping after the timestepper advanced n iterations:
-        counters, the cadence-gated probes, scheduled handlers."""
-        self.defer_scatter(self.X)
+        counters, the cadence-gated probes (the phase sampler sits out the
+        dd route; the health probe reads its f32 view), scheduled
+        handlers."""
+        if self._dd is not None:
+            self._dd_defer_pull(self._dd.X)
+        else:
+            self.defer_scatter(self.X)
         self.snapshot_versions()
         self.problem.sim_time = self.sim_time
         self.iteration += n

@@ -48,6 +48,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..tools.jitlift import discovering
+
 __all__ = [
     "DD", "dd_from_f64", "dd_to_f64", "dd_zeros",
     "two_sum", "quick_two_sum", "two_prod",
@@ -253,6 +255,15 @@ def _dd_slices(x, axis, slices):
     for p in range(slices):
         sc = np.float64(2.0 ** (SLICE_BITS * (p + 1)))
         q = jnp.rint(r * sc)                 # |q| <= 64
+        # ... where rint is exact. A TPU's f64 is a float32 pair and its
+        # rint of a value of this size is now and then a whole unit off
+        # (v5e, PR 35): the remainder is then a unit of this plane, the
+        # NEXT q is 128 to 192, and the int8 plane saturates at 127 while
+        # the remainder goes on as if it held the rest — that line keeps
+        # only the planes above (errors of 1e-11 to 1e-4 of the line).
+        # Rounded once more on what is left, where the value is of order
+        # one and rint has nothing to get wrong; adds 0 where it was right
+        q = q + jnp.rint((r - q / sc) * sc)  # |q| <= 65
         planes.append(q.astype(jnp.int8))
         r = r - q / sc                       # exact
     planes = jnp.stack(planes)
@@ -285,7 +296,14 @@ def dd_slices_from_f64(M, slices=DEFAULT_SLICES, axis=-1):
     return planes, (1.0 / s).astype(np.float32)
 
 
+plane_dots_traced = 0   # int8 plane products traced in this process
+
+
 def _plane_dot(ap, bp, dims):
+    global plane_dots_traced
+    # a lifted program's discovery pass traces everything a second time
+    if not discovering():
+        plane_dots_traced += 1
     return jax.lax.dot_general(ap, bp, dims,
                                preferred_element_type=jnp.int32)
 

@@ -293,6 +293,12 @@ class lifted_jit:
             args.insert(pos, val)
         return self.fn(*args)
 
+    def constants(self):
+        """The device constants the programs traced so far take as
+        arguments (as interned: host or device arrays)."""
+        return [_registry.arrays[i] for idxs, _ in self._cache.values()
+                for i in idxs]
+
     def jaxpr(self, *args):
         """ClosedJaxpr of the lifted program body (device constants
         resolve to their interned device arrays, so they appear as jaxpr

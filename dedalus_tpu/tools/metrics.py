@@ -190,6 +190,12 @@ class BuildPhases:
         # which way the first step program applies its `gblocks` stacks
         # (core/curvilinear.gblocks_tally), once it has been lowered
         self.group_stacks = None
+        # which route keeps a float64 problem's guarantee: "dd" (the
+        # emulated-f64 runner, core/ddstep.py; `dd` is then the callable
+        # that gives its counters), "xla_f64" (XLA's own float64: native on
+        # a CPU, software on a TPU), None for a narrower dtype
+        self.f64_route = None
+        self.dd = None
 
     class _Scope:
         def __init__(self, phases, name):
@@ -225,6 +231,9 @@ class BuildPhases:
         out["assembly_cache"] = self.cache
         if self.group_stacks is not None:
             out["group_stacks"] = self.group_stacks
+        out["f64_route"] = self.f64_route
+        if self.dd is not None:
+            out["dd"] = self.dd()
         return out
 
 

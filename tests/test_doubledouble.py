@@ -147,3 +147,32 @@ def test_mass_conservation_grade(rng):
         b = dd.dd_add(b, a)
         b = dd.dd_sub(b, a)
     assert rel_err(dd.dd_to_f64(b), x) < 1e-13
+
+
+@pytest.mark.parametrize("plane", [0, 3, 6])
+def test_slices_survive_a_rint_that_is_a_unit_off(rng, monkeypatch, plane):
+    """A TPU's f64 is a float32 pair and its rint is now and then a whole
+    unit off (v5e, PR 35): the next plane's value is then 128 or more,
+    which int8 cannot hold, and the line lost its lower planes. The
+    slicing rounds a second time on what the first left, so the line is
+    still rebuilt to the last plane's size. Emulated here by a rint that
+    rounds one entry of one plane's FIRST rounding a unit too low."""
+    import jax.numpy as jnp
+    x = rng.standard_normal((6, 5))
+    rint, calls = jnp.rint, []
+
+    def unit_off(v):
+        q = rint(v)
+        calls.append(None)
+        if len(calls) - 1 == 2 * plane:      # two roundings a plane
+            q = q.at[2, 1].add(jnp.where(q[2, 1] > 0, -1.0, 1.0))
+        return q
+
+    monkeypatch.setattr(jnp, "rint", unit_off)
+    planes, inv = dd._dd_slices(dd.dd_from_f64(x), axis=-2, slices=8)
+    planes = np.asarray(planes).astype(np.float64)
+    assert np.abs(planes).max() <= 65
+    rebuilt = np.asarray(inv, np.float64) * sum(
+        planes[p] * 2.0 ** (-7 * (p + 1)) for p in range(8))
+    top = np.abs(x).max(axis=0, keepdims=True)
+    assert (np.abs(rebuilt - x) / top).max() < 2.0 ** -47
