@@ -10,9 +10,12 @@ arithmetic (libraries/doubledouble.py):
 
   * M/L matvecs and the residual matvec of the implicit solve run as
     Ozaki int8 slice matmuls on the MXU (exact int32 accumulation);
-  * the implicit solve is the existing f32 factorization plus dd-residual
+  * the implicit solve is an f32 factorization plus dd-residual
     iterative refinement sweeps (mixed-precision IR: f64-grade solutions
-    for cond(A) well below 1/eps32);
+    for cond(A) well below 1/eps32). The f32 solves inside the sweeps are
+    plain ones: where the solver's own class refines in float32 (a TPU's
+    `BatchedInverseRefined` for 64-bit variables) the runner takes the
+    stored inverse alone, one read of it a solve (`_inner_ops`);
   * the RHS expression tree is evaluated by a dd interpreter mirroring
     the Future.ev protocol: linear operators via their host descriptor
     matrices, Add / pointwise products elementwise, grid<->coeff
@@ -36,6 +39,7 @@ import logging
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Var
 
 from ..libraries import doubledouble
 from ..libraries.doubledouble import (
@@ -43,7 +47,7 @@ from ..libraries.doubledouble import (
     dd_sub, dd_neg, dd_mul, dd_mul_f32, dd_matmul, dd_slices_from_f64,
     dd_zeros)
 from ..tools import tracing
-from ..tools.jitlift import lifted_jit, device_constant
+from ..tools.jitlift import lifted_jit, device_constant, discovering
 
 logger = logging.getLogger(__name__)
 
@@ -335,6 +339,64 @@ def _dd_ev_impl(node, ctx):
 
 # --------------------------------------------------------------- runner
 
+def _inner_ops(ops):
+    """The float32 solver of the dd sweeps' inner solves, from the
+    solver's own dense `ops`. The route never takes a REFINED class by
+    default: the dd sweeps around each solve are the refinement, their
+    residual is float64-grade, and float32 sweeps inside a float32 solve
+    cannot pass cond * 2^-24, which the next dd sweep repeats properly
+    (PERF.md, PR 36: the same 1e-12 after two dd sweeps either way, at 7
+    reads of a (G, S, S) stack a solve against 1). So the class
+    `_dense_matsolver` resolves for 64-bit variables on a TPU,
+    `BatchedInverseRefined`, becomes the plain `BatchedInverse`. Any
+    other class (LU on a CPU) is used as it is, and so is a refined
+    class that a non-native `[precision]` plan asked for: that is the
+    user's own statement."""
+    from ..libraries.matsolvers import BatchedInverseRefined
+    from ..libraries.pencilops import DenseOps
+    cls = ops.solver_cls
+    refined = isinstance(cls, type) and issubclass(cls, BatchedInverseRefined)
+    if refined and ops._solve_plan.dtype == "native":
+        return DenseOps("BatchedInverse", solve_plan=ops._solve_plan)
+    return ops
+
+
+def _stack_reads(jaxpr, stacks, times=1):
+    """How often `jaxpr` reads the variables `stacks`: its equations that
+    take one, those of a sub-program (a jit, a custom call, a loop's body)
+    followed by position, a scan's body times its length."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        held = [i for i, v in enumerate(eqn.invars)
+                if isinstance(v, Var) and v in stacks]
+        if not held:
+            continue
+        subs = [getattr(p, "jaxpr", p) for p in eqn.params.values()
+                if hasattr(p, "eqns") or hasattr(p, "jaxpr")]
+        if not subs:
+            n += times
+        for sub in subs:
+            # a sub-program's inputs are the equation's last ones
+            off = len(eqn.invars) - len(sub.invars)
+            n += _stack_reads(
+                sub, {sub.invars[i - off] for i in held if i >= off},
+                times * eqn.params.get("length", 1))
+    return n
+
+
+def _stack_reads_per_solve(ops, G, S):
+    """Reads of a stored (G, S, S) stack by one float32 `ops.solve`,
+    counted in its jaxpr from shapes alone: 1 for a stored inverse, 7
+    for `BatchedInverseRefined` at its 3 sweeps, 2 for LU."""
+    f32 = jnp.float32
+    aux = jax.eval_shape(ops.factor, jax.ShapeDtypeStruct((G, S, S), f32))
+    jaxpr = jax.make_jaxpr(ops.solve)(
+        aux, jax.ShapeDtypeStruct((G, S), f32)).jaxpr
+    return _stack_reads(jaxpr, {
+        v for v, leaf in zip(jaxpr.invars, jax.tree.leaves(aux))
+        if leaf.ndim == 3})
+
+
 class DDIVPRunner:
     """Advance an InitialValueSolver's IVP in emulated f64 (see module
     docstring). Usage:
@@ -348,6 +410,12 @@ class DDIVPRunner:
     Supports MultistepIMEX and RungeKuttaIMEX schemes (the scheme class
     is taken from the solver's timestepper). The wrapped solver is left
     untouched except by push_state().
+
+    The runner owns the float32 solver of its sweeps, `self.f32`
+    (`_inner_ops`): `solver.ops` itself, or the plain stored inverse
+    where `solver.ops` would refine in float32 by default. `counters()`
+    names the class it met (`f32_solver`) and how many (G, S, S) float32
+    stacks a step reads through it (`f32_stack_reads_per_step`).
     """
 
     def __init__(self, solver, refine=2):
@@ -355,8 +423,12 @@ class DDIVPRunner:
         self.solver = solver
         self.refine = int(refine)
         self.slices = DEFAULT_SLICES
-        # {int8_dots_per_step, plane_MB} of the first step program run
+        # {int8_dots_per_step, plane_MB, f32_stack_reads_per_step} of the
+        # first step program run
         self.program_counts = None
+        # reads of a stored float32 stack by the inner solves traced so
+        # far (a running tally, as doubledouble.plane_dots_traced)
+        self.f32_reads_traced = 0
         ts = solver.timestepper
         if isinstance(ts, MultistepIMEX):
             self.kind = "multistep"
@@ -374,6 +446,12 @@ class DDIVPRunner:
             raise DDUnsupportedError(
                 "DDIVPRunner currently requires the dense pencil path "
                 "(set MATRIX_SOLVER='dense' for emulated-f64 runs).")
+        self.f32 = _inner_ops(ops)
+        if self.f32 is not ops:
+            logger.info(
+                f"dd route: inner float32 solves by "
+                f"{self.f32.solver_cls.__name__} in place of "
+                f"{ops.solver_cls.__name__} (the dd sweeps refine)")
         # host f64 pencil matrices
         self.M_host = np.asarray(solver._matrices["M"], dtype=np.float64)
         self.L_host = np.asarray(solver._matrices["L"], dtype=np.float64)
@@ -531,7 +609,15 @@ class DDIVPRunner:
                    jnp.concatenate(parts_lo, axis=1))
             return dd_mul_f32(F, device_constant(self.mask_np))
 
-        ops = self.solver.ops
+        f32 = self.f32
+        reads_per_solve = _stack_reads_per_solve(f32, *self.shape)
+
+        def solve32(aux32, rhs):
+            """One float32 solve, its stack reads tallied as traced."""
+            if not discovering():
+                self.f32_reads_traced += reads_per_solve
+            return f32.solve(aux32, rhs)
+
         M_planes = _consts.matrix_slices(self.M_host)
         L_planes = _consts.matrix_slices(self.L_host)
 
@@ -564,21 +650,23 @@ class DDIVPRunner:
                 A = build_A_dd(a0, b0)
                 planes, inv = doubledouble._dd_slices(
                     A, axis=-1, slices=self.slices)
-                aux32 = ops.factor(A.hi)
+                aux32 = f32.factor(A.hi)
             return {"planes": planes, "inv": inv, "aux32": aux32}
 
-        def solve_ir(lhs, rhs):
-            """f32 solve + dd-residual iterative refinement. The f32
-            solves keep their own scopes (dense.solve and the solver
-            class's); the sweeps' A x is dd.residual."""
+        def solve_ir(lhs, rhs, refine=self.refine):
+            """f32 solve + dd-residual iterative refinement: `refine`
+            sweeps, each a dd.residual (A x in int8 planes) and one plain
+            f32 solve of what is left. The f32 solves keep their own
+            scopes (dense.solve and the solver class's) and refine
+            nothing themselves (`_inner_ops`)."""
             with jax.named_scope("dedalus/matsolve/dd.refine"):
-                x32 = ops.solve(lhs["aux32"], rhs.hi)
+                x32 = solve32(lhs["aux32"], rhs.hi)
                 x = DD(x32, jnp.zeros_like(x32))
-                for _ in range(self.refine):
+                for _ in range(refine):
                     with jax.named_scope("dedalus/matsolve/dd.residual"):
                         Ax = matvec((lhs["planes"], lhs["inv"]), x)
                     r = dd_sub(rhs, Ax)
-                    dx = ops.solve(lhs["aux32"], r.hi)
+                    dx = solve32(lhs["aux32"], r.hi)
                     x = dd_add(x, DD(dx, jnp.zeros_like(dx)))
             return x
 
@@ -669,6 +757,9 @@ class DDIVPRunner:
             return carry
 
         self._factor = lifted_jit(factor)
+        # the refined solve alone, after any number of sweeps: what the
+        # accuracy checks call (tests/test_ddstep.py), no step does
+        self._solve_ir = lifted_jit(solve_ir, static_argnums=(2,))
         self._step = lifted_jit(step_body)
         self._step_n = lifted_jit(step_n_body, static_argnums=(11,))
         self._rk_factor = lifted_jit(rk_factor)
@@ -709,6 +800,7 @@ class DDIVPRunner:
         if self.program_counts is not None:
             return program(*args)
         dots_before = doubledouble.plane_dots_traced
+        reads_before = self.f32_reads_traced
         out = program(*args)
         planes = {id(a): a.nbytes
                   for a in jax.tree.leaves((args, program.constants()))
@@ -716,12 +808,15 @@ class DDIVPRunner:
         self.program_counts = {
             "int8_dots_per_step":
                 doubledouble.plane_dots_traced - dots_before,
-            "plane_MB": round(sum(planes.values()) / 1e6, 1)}
+            "plane_MB": round(sum(planes.values()) / 1e6, 1),
+            "f32_stack_reads_per_step":
+                self.f32_reads_traced - reads_before}
         return out
 
     def counters(self):
         """What `build_phases.record()` says of this route."""
-        return dict({"slices": self.slices, "refine": self.refine},
+        return dict({"slices": self.slices, "refine": self.refine,
+                     "f32_solver": self.f32.solver_cls.__name__},
                     **(self.program_counts or {}))
 
     def _lhs_for(self, a0, b0, dt):

@@ -405,7 +405,11 @@ class SolverBase:
         # TPU: triangular solves are sequential (slow); a precomputed
         # batched inverse makes every solve one MXU matmul (~65x faster
         # on v5e). TPU LuDecomposition only implements F32/C64, so
-        # 64-bit problems factor in 32-bit + iterative refinement.
+        # 64-bit problems factor in 32-bit + iterative refinement: that
+        # is what steps XLA's software float64 (EMULATED_F64 = never, or
+        # a problem the dd runner refuses). The double-double route
+        # refines by itself and takes the plain inverse for its inner
+        # float32 solves (core/ddstep._inner_ops).
         # Elsewhere (CPU/GPU): LU is accurate and fast.
         if jax.default_backend() == "tpu":
             small = all(np.dtype(v.dtype) in (np.dtype(np.float32),

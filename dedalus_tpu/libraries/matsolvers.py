@@ -48,7 +48,13 @@ def batched_matvec(A, x):
     56% and 27% of `device_ms_per_step`). The multiply-reduce is what
     XLA's own algebraic simplifier turned most of these dots into; it
     reads the stack as it lies. Same arithmetic: f32 products summed in
-    f32, where the dot at `highest` emulates f32 in six bf16 passes."""
+    f32, where the dot at `highest` emulates f32 in six bf16 passes.
+    Still said as dots: `BatchedInverseRefined`'s four `einsum`s. The
+    float64 route's sweeps ran them until PR 36 (42 stack reads a step
+    of rb256x64-f64) and take the plain `BatchedInverse` now
+    (core/ddstep._inner_ops); what is left to them, XLA's software
+    float64 (`EMULATED_F64 = never`) and the `[precision]` ladder, no
+    cell runs."""
     return jnp.sum(A * x[:, None, :], axis=-1)
 
 

@@ -262,6 +262,10 @@ def test_no_int8_product_is_outside_the_dd_scopes(cfg, stepped, lowered):
     assert dd["int8_dots_per_step"] == sum(counts.values())
     G, S = stepped[0].solver.pencil_shape
     assert dd["plane_MB"] >= round(3 * 8 * G * S * S / 1e6, 1)   # M, L, A
+    # the sweeps' float32 solves: the stored inverse alone (PR 36), read
+    # once by each of a stage's first solve and two corrections
+    assert dd["f32_solver"] == "BatchedInverse"
+    assert dd["f32_stack_reads_per_step"] == 2 * 3
 
 
 def test_scan_block_counts_one_step(stepped, blocked):

@@ -194,3 +194,128 @@ def test_rayleigh_benard_dd_matches_f64():
     # tau/pin conditioning at this tiny resolution sets the IR floor at
     # ~1e-10 relative; still ~1000x below the f32 error floor
     assert np.abs(Xdd - X64).max() / np.abs(X64).max() < 1e-9
+
+
+# ------------------------------------------- the sweeps' inner f32 solves
+
+RB_STEPS, RB_DT = 10, 1e-3
+
+
+@pytest.fixture(scope="module")
+def rb_native():
+    """Native float64 RB 32x8 after RB_STEPS steps (LU, the CPU's own)."""
+    from dedalus_tpu.extras.bench_problems import build_rb_solver
+    solver, _ = build_rb_solver(32, 8, np.float64)
+    for _ in range(RB_STEPS):
+        solver.step(RB_DT)
+    return np.asarray(solver.X, dtype=np.float64)
+
+
+def _rb_runner(monkeypatch, inner):
+    """A runner over RB 32x8 built with the class a TPU takes for 64-bit
+    variables, `BatchedInverseRefined`: with the rule's own choice of
+    inner solver (`plain`) or with the rule switched off (`refined`: the
+    float32 solves as they were before PR 36)."""
+    from dedalus_tpu.core import ddstep
+    from dedalus_tpu.extras.bench_problems import build_rb_solver
+    solver, _ = build_rb_solver(32, 8, np.float64,
+                                matsolver="BatchedInverseRefined")
+    if inner == "refined":
+        monkeypatch.setattr(ddstep, "_inner_ops", lambda ops: ops)
+    return DDIVPRunner(solver)
+
+
+def _tracks_native(monkeypatch, rb_native, inner, cls, reads):
+    runner = _rb_runner(monkeypatch, inner)
+    for _ in range(RB_STEPS):
+        runner.step(RB_DT)
+    err = np.abs(runner.state_f64() - rb_native).max() \
+        / np.abs(rb_native).max()
+    assert err < 1e-9     # test_rayleigh_benard_dd_matches_f64's own
+    # RK222: two stages of one first solve and two corrections
+    counted = runner.counters()
+    assert counted["f32_solver"] == cls
+    assert counted["f32_stack_reads_per_step"] == reads
+    assert counted["refine"] == 2 and counted["int8_dots_per_step"] > 0
+
+
+def _worst_pencil_errors(runner, sweeps):
+    """Worst pencil's |x - x*|_inf / |x*|_inf of the refined solve of
+    (M + dt gamma L) x = M X after each count of dd sweeps, x* from
+    numpy.linalg.solve in float64 (PERF.md, PR 36: the table at 256x64)."""
+    from dedalus_tpu.core.ddstep import _dd_scalar
+    from dedalus_tpu.libraries.doubledouble import dd_from_f64, dd_to_f64
+    gamma = float(runner.scheme.H[1, 1])
+    lhs = runner._rk_factor([_dd_scalar(RB_DT * gamma)])[0]
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal(runner.shape)
+    A = runner.M_host + RB_DT * gamma * runner.L_host
+    rhs = np.einsum("gij,gj->gi", A, x)
+    want = np.linalg.solve(A, rhs[..., None])[..., 0]
+    out = []
+    for n in sweeps:
+        got = dd_to_f64(runner._solve_ir(lhs, dd_from_f64(rhs), n))
+        each = np.abs(got - want).max(axis=-1) / np.abs(want).max(axis=-1)
+        out.append(float(each.max()))
+    return out
+
+
+def _sweeps_contract_alike(monkeypatch):
+    sweeps = (0, 1, 2, 3)
+    plain = _worst_pencil_errors(_rb_runner(monkeypatch, "plain"), sweeps)
+    refined = _worst_pencil_errors(_rb_runner(monkeypatch, "refined"),
+                                   sweeps)
+    print("plain", plain, "refined", refined)
+    # an unrefined float32 answer (cond 1e5 and more at this size: the
+    # tau lines), then orders of magnitude a dd sweep down to the floor
+    assert 1e-9 < plain[0] < 1e-1
+    assert plain[1] < 1e-3 * plain[0] and plain[2] <= plain[1]
+    # what the float32 sweeps inside each solve bought after the route's
+    # two dd sweeps: nothing (both at the floor cond * the residual's
+    # rounding sets)
+    assert plain[2] <= 2 * refined[2]
+    assert plain[2] < 1e-9
+
+
+def _the_rule(monkeypatch):
+    from dedalus_tpu.core.ddstep import _inner_ops
+    from dedalus_tpu.libraries import matsolvers, pencilops, solvecomp
+    native = solvecomp.SolvePlan()
+    # the default refinement of a 64-bit TPU build: the route is that
+    refined = pencilops.DenseOps("BatchedInverseRefined", solve_plan=native)
+    assert issubclass(refined.solver_cls, matsolvers.BatchedInverseRefined)
+    inner = _inner_ops(refined)
+    assert inner.solver_cls is matsolvers.BatchedInverse
+    assert inner.kind == "dense" and inner is not refined
+    # any other class is used as it is
+    for name in ("BatchedLUFactorized", "BatchedInverse"):
+        ops = pencilops.DenseOps(name, solve_plan=native)
+        assert _inner_ops(ops) is ops
+    # a [precision] ladder is the user's statement
+    ladder = pencilops.DenseOps(
+        None, solve_plan=solvecomp.SolvePlan(dtype="f32", sweeps=1))
+    assert issubclass(ladder.solver_cls, matsolvers.BatchedInverseRefined)
+    assert _inner_ops(ladder) is ladder
+    # and the runner takes what the rule gives, the solver keeps its own
+    runner = _rb_runner(monkeypatch, "plain")
+    assert runner.f32.solver_cls is matsolvers.BatchedInverse
+    assert issubclass(runner.solver.ops.solver_cls,
+                      matsolvers.BatchedInverseRefined)
+    assert runner.counters()["f32_solver"] == "BatchedInverse"
+
+
+INNER_CASES = {
+    "plain_tracks_f64": lambda mp, ref: _tracks_native(
+        mp, ref, "plain", "BatchedInverse", 6),
+    "refined_tracks_f64": lambda mp, ref: _tracks_native(
+        mp, ref, "refined", "BatchedInverseLadder", 42),
+    "sweeps": lambda mp, ref: _sweeps_contract_alike(mp),
+    "rule": lambda mp, ref: _the_rule(mp),
+}
+
+
+@pytest.mark.parametrize("case", sorted(INNER_CASES))
+def test_inner_float32_solves(case, monkeypatch, rb_native):
+    """The float32 solves inside the dd sweeps are plain stored-inverse
+    products where the solver's class would refine in float32 (PR 36)."""
+    INNER_CASES[case](monkeypatch, rb_native)
