@@ -127,7 +127,7 @@ def report(args):
     records from before any given key existed print with defaults rather
     than crashing. `--last N` restricts to the N most recent parsable
     rows."""
-    from .tools.metrics import format_phase_table
+    from .tools.metrics import format_build_phases, format_phase_table
     path = pathlib.Path(args.jsonl)
     try:
         lines = path.read_text().splitlines()
@@ -168,6 +168,9 @@ def report(args):
             # format_phase_table's first line repeats the sample count
             # already printed in the header above
             for tline in format_phase_table(record, indent="    ")[1:]:
+                print(tline)
+            for tline in format_build_phases(record.get("build_phases"),
+                                             indent="    "):
                 print(tline)
             health = record.get("health")
             if isinstance(health, dict):

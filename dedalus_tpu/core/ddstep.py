@@ -48,6 +48,7 @@ from ..libraries.doubledouble import (
     dd_zeros)
 from ..tools import tracing
 from ..tools.jitlift import lifted_jit, device_constant, discovering
+from ..tools.metrics import build_scope
 
 logger = logging.getLogger(__name__)
 
@@ -95,18 +96,20 @@ class _HostConstCache:
     def matrix_slices(self, M):
         key = id(M)
         if key not in self.slices:
-            A = M.toarray() if hasattr(M, "toarray") else np.asarray(M)
-            self.slices[key] = dd_slices_from_f64(
-                np.asarray(A, dtype=np.float64), axis=-1)
+            with build_scope("dd_prepare"):
+                A = M.toarray() if hasattr(M, "toarray") else np.asarray(M)
+                self.slices[key] = dd_slices_from_f64(
+                    np.asarray(A, dtype=np.float64), axis=-1)
             self._register(self.slices, key, M)
         return self.slices[key]
 
     def dd_pair(self, M):
         key = id(M)
         if key not in self.pairs:
-            A = np.asarray(M.toarray() if hasattr(M, "toarray") else M,
-                           dtype=np.float64)
-            self.pairs[key] = dd_split_host(A)
+            with build_scope("dd_prepare"):
+                A = np.asarray(M.toarray() if hasattr(M, "toarray") else M,
+                               dtype=np.float64)
+                self.pairs[key] = dd_split_host(A)
             self._register(self.pairs, key, M)
         return self.pairs[key]
 
@@ -620,6 +623,10 @@ class DDIVPRunner:
 
         M_planes = _consts.matrix_slices(self.M_host)
         L_planes = _consts.matrix_slices(self.L_host)
+        # the float32 pairs `build_A_dd` takes, split here and not inside
+        # the factor program's first trace: host work of the build
+        _consts.dd_pair(self.M_host)
+        _consts.dd_pair(self.L_host)
 
         def matvec(a_planes, X):
             B = DD(X.hi[..., None], X.lo[..., None])        # (G, S, 1)

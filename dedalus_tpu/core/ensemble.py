@@ -685,8 +685,8 @@ class EnsembleSolver:
                     return (jnp.sum(~jnp.isfinite(x)), jnp.max(ax))
                 with metrics_mod.trace_scope("ensemble", "probe"):
                     return jax.vmap(one)(X)
-            self._probe_prog = jax.jit(
-                retrace_mod.noted(raw, "ensemble/probe"))
+            self._probe_prog = retrace_mod.noted_jit(
+                raw, "ensemble/probe")
         return self._probe_prog(self.X if X is None else X)
 
     # ------------------------------------------------------ factorization

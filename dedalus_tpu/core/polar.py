@@ -23,6 +23,7 @@ TPU-native design:
 import numpy as np
 
 from ..tools.cache import CachedClass, CachedMethod
+from ..tools.metrics import in_build_scope
 from ..libraries import zernike
 from ..tools import jacobi as jacobi_tools
 from .basis import Basis, RealFourier, ComplexFourier, AffineCOV, Jacobi
@@ -257,6 +258,7 @@ class DiskBasis(SpinBasisMixin, Basis):
 
     # ------------------------------------------------- radial matrix stacks
 
+    @in_build_scope("basis_stacks")
     def _build_stack(self, build, rows, cols, align_rows=True, align_cols=True):
         """Assemble (G, rows, cols) stack from per-m builder
         `build(m, nmodes) -> (r, c)`; slot dimensions (align_*=True) are
@@ -339,6 +341,7 @@ class DiskBasis(SpinBasisMixin, Basis):
         return self._build_stack(build, self.Nr, self.Nr)
 
     @CachedMethod
+    @in_build_scope("basis_stacks")
     def laplacian_stack(self, s):
         """(G, Nr, Nr): spin-weighted Laplacian, k -> k+2."""
         up = self.ladder_stack(s, +1)
@@ -574,6 +577,7 @@ class AnnulusBasis(SpinBasisMixin, WeightedJacobiRadial, Basis):
         return out
 
     @CachedMethod
+    @in_build_scope("basis_stacks")
     def ladder_stack(self, s, ds):
         """(G, Nr, Nr): D_{ds} on spin-s components, k -> k+1, in problem
         radius units."""
@@ -587,6 +591,7 @@ class AnnulusBasis(SpinBasisMixin, WeightedJacobiRadial, Basis):
         return stack
 
     @CachedMethod
+    @in_build_scope("basis_stacks")
     def laplacian_stack(self, s):
         """(G, Nr, Nr): spin-weighted Laplacian, k -> k+2."""
         up = self.ladder_stack(s, +1)
@@ -595,6 +600,7 @@ class AnnulusBasis(SpinBasisMixin, WeightedJacobiRadial, Basis):
         return 2 * np.einsum("gij,gjk->gik", down, up)
 
     @CachedMethod
+    @in_build_scope("basis_stacks")
     def interpolation_stack(self, s, position):
         """(G, 1, Nr): evaluate spin-s components at problem radius
         `position`."""

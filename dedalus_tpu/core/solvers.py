@@ -15,6 +15,7 @@ device (reference hot loop anatomy: core/solvers.py:683-711 + SURVEY.md §3.2).
 import os
 import pathlib
 import time as time_mod
+import weakref
 import logging
 import numpy as np
 import scipy.linalg
@@ -49,6 +50,7 @@ class SolverBase:
     lazy_ok = False   # EVP: per-group on-demand assembly at large sizes
     cache_ok = True   # NLBVP: Jacobian rebuilds churn the persistent cache
 
+    @metrics_mod.timed_init
     def __init__(self, problem, matsolver=None, ncc_cutoff=None,
                  matrix_coupling=None, **kw):
         self.problem = problem
@@ -63,18 +65,20 @@ class SolverBase:
         # sparsify defaults), so the value is accepted but currently
         # unused.
         self.ncc_cutoff = ncc_cutoff
-        self.layout = PencilLayout(self.dist, self.variables,
-                                   problem.equations,
-                                   matrix_coupling=matrix_coupling)
-        self.equations = merge_conditional_equations(problem.equations,
-                                                     self.dist, self.layout)
-        self.subproblems = build_subproblems(self.layout)
+        with self.build_phases.scope("layout"):
+            self.layout = PencilLayout(self.dist, self.variables,
+                                       problem.equations,
+                                       matrix_coupling=matrix_coupling)
+            self.equations = merge_conditional_equations(
+                problem.equations, self.dist, self.layout)
+            self.subproblems = build_subproblems(self.layout)
         self._lazy = False
-        # cold-start accounting: host_assembly/structure/factor/compile
-        # wall seconds + assembly-cache verdict (tools/metrics.BuildPhases)
-        self.build_phases = metrics_mod.BuildPhases()
+        # cold-start accounting (tools/metrics.BuildPhases): `timed_init`
+        # made `self.build_phases` and times this whole `__init__`
         self._build_pencil_system()
-        self.valid_row_mask = row_valid_masks(self.layout, self.equations)
+        with self.build_phases.scope("layout"):
+            self.valid_row_mask = row_valid_masks(self.layout,
+                                                  self.equations)
 
     def _build_pencil_system(self):
         """
@@ -143,35 +147,19 @@ class SolverBase:
         cache = assembly_cache.resolve() if self.cache_ok else None
         ckey = None
         if cache is not None:
-            ckey = assembly_cache.solver_key(self, names)
+            with self.build_phases.scope("assembly_cache"):
+                ckey = assembly_cache.solver_key(self, names)
         # content identity of this pencil system, stashed for consumers
         # that key on it after the build (the warm-pool service's
         # assembly_cache.pool_key); None when the cache is disabled or
         # the graph is unfingerprintable — pool_key then recomputes
         self.assembly_key = ckey
         if ckey is not None:
-            payload = cache.load(ckey)
-            if payload is not None:
-                try:
-                    installed = assembly_cache.install_payload(
-                        self, names, payload)
-                except Exception as exc:
-                    # parseable but internally inconsistent (missing
-                    # array, drifted structure state): quarantine and
-                    # assemble fresh — same contract as load-time
-                    # corruption, which must never abort solver builds
-                    installed = False
-                    logger.warning(
-                        f"assembly cache payload {ckey[:12]} failed to "
-                        f"install ({exc!r}); quarantined, assembling fresh")
-                    cache.discard(ckey)
-                if installed:
-                    self.build_phases.cache = "hit"
-                    logger.info(
-                        f"Pencil system: assembly cache hit "
-                        f"({payload['meta']['kind']}, key {ckey[:12]})")
-                    return
-            self.build_phases.cache = "miss"
+            with self.build_phases.scope("assembly_cache"):
+                installed = self._cache_install(cache, ckey, names)
+            self.build_phases.cache = "hit" if installed else "miss"
+            if installed:
+                return
         self._assemble_batched(names)
         spec = self.matsolver if isinstance(self.matsolver, str) else ""
         forced = spec.lower() if spec.lower() in ("banded", "dense") else None
@@ -221,16 +209,41 @@ class SolverBase:
             solve_plan=getattr(self, "_solve_plan", None))
         self._cache_store(cache, ckey, names)
 
+    def _cache_install(self, cache, ckey, names):
+        """Load the cached pencil system and install it (the dense scatter
+        of a cached COO store with it); False on a miss."""
+        payload = cache.load(ckey)
+        if payload is None:
+            return False
+        try:
+            installed = assembly_cache.install_payload(self, names, payload)
+        except Exception as exc:
+            # parseable but internally inconsistent (missing array,
+            # drifted structure state): quarantine and assemble fresh —
+            # same contract as load-time corruption, which must never
+            # abort solver builds
+            installed = False
+            logger.warning(
+                f"assembly cache payload {ckey[:12]} failed to "
+                f"install ({exc!r}); quarantined, assembling fresh")
+            cache.discard(ckey)
+        if installed:
+            logger.info(
+                f"Pencil system: assembly cache hit "
+                f"({payload['meta']['kind']}, key {ckey[:12]})")
+        return bool(installed)
+
     def _cache_store(self, cache, ckey, names):
         """Persist the freshly built pencil system (miss path only)."""
         if cache is None or ckey is None:
             return
-        try:
-            exported = assembly_cache.export_payload(self, names)
-            if exported is not None:
-                cache.store(ckey, *exported)
-        except Exception as exc:
-            logger.warning(f"assembly cache store failed: {exc!r}")
+        with self.build_phases.scope("assembly_cache"):
+            try:
+                exported = assembly_cache.export_payload(self, names)
+                if exported is not None:
+                    cache.store(ckey, *exported)
+            except Exception as exc:
+                logger.warning(f"assembly cache store failed: {exc!r}")
 
     def _assemble_batched(self, names):
         """Attempt group-batched assembly; sets self._batched to the shared
@@ -312,12 +325,13 @@ class SolverBase:
         scale = 0.0
         if self._batched is not None:
             pr, pc, bvals, row_valid_b, col_valid_b = self._batched
-            for g in range(len(self.subproblems)):
-                coo_store.append({name: (pr, pc, bvals[name][g])
-                                  for name in names})
-                masks.append((row_valid_b[g], col_valid_b[g]))
-            scale = max((np.abs(bvals[name]).max() if bvals[name].size else 0.0)
-                        for name in names)
+            with self.build_phases.scope("pattern"):
+                for g in range(len(self.subproblems)):
+                    coo_store.append({name: (pr, pc, bvals[name][g])
+                                      for name in names})
+                    masks.append((row_valid_b[g], col_valid_b[g]))
+                scale = max((np.abs(bvals[name]).max()
+                             if bvals[name].size else 0.0) for name in names)
         else:
             from .subsystems import map_groups
             with self.build_phases.scope("host_assembly"):
@@ -642,6 +656,7 @@ class InitialValueSolver(SolverBase):
 
     matrices = ("M", "L")
 
+    @metrics_mod.timed_init
     def __init__(self, problem, timestepper, matsolver=None,
                  enforce_real_cadence=100, warmup_iterations=10,
                  profile=None, profile_directory=None, metrics=None,
@@ -654,14 +669,17 @@ class InitialValueSolver(SolverBase):
                                             self.pencil_dtype)
             self.L_mat = self.ops.to_device(self._matrices["L"],
                                             self.pencil_dtype)
-        self.eval_F = self.build_rhs_evaluator("F", time_field=problem.time)
+        with self.build_phases.scope("plans"):
+            self.eval_F = self.build_rhs_evaluator(
+                "F", time_field=problem.time)
         # fused RHS operator chains (core/fusedstep.py FUSED_TRANSFORMS):
         # foldable linear-operator nodes get host-precomposed
         # backward-MMT @ operator composite GEMMs, persisted through the
         # assembly cache; None when transform fusion is off or nothing
         # folds. Read at trace time via EvalContext.fusion.
         from . import fusedstep
-        self._fused_eval_plan = fusedstep.build_eval_plan(self)
+        with self.build_phases.scope("plans"):
+            self._fused_eval_plan = fusedstep.build_eval_plan(self)
         # timestepping state
         self.sim_time = 0.0
         self.initial_sim_time = 0.0
@@ -694,7 +712,8 @@ class InitialValueSolver(SolverBase):
                     "EMULATED_F64", "auto").lower() != "never"):
             from .ddstep import DDIVPRunner, DDUnsupportedError
             try:
-                self._dd = DDIVPRunner(self)
+                with self.build_phases.scope("dd_prepare"):
+                    self._dd = DDIVPRunner(self)
                 logger.info("float64 on accelerator: emulated-f64 "
                             "(double-double) step path active")
             except DDUnsupportedError as exc:
@@ -703,7 +722,7 @@ class InitialValueSolver(SolverBase):
         # which route keeps a float64 problem's guarantee, for the records
         if self._dd is not None:
             self.build_phases.f64_route = "dd"
-            self.build_phases.dd = self._dd.counters
+            self.build_phases.dd = weakref.WeakMethod(self._dd.counters)
         elif np.dtype(self.pencil_dtype) in (np.float64, np.complex128):
             self.build_phases.f64_route = "xla_f64"
         # Profiling (reference: core/solvers.py:546-561,780-806 cProfile
@@ -840,7 +859,7 @@ class InitialValueSolver(SolverBase):
                 dd.step(dt)
             self.X = dd.X.hi   # f32 view: finite checks, health probe
             self.sim_time = dd.sim_time
-            self._book_compile(first)
+            self._book_group_stacks(first)
             self._after_advance(n, dt)
 
     def _dd_defer_pull(self, Xdd):
@@ -935,33 +954,24 @@ class InitialValueSolver(SolverBase):
         # the whole host side of one iteration, not only the launch
         with tracing.span("step", {"iteration": self.iteration}):
             self.timestepper.step(dt)
-            self._book_compile(first)
+            self._book_group_stacks(first)
             self._after_advance(1, dt)
 
     def _first_advance(self):
-        """(start, factor seconds so far, `gblocks` applications traced so
-        far) before the run's first advance, None before any later one."""
-        if "compile" in self.build_phases.seconds:
-            return None
-        return (time_mod.perf_counter(),
-                self.build_phases.seconds.get("factor", 0.0),
-                gblocks_snapshot())
+        """Entering an advance: this solver's build phases become the
+        thread's current ones (the set-up ledger books the programs first
+        called from here to it: `compile_sec`). Returns the `gblocks`
+        applications traced so far before the run's first advance, None
+        before any later one."""
+        phases = self.build_phases
+        phases.enter()
+        return gblocks_snapshot() if phases.group_stacks is None else None
 
-    def _book_compile(self, first):
-        """After the first advance: trace + lower + XLA compile of the
-        step program dominate it; recorded as the cold-start `compile`
-        phase, less the first factorization, which the timestepper books
-        under `factor` (timesteppers._ensure_lhs). With it, which way the
-        step program applies its `gblocks` stacks
-        (curvilinear.gblocks_tally)."""
-        if first is None:
-            return
-        start, factor_before, gblocks_before = first
-        jax.block_until_ready(self.X)
-        factored = self.build_phases.seconds.get("factor", 0.0) - factor_before
-        self.build_phases.add(
-            "compile", time_mod.perf_counter() - start - factored)
-        self.build_phases.group_stacks = gblocks_tally(since=gblocks_before)
+    def _book_group_stacks(self, first):
+        """After the first advance: which way the step program applies
+        its `gblocks` stacks (curvilinear.gblocks_tally)."""
+        if first is not None:
+            self.build_phases.group_stacks = gblocks_tally(since=first)
 
     def step_many(self, n, dt):
         """
@@ -995,7 +1005,7 @@ class InitialValueSolver(SolverBase):
         first = self._first_advance()
         with tracing.span("step_many", {"iteration": self.iteration, "n": n}):
             self.timestepper.step_many(n, dt)
-            self._book_compile(first)
+            self._book_group_stacks(first)
             self.metrics.inc("step_many_blocks")
             self._after_advance(n, dt)
 
@@ -1394,12 +1404,8 @@ class InitialValueSolver(SolverBase):
         logger.info(f"Final sim time: {self.sim_time}")
         logger.info(f"Setup time (init - iter 0): {self.start_time - self.init_time:{format}} sec")
         bp = self.build_phases.record()
-        logger.info(
-            f"Build phases: host_assembly {bp['host_assembly_sec']:{format}}"
-            f" s, structure {bp['structure_sec']:{format}} s, factor "
-            f"{bp['factor_sec']:{format}} s, compile "
-            f"{bp['compile_sec']:{format}} s "
-            f"(assembly cache: {bp['assembly_cache']})")
+        for line in metrics_mod.format_build_phases(bp, format):
+            logger.info(line)
         phases = {"setup": self._setup_time,
                   "total": total}
         if self.iteration > self.warmup_iterations and self.warmup_time:
@@ -1444,6 +1450,7 @@ class LinearBoundaryValueSolver(SolverBase):
 
     matrices = ("L",)
 
+    @metrics_mod.timed_init
     def __init__(self, problem, matsolver=None, **kw):
         super().__init__(problem, matsolver=matsolver, **kw)
         with self.build_phases.scope("factor"):
@@ -1468,6 +1475,7 @@ class LinearBoundaryValueSolver(SolverBase):
     def solve(self):
         """Solve L.X = F with current NCC/RHS fields
         (reference: core/solvers.py:369)."""
+        self.build_phases.enter()
         X0 = self.gather_fields()
         X = self._rhs_solve(self._aux, X0, self.rhs_extra())
         self.scatter_fields(X)
@@ -1483,6 +1491,7 @@ class NonlinearBoundaryValueSolver(SolverBase):
     # persisting each one would churn the on-disk cache for zero reuse.
     cache_ok = False
 
+    @metrics_mod.timed_init
     def __init__(self, problem, matsolver=None, **kw):
         # Matrices are in terms of the perturbation variables.
         self._problem_ref = problem
@@ -1525,6 +1534,7 @@ class NonlinearBoundaryValueSolver(SolverBase):
         (reference: core/solvers.py:470)."""
         # Rebuild Jacobian matrices around the current state (NCC data moves;
         # the structural path is re-selected since the pattern can change).
+        self.build_phases.enter()
         self._build_pencil_system()
         L = self.ops.to_device(self._matrices["L"], self.pencil_dtype)
         aux = self.ops.factor(L)
@@ -1557,6 +1567,7 @@ class EigenvalueSolver(SolverBase):
     matrices = ("M", "L")
     lazy_ok = True
 
+    @metrics_mod.timed_init
     def __init__(self, problem, matsolver=None, **kw):
         super().__init__(problem, matsolver=matsolver, **kw)
         self.eigenvalues = None
@@ -1610,6 +1621,7 @@ class EigenvalueSolver(SolverBase):
         (reference: core/solvers.py:180 solve_dense). `rebuild_matrices`
         reassembles M/L around the current NCC field data (parameter
         continuation, e.g. the Mathieu example's q sweep)."""
+        self.build_phases.enter()
         if rebuild_matrices:
             # parameter-continuation rebuilds change the NCC data every
             # call: each would hash to a never-reloaded fresh cache key,
@@ -1646,6 +1658,7 @@ class EigenvalueSolver(SolverBase):
                      rebuild_matrices=False, **kw):
         """Sparse shift-invert eigensolve around `target`
         (reference: core/solvers.py:225 solve_sparse)."""
+        self.build_phases.enter()
         from ..tools.array import scipy_sparse_eigs
         if rebuild_matrices:
             # see solve_dense: continuation rebuilds must not churn the

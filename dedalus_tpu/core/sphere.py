@@ -22,6 +22,7 @@ TPU-native design (mirrors core/polar.py DiskBasis):
 import numpy as np
 
 from ..tools.cache import CachedMethod
+from ..tools.metrics import in_build_scope
 from ..libraries import sphere as swsh
 from .basis import Basis
 from .coords import S2Coordinates, SphericalCoordinates
@@ -179,6 +180,7 @@ class SphereBasis(SpinBasisMixin, Basis):
 
     # ------------------------------------------- colatitude matrix stacks
 
+    @in_build_scope("basis_stacks")
     def _build_stack(self, build, rows, cols, row_off=None, col_off=None):
         """Assemble (G, rows, cols) stack from per-m builder
         `build(m) -> (r, c)`; `row_off(m)` / `col_off(m)` give the slot
@@ -231,6 +233,7 @@ class SphereBasis(SpinBasisMixin, Basis):
             col_off=lambda m: self._lmin(m, s))
 
     @CachedMethod
+    @in_build_scope("basis_stacks")
     def laplacian_stack(self, s):
         """(G, Ntheta, Ntheta): spin-weighted Laplacian, diagonal with
         eigenvalues -(l(l+1) - s^2)/r^2."""

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from itertools import product as iter_product
 
 from ..tools.cache import CachedMethod, cached_function
+from ..tools.metrics import in_build_scope
 from ..tools import jacobi as jacobi_tools
 from ..tools.array import match_precision
 from ..libraries import sphere as swsh
@@ -320,6 +321,7 @@ class ShellBasis(WeightedJacobiRadial, Basis):
         return l, l >= 0
 
     @CachedMethod
+    @in_build_scope("basis_stacks")
     def dplus_stack(self, regtotal):
         """D+ = d/dr - l/r at l = ell + regtotal, k -> k+1."""
         l, ok = self._ell_l(regtotal)
@@ -329,6 +331,7 @@ class ShellBasis(WeightedJacobiRadial, Basis):
         return stack
 
     @CachedMethod
+    @in_build_scope("basis_stacks")
     def dminus_stack(self, regtotal):
         """D- = d/dr + (l+1)/r at l = ell + regtotal, k -> k+1."""
         l, ok = self._ell_l(regtotal)
@@ -338,6 +341,7 @@ class ShellBasis(WeightedJacobiRadial, Basis):
         return stack
 
     @CachedMethod
+    @in_build_scope("basis_stacks")
     def laplacian_reg_stack(self, regtotal):
         """L = D-(l+1) @ D+(l) at l = ell + regtotal, k -> k+2
         (reference: core/basis.py:3855 operator_matrix 'L')."""
@@ -356,6 +360,7 @@ class ShellBasis(WeightedJacobiRadial, Basis):
         return col
 
     @CachedMethod
+    @in_build_scope("basis_stacks")
     def interp_stack(self, regtotal, position):
         """(Ntheta, 1, Nr): boundary evaluation rows (ell-independent on the
         shell; per-ell on the ball)."""
@@ -595,6 +600,7 @@ class BallBasis(Basis):
     # (Ntheta, rows, cols) stacks over the ell groups; slot dimensions are
     # right-aligned at nmin(ell).
 
+    @in_build_scope("basis_stacks")
     def _build_ell_stack(self, build, rows, cols, align_rows=True,
                          align_cols=True):
         out = np.zeros((self.Ntheta, rows, cols))

@@ -22,6 +22,7 @@ from . import meshctx
 from ..tools.array import zeropad
 
 from ..tools.array import apply_matrix_jax
+from ..tools import metrics as metrics_mod
 from ..tools.metrics import scoped as _scoped
 
 # Registry: {(basis_class_name, library): plan_class}
@@ -36,6 +37,7 @@ def register_transform(basis_cls_name, name):
     return wrapper
 
 
+@metrics_mod.in_build_scope("plans")
 def get_plan(basis, scale, library=None):
     """Build a transform plan. Callers go through Basis.transform_plan
     (@CachedMethod), so plans — and the host matrices they own, which the

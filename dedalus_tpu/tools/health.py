@@ -288,7 +288,7 @@ class HealthMonitor:
         # noted(): the probe participates in the retrace sentinel like the
         # lifted_jit step programs (tools/retrace.py)
         from . import retrace as retrace_mod
-        self._probe = jax.jit(retrace_mod.noted(probe, "health/probe"))
+        self._probe = retrace_mod.noted_jit(probe, "health/probe")
         return self._probe
 
     # ------------------------------------------------------------- ticks
@@ -377,8 +377,8 @@ class HealthMonitor:
                     return total
             # memoized on self just above (one wrapper per monitor, so
             # the retrace sentinel counts real signature churn only)
-            probe = self._value_probe = jax.jit(  # dedalus-lint: disable=DTL003
-                retrace_mod.noted(raw, "health/values"))
+            probe = self._value_probe = retrace_mod.noted_jit(
+                raw, "health/values")
         return probe
 
     def nonfinite_count(self, tree, phase="values"):

@@ -32,6 +32,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import metrics as metrics_mod
 from . import retrace as retrace_mod
 
 __all__ = ["device_constant", "discovering", "lifted_jit", "tracing_active",
@@ -150,7 +151,9 @@ class _Registry:
         called outside a trace."""
         val = self.arrays[idx]
         if isinstance(val, np.ndarray):
-            converted = jnp.asarray(val)
+            # host -> device, once per constant (build phase `upload`)
+            with metrics_mod.build_scope("upload"):
+                converted = jnp.asarray(val)
             # never cache a tracer: belt (probe) AND suspenders (type
             # check), so a degraded never-tracing probe cannot poison the
             # process-global registry from inside a foreign trace
@@ -269,6 +272,19 @@ class lifted_jit:
         key = (static, _signature(dynamic))
         entry = self._cache.get(key)
         if entry is None:
+            return self._first_call(key, static, dynamic, len(args))
+        idxs, jfn = entry
+        return jfn([_registry.device_value(i) for i in idxs], *dynamic)
+
+    def _first_call(self, key, static, dynamic, n_args):
+        """The first call of a signature: discovery pass, the jit wrapper,
+        the first launch (trace, lowering, compile or cache load),
+        bracketed as ONE row of the set-up ledger (tools/retrace.py). The
+        upload of the constants it found is the build phase `upload`
+        (`_Registry.device_value`), which pauses the row."""
+        row = retrace_mod.sentinel.open_row(
+            self._retrace_state, metrics_mod.current_phases())
+        try:
             touched = set()
             with _Mode("discover", touched):
                 jax.eval_shape(lambda *d: self._call_fn(static, d), *dynamic)
@@ -280,12 +296,15 @@ class lifted_jit:
                 with _Mode("substitute", dict(zip(idxs, consts))):
                     return self._call_fn(static, d)
 
-            donate = self._donate_positions(len(args)) \
+            donate = self._donate_positions(n_args) \
                 if self.donate_argnums else ()
-            entry = self._cache[key] = (
-                idxs, jax.jit(wrapped, donate_argnums=donate))
-        idxs, jfn = entry
-        return jfn([_registry.device_value(i) for i in idxs], *dynamic)
+            # memoized in self._cache on the next line
+            jfn = jax.jit(wrapped, donate_argnums=donate)  # dedalus-lint: disable=DTL003
+            self._cache[key] = (idxs, jfn)
+            row.discovered()
+            return jfn([_registry.device_value(i) for i in idxs], *dynamic)
+        finally:
+            row.close()
 
     def _call_fn(self, static, dynamic):
         args = list(dynamic)

@@ -74,6 +74,10 @@ noise-band regressions per series (docs/observability.md).
 """
 
 import atexit
+import collections
+import contextlib
+import functools
+import itertools
 import json
 import os
 import signal
@@ -84,6 +88,7 @@ import weakref
 import numpy as np
 import jax
 
+from . import retrace
 from . import tracing
 from .config import config
 from .lint.threadcheck import named_lock
@@ -113,11 +118,13 @@ SUM_PHASES = ("transform", "matsolve", "transpose", "evaluator")
 # benchmarks/scaling.py measures and records them per device count.
 PHASES = SUM_PHASES + ("fused", "transpose_exposed", "transpose_overlapped")
 
-# The cold-start (build) phase vocabulary: host-side symbolic assembly,
-# banded structural analysis, device transfer + factorization, and the
-# first-dispatch trace/compile. Labels double as `dedalus/build/...`
-# trace annotations so profiler rows and telemetry share one vocabulary.
-BUILD_PHASES = ("host_assembly", "structure", "factor", "compile")
+# The cold-start (build) phase vocabulary (BuildPhases has each one's
+# meaning). Labels double as `build/<name>` spans and `dedalus/build/...`
+# profiler rows, so traces and telemetry share one vocabulary. `compile`
+# is not among them: it is the sum of the solver's program rows.
+BUILD_PHASES = ("layout", "assembly_cache", "host_assembly", "pattern",
+                "structure", "factor", "basis_stacks", "plans", "upload",
+                "dd_prepare")
 
 
 def trace_scope(phase, detail=None):
@@ -174,17 +181,64 @@ class CadenceGate:
 
 class BuildPhases:
     """
-    Wall-clock accounting of the solver BUILD (cold-start) phases, the
-    setup-side sibling of the step-loop PhaseTimer: `scope(name)` brackets
-    one phase (accumulating across re-entries, e.g. Newton rebuilds) and
-    annotates the region `dedalus/build/<name>` for profiler traces.
+    Wall-clock accounting of one solver's BUILD (cold start), the set-up
+    sibling of the step-loop PhaseTimer. Always on; nothing per step.
+
+    `init()` brackets the solver's whole `__init__` (`init_sec`;
+    `timed_init` wraps every solver class's). `scope(name)` brackets one
+    phase, accumulating across re-entries (Newton rebuilds), and is a
+    `build/<name>` span (and, where `tracing.live()`, a
+    `dedalus/build/<name>` row on the profiler's host plane). Phases are
+    FLAT AND EXCLUSIVE: a scope opened inside another takes its time out
+    of it, and one opened inside a program's first call (a stack built the
+    first time a trace asks for it) out of that row. The names
+    (`BUILD_PHASES`):
+
+        layout          pencil layout, subproblems, validity masks
+        assembly_cache  the persistent assembly cache: key, load and
+                        install (the dense scatter of a cached COO store
+                        with it) or export and store
+
+        host_assembly   symbolic assembly of the pencil matrices
+        pattern         the banded attempt's per-group views of the
+                        assembled entries and their magnitude scale
+        structure       the banded structural analysis
+        factor          upload of M and L + the run's first factorization
+                        with its program's compile or cache load, waited for
+        basis_stacks    the curvilinear bases' host-built per-m / per-ell
+                        matrix stacks (sphere, disk, annulus, shell, ball)
+        plans           transform plans and the fused evaluator's plan
+        upload          host -> device copies that are not M's and L's:
+                        every program's lifted constants (stacks, planes,
+                        masks) on their first use
+        dd_prepare      the float64 route's host work: float64 copies of M
+                        and L, their int8 planes and float32 pairs, the
+                        double-double state (core/ddstep.py)
+
+    `compile` is no scope: `compile_sec` is the sum of `first_call_sec`
+    over the program rows this solver owns (tools/retrace.py: the thread's
+    current BuildPhases when a program was first called), every program to
+    date, and may overlap `factor` (the factor program's first call).
+
+    "Current" is the BuildPhases whose `init()` is open on this thread,
+    else the one whose `init()` closed or that called `enter()` (a
+    solver's `step`, `step_many`, `solve`) last: `build_scope(name)` books
+    there, or to the process-level `process_phases()` where the thread has
+    none (a basis building a stack before any solver exists).
+
     `record()` flattens to the `<name>_sec` keys telemetry records and
-    bench rows carry (`host_assembly_sec`, `structure_sec`, `factor_sec`,
-    `compile_sec`), plus the assembly-cache verdict and, once the first
-    step program is lowered, its `group_stacks` tally.
+    bench rows carry, plus `init_sec`, `unnamed_sec` (= `init_sec` less
+    everything named inside `init()`: the phases and, outside any phase,
+    the first calls of programs, `init_compile_sec`), the assembly-cache
+    verdict, the float64 route, the first step program's `group_stacks`
+    tally, and `programs`: this solver's totals of the set-up ledger and
+    its twelve rows with the largest `first_call_sec`.
     """
 
-    def __init__(self):
+    TOP_ROWS = 12
+
+    def __init__(self, owner="process"):
+        self.name = f"{owner}#{next(_phase_serial)}"
         self.seconds = {}
         self.cache = "off"   # off | miss | hit
         # which way the first step program applies its `gblocks` stacks
@@ -192,10 +246,49 @@ class BuildPhases:
         self.group_stacks = None
         # which route keeps a float64 problem's guarantee: "dd" (the
         # emulated-f64 runner, core/ddstep.py; `dd` is then the callable
-        # that gives its counters), "xla_f64" (XLA's own float64: native on
-        # a CPU, software on a TPU), None for a narrower dtype
+        # that gives its counters, held weakly: this object outlives its
+        # solver in the process's list), "xla_f64" (XLA's own float64:
+        # native on a CPU, software on a TPU), None for a narrower dtype
         self.f64_route = None
         self.dd = None
+        self.init_sec = 0.0
+        self._init_depth = 0
+        self._named_in_init = 0.0
+        self.init_compile_sec = 0.0
+        self._rows = []        # the TOP_ROWS largest rows this solver owns
+        self._row_totals = dict.fromkeys(retrace.ROW_SECONDS, 0.0)
+        self._row_counts = {"programs": 0, "cache_hits": 0,
+                            "cache_misses": 0}
+        if owner != "process":
+            _all_phases.append(self)
+
+    # ------------------------------------------------------------ current
+
+    def enter(self):
+        """Make this the thread's current BuildPhases: the entry points'
+        ONE assignment (`step`, `step_many`, `solve`)."""
+        _current.phases = self
+
+    @contextlib.contextmanager
+    def init(self):
+        """Bracket a solver's `__init__`: `init_sec` (the outermost of a
+        class chain's), with this the thread's current BuildPhases."""
+        prev = getattr(_current, "phases", None)
+        _current.phases = self
+        self._init_depth += 1
+        t0 = time.perf_counter()
+        try:
+            yield self
+        finally:
+            self._init_depth -= 1
+            if not self._init_depth:
+                self.init_sec += time.perf_counter() - t0
+                # built inside another solver's __init__: that one goes
+                # on; else what follows (initial conditions) is this one's
+                if prev is not None and prev._init_depth:
+                    _current.phases = prev
+
+    # ------------------------------------------------------------- phases
 
     class _Scope:
         def __init__(self, phases, name):
@@ -203,38 +296,153 @@ class BuildPhases:
             self.name = name
 
         def __enter__(self):
-            self.ann = annotate(f"dedalus/build/{self.name}")
-            self.ann.__enter__()
             # child span under the ambient trace (the server's
-            # pool_acquire span when a cold build runs inside a request)
+            # pool_acquire span when a cold build runs inside a request);
+            # a live span is its own `dedalus/build/<name>` profiler row
             self.span = tracing.span(f"build/{self.name}")
             self.span.__enter__()
+            self.inner = 0.0
+            _open_scopes().append(self)
+            # a phase inside a program's first call is the phase's time
+            self.pause = retrace.pause_row()
+            self.pause.__enter__()
             self.t0 = time.perf_counter()
             return self
 
         def __exit__(self, *exc):
             dt = time.perf_counter() - self.t0
-            sec = self.phases.seconds
-            sec[self.name] = sec.get(self.name, 0.0) + dt
-            self.span.__exit__(*exc)
-            return self.ann.__exit__(*exc)
+            self.pause.__exit__(*exc)
+            stack = _open_scopes()
+            if stack and stack[-1] is self:
+                stack.pop()
+            if stack:
+                stack[-1].inner += dt    # exclusive: the parent loses it
+            self.phases.add(self.name, max(dt - self.inner, 0.0))
+            return self.span.__exit__(*exc)
 
     def scope(self, name):
         return self._Scope(self, name)
 
     def add(self, name, seconds):
-        self.seconds[name] = self.seconds.get(name, 0.0) + float(seconds)
+        seconds = float(seconds)
+        self.seconds[name] = self.seconds.get(name, 0.0) + seconds
+        if self._init_depth:
+            self._named_in_init += seconds
+
+    def book_program(self, row):
+        """One closed row of the set-up ledger that this solver owns
+        (tools/retrace.ProgramRow.close)."""
+        for key in retrace.ROW_SECONDS:
+            self._row_totals[key] += row[key]
+        self._row_counts["programs"] += 1
+        if row["cache"] == "hit":
+            self._row_counts["cache_hits"] += 1
+        elif row["cache"] == "miss":
+            self._row_counts["cache_misses"] += 1
+        self._rows.append(row)
+        self._rows.sort(key=lambda r: -r["first_call_sec"])
+        del self._rows[self.TOP_ROWS:]
+        if self._init_depth and not _open_scopes():
+            # inside __init__ and under no phase: named by the row
+            self.init_compile_sec += row["first_call_sec"]
+            self._named_in_init += row["first_call_sec"]
+
+    @property
+    def compile_sec(self):
+        return self._row_totals["first_call_sec"]
+
+    def programs(self):
+        """This solver's totals of the set-up ledger, the process's eager
+        aggregate, and its largest rows."""
+        out = dict(self._row_counts)
+        out.update({k: round(v, 4) for k, v in self._row_totals.items()})
+        out["eager"] = retrace.sentinel.eager_totals()
+        out["rows"] = [dict(r) for r in self._rows]
+        return out
 
     def record(self):
         out = {f"{name}_sec": round(self.seconds.get(name, 0.0), 4)
                for name in BUILD_PHASES}
+        out["compile_sec"] = round(self.compile_sec, 4)
+        out["init_sec"] = round(self.init_sec, 4)
+        out["init_compile_sec"] = round(self.init_compile_sec, 4)
+        out["unnamed_sec"] = round(
+            max(self.init_sec - self._named_in_init, 0.0), 4)
         out["assembly_cache"] = self.cache
         if self.group_stacks is not None:
             out["group_stacks"] = self.group_stacks
         out["f64_route"] = self.f64_route
-        if self.dd is not None:
-            out["dd"] = self.dd()
+        dd = self.dd() if self.dd is not None else None
+        if dd is not None:
+            out["dd"] = dd()
+        out["programs"] = self.programs()
         return out
+
+
+_phase_serial = itertools.count(1)
+_current = threading.local()    # .phases: current BuildPhases; .scopes
+# every solver's BuildPhases of the process, newest last. Bounded, and held
+# strongly: a BuildPhases holds no reference to its solver, and a reader
+# after the fact wants the seconds of solvers that are gone (the LBVP a
+# configuration solves on its way to the IVP)
+_all_phases = collections.deque(maxlen=256)
+
+
+def _open_scopes():
+    stack = getattr(_current, "scopes", None)
+    if stack is None:
+        stack = _current.scopes = []
+    return stack
+
+
+_process_phases = BuildPhases()
+
+
+def current_phases():
+    """The thread's current BuildPhases (see BuildPhases), or None."""
+    return getattr(_current, "phases", None)
+
+
+def process_phases():
+    """Where `build_scope` books when the thread has no current solver."""
+    return _process_phases
+
+
+def all_phases():
+    """The BuildPhases of every solver this process built (the newest
+    256), oldest first; `name` is `<SolverClass>#<n>`."""
+    return list(_all_phases)
+
+
+def build_scope(name):
+    """`scope(name)` of the thread's current BuildPhases, else of the
+    process-level one: for set-up code with no solver in reach."""
+    return (current_phases() or _process_phases).scope(name)
+
+
+def in_build_scope(name):
+    """Decorator: the function's calls run inside `build_scope(name)`."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            with build_scope(name):
+                return fn(*args, **kwargs)
+        return wrapper
+    return decorate
+
+
+def timed_init(init):
+    """Decorator for a solver class's `__init__`: the whole of it runs
+    inside `self.build_phases.init()` (created here, under the class's
+    name, by the first `__init__` of a chain to run)."""
+    @functools.wraps(init)
+    def wrapper(self, *args, **kwargs):
+        phases = self.__dict__.get("build_phases")
+        if phases is None:
+            phases = self.build_phases = BuildPhases(type(self).__name__)
+        with phases.init():
+            return init(self, *args, **kwargs)
+    return wrapper
 
 
 class Counter:
@@ -660,6 +868,43 @@ def resolve(spec=None, sink=None, cadence=None, meta=None):
         sink = section.get("METRICS_FILE", "").strip() or None
     return Metrics(sample_cadence=cadence, sink=sink, enabled=enabled,
                    meta=meta)
+
+
+def format_build_phases(bp, format=".4g", indent=""):
+    """A `BuildPhases.record()` as text lines (used by `log_stats` and the
+    `report` CLI): the phases with what `init_sec` leaves unnamed, then
+    the set-up ledger's totals with the three largest program rows.
+    Records from before a key existed print what they have."""
+    if not bp:
+        return []
+    named = [f"{name} {bp[f'{name}_sec']:{format}}" for name in BUILD_PHASES
+             if bp.get(f"{name}_sec")]
+    line = f"{indent}Build phases: " + (", ".join(named) or "none") + " s"
+    if "init_sec" in bp:
+        line += (f"; init {bp['init_sec']:{format}} s, unnamed "
+                 f"{bp.get('unnamed_sec', 0.0):{format}} s")
+    line += (f"; compile {bp.get('compile_sec', 0.0):{format}} s "
+             f"(assembly cache: {bp.get('assembly_cache', '?')})")
+    lines = [line]
+    prog = bp.get("programs")
+    if prog:
+        top = ", ".join(
+            f"{r['label']} {r['first_call_sec']:{format}} s ({r['cache']})"
+            for r in prog.get("rows", [])[:3])
+        lines.append(
+            f"{indent}Programs: {prog.get('programs', 0)} first calls "
+            f"{prog.get('first_call_sec', 0.0):{format}} s = trace "
+            f"{prog.get('trace_sec', 0.0):{format}} + lower "
+            f"{prog.get('lower_sec', 0.0):{format}} + backend "
+            f"{prog.get('backend_sec', 0.0):{format}} (cache load "
+            f"{prog.get('retrieval_sec', 0.0):{format}}; "
+            f"{prog.get('cache_hits', 0)} hits, "
+            f"{prog.get('cache_misses', 0)} misses) + discovery "
+            f"{prog.get('discover_sec', 0.0):{format}}; eager "
+            f"{prog.get('eager', {}).get('count', 0)} programs "
+            f"{prog.get('eager', {}).get('sec', 0.0):{format}} s"
+            + (f"; largest: {top}" if top else ""))
+    return lines
 
 
 def format_phase_table(record, indent="  "):

@@ -1081,8 +1081,8 @@ class ResilientLoop:
                     return total
 
             # memoized on self just above (one wrapper per loop)
-            self._compare_prog = jax.jit(  # dedalus-lint: disable=DTL003
-                retrace_mod.noted(raw, "resilience/sdc_compare"))
+            self._compare_prog = retrace_mod.noted_jit(
+                raw, "resilience/sdc_compare")
         return self._compare_prog
 
     def _sdc_check(self, anchor, dt):

@@ -12,10 +12,20 @@ tier composes per request:
       admission                     queue-slot + breaker verdict
       queue                         accept -> worker dispatch wait
       pool_acquire                  warm-pool verdict (hit | warm-cache
-        build/host_assembly           | cold); cold builds carry the
-        build/structure               BuildPhases child spans
-        build/factor
-        build/compile
+        build/<name>                  | cold); cold builds carry the
+          compile/<label>             BuildPhases child spans (<name>:
+                                      layout, assembly_cache,
+                                      host_assembly, pattern, structure,
+                                      factor, basis_stacks, plans, upload,
+                                      dd_prepare; flat and exclusive,
+                                      tools/metrics.py), and every
+                                      program's first call is a
+                                      compile/<label> span wherever it
+                                      falls: under a build/<name>, step,
+                                      step_many, step/factor or
+                                      handler/eval (attrs cache,
+                                      trace_sec, lower_sec, backend_sec;
+                                      the set-up ledger, tools/retrace.py)
       batch/seat  batch/join        continuous-batching membership
       batch/block                   one fixed-size block of fused steps
       batch/boundary                the per-block probe sync
@@ -27,7 +37,11 @@ tier composes per request:
       error                         terminal error frame (code attr)
 
       step | step_many              one solver iteration (or block), host
+        compile/<label>               a program's first call (the run's
+                                      first step, a new block length)
         step/factor                   side: LHS refactorization launch
+          build/factor                  the run's first one, waited for
+            compile/<label>               and its program's first call
         step/handlers                 scheduled handlers that fell due
           handler/eval                  task program launch (mode attr)
             state/scatter                 one launch: X into the fields
@@ -45,7 +59,9 @@ timestamps per span. A span is LIVE (`live()`) when the [tracing] switch
 is on OR a `jax.profiler` trace is being captured: whoever starts a
 profiler gets the step-loop spans in the ring and, as `dedalus/<name>`
 rows, on the host plane of the same xplane, with no switch of this
-package to set. The `span()` fast path when nothing looks is a shared
+package to set: a profiler started before a build shows which gap of the
+device is which phase (`dedalus/build/<name>`) and which program's
+tracing, lowering and compile or cache load (`dedalus/compile/<label>`). The `span()` fast path when nothing looks is a shared
 no-op context manager: zero allocation, zero branches inside traced
 code, nothing registered anywhere.
 
