@@ -31,10 +31,11 @@ Fusion layers (config section [fusion], resolved once per solver build):
                     NLBVP/EVP `factor()` keeps the backward-stable
                     substitution (one factor, one solve — nothing to
                     amortize).
-  FUSED_MATVEC    — M@X and L@X in one pass: shared permute/pad/scatter,
-                    both band stores walked over one padded operand
-                    (`BandedOps.matvec_pair`); bitwise-identical to the
-                    separate matvecs by construction.
+  FUSED_MATVEC    — M@X and L@X in one pass: shared permute/pad, and one
+                    scan over row tiles whose body reads its tile of both
+                    band stores against one window of the padded operand
+                    (`BandedOps.matvec_pair`, `_band_mv`); row by row the
+                    float operations of the separate matvecs.
   FUSED_TRANSFORMS— RHS linear-operator chains precomposed host-side into
                     single batched GEMMs: dealias-scaled backward MMT @
                     (conversion/derivative matrices) on the coupled

@@ -28,6 +28,7 @@ metadata (permutations, band offsets, block size, pin positions) is
 host-static.
 """
 
+import logging
 import threading
 
 import numpy as np
@@ -44,6 +45,7 @@ from ..tools.config import config
 from ..tools.array import zeropad
 from ..tools import tracing
 
+logger = logging.getLogger(__name__)
 
 # ------------------------------------------------------- pencil-mesh routing
 #
@@ -282,8 +284,10 @@ class BandedMatrix:
     structurally nonzero diagonals are kept (`dsel` maps stored rows to the
     shared 0..nd-1 diagonal lattice), and an all-zero pinned-row block is
     dropped entirely. The mass matrix M typically occupies a few diagonals
-    of the lattice the stiffness L defines, so trimming cuts both storage
-    and matvec work.
+    of the lattice the stiffness L defines, so trimming cuts both the
+    storage and what a band product reads: `BandedOps._band_mv` streams
+    every STORED diagonal once, zeros included (a stored diagonal's zero
+    runs are not trimmed).
     """
 
     def __init__(self, bands, Vt, dsel):
@@ -388,6 +392,10 @@ class BandedOps(AdjointSolveOps):
         # Chosen at factor time (needs G and the dtype); solve re-derives
         # the count from the aux's shapes — this attr is diagnostic only.
         self._g_chunks = 1
+        # (rows per tile, tiles) of the band product's scan as last
+        # traced (_band_tiles reads them off the stores' shapes) —
+        # diagnostic, as _g_chunks is
+        self._band_tiling = None
         # Re-blocking: BANDED_MIN_Q = <integer> re-blocks the SAME banded
         # lattice with a larger q (fewer, fatter scan steps). The band
         # STORAGE keeps its assembled width (n_store); factor transients
@@ -479,6 +487,12 @@ class BandedOps(AdjointSolveOps):
         Vt_dev = None
         if self.t and np.any(Vt):
             Vt_dev = jnp.asarray(Vt, dtype=dtype)
+        rows, tiles = self._band_tiles(trimmed)
+        logger.info(
+            f"Banded store on the device: {len(dsel)} of {self.nd} "
+            f"diagonals x {trimmed.shape[-1]} rows x {trimmed.shape[0]} "
+            f"groups ({trimmed.nbytes / 1e6:.1f} MB), band product in "
+            f"{tiles} tile(s) of {rows} rows")
         return BandedMatrix(trimmed, Vt_dev, dsel)
 
     def densify_host(self, host_arrs, g):
@@ -514,50 +528,120 @@ class BandedOps(AdjointSolveOps):
             Vt = Vt.at[:, :, :self.n_store].set(a * A.Vt)
         return full, Vt
 
-    def _band_mv(self, bands, dsel, x):
-        """y[g, p] = sum_{d in dsel} bands[g, i, p] * x[g, p + d - kl];
-        width follows the band ARRAY (assembled storage, not the
-        re-blocked factor width)."""
-        width = bands.shape[-1]
-        xpad = zeropad(x, ((0, 0), (self.kl, self.ku)))
-        y = jnp.zeros_like(x)
-        for i, d in enumerate(dsel):
-            y = y + bands[:, i, :] * jax.lax.slice_in_dim(
-                xpad, d, d + width, axis=1)
-        return y
+    # bytes of ONE store's tile a body of the band product's scan reads:
+    # at a v5e's bandwidth 80 us a body, against the 3 us a body costs to
+    # launch (PERF.md, PR 29), and far under what a step's programs have
+    # to spare beside 10 GB of resident factors
+    _BAND_TILE_BYTES = 64 * 2 ** 20
 
-    def matvec(self, A, X):
-        """Full A @ X in the ORIGINAL slot ordering; X (G, S)."""
-        with jax.named_scope("dedalus/matsolve/banded.matvec"):
-            xp = X[:, self.col_perm]
-            xp = zeropad(xp, ((0, 0), (0, A.bands.shape[-1] - self.n)))
-            yp = self._band_mv(A.bands, A.dsel, xp)
+    def _band_tiles(self, *stores):
+        """(rows per tile, tiles) of the band product over band stores
+        (G, D, width) of one width, read off their shapes: the fewest
+        tiles whose slab of the widest store (every stored diagonal, every
+        group) stays under _BAND_TILE_BYTES, the rows spread evenly over
+        them and rounded up to the 8 sublanes of a float32 tile, so that
+        a tile starts on a tile boundary of the group-minor layout a TPU
+        keeps the stores in. A store that fits one tile is one tile."""
+        G, _, width = stores[0].shape
+        row_bytes = max(1, G * max(b.shape[1] * b.dtype.itemsize
+                                   for b in stores))
+        tiles = -(-width * row_bytes // self._BAND_TILE_BYTES)
+        if tiles <= 1:
+            return width, 1
+        rows = -(-(-(-width // tiles)) // 8) * 8
+        return rows, -(-width // rows)
+
+    def _band_mv(self, mats, x):
+        """For every (bands, dsel) of `mats`, stores of one width:
+            y[g, p] = sum_i bands[g, i, p] * x[g, p + dsel[i] - kl]
+        over ONE zero-padded x; width follows the band ARRAY (assembled
+        storage, not the re-blocked factor width).
+
+        Each store is read once, where it lies: a scan over tiles of rows
+        (`_band_tiles`) whose body takes a `dynamic_slice` of the bands
+        (every stored diagonal, `rows` rows, every group) and of the
+        rows + kl + ku window of the padded x they meet, and sums the
+        shifted multiply-adds inside the body, i ascending: on every row
+        the float operations of a loop over whole diagonals, in its order.
+        That loop, which this replaces, reached a v5e as copies of 50 of
+        54 diagonals out of the store and 19 shifted copies of x: 8 GB
+        moved for 1.8 GB of bands (PERF.md, PR 38). A store that fits one
+        tile takes the body once, which is that loop's program again.
+
+        The last tile's start is clamped to the stored width, so it
+        rewrites rows it shares with its neighbour, with the same values,
+        and a store is never padded or copied to fit. The results are
+        carried TRANSPOSED, (width, G): a TPU keeps the stores and x
+        group-minor and gives a loop's carry of no other user the default
+        layout, so a (G, width) carry came out row-minor and every body
+        transposed 54 slabs to meet it (146 MB of temporaries a product);
+        transposed, the default layout IS group-minor and both `.T` are
+        bitcasts there; on a CPU they transpose one result, 1/D of the
+        bytes. The group axis is not tiled: a pencil mesh shards it as
+        before. Scan indices are int32 (`_shard_chunked`)."""
+        width = mats[0][0].shape[-1]
+        rows, tiles = self._band_tiling = self._band_tiles(
+            *(bands for bands, _ in mats))
+        xpad = zeropad(x, ((0, 0), (self.kl, self.ku)))
+        window = rows + self.kl + self.ku
+
+        def tile(start):
+            """Rows [start, start + rows) of every product."""
+            xw = jax.lax.dynamic_slice_in_dim(xpad, start, window, axis=1)
+            ys = []
+            for bands, dsel in mats:
+                b = jax.lax.dynamic_slice_in_dim(bands, start, rows, axis=2)
+                y = jnp.zeros_like(xw[:, :rows])
+                for i, d in enumerate(dsel):
+                    y = y + b[:, i, :] * jax.lax.slice_in_dim(
+                        xw, d, d + rows, axis=1)
+                ys.append(y)
+            return ys
+
+        if tiles == 1:
+            return tile(0)
+
+        def body(yTs, k):
+            start = jnp.minimum(k * rows, width - rows)
+            return [jax.lax.dynamic_update_slice_in_dim(yT, y.T, start, 0)
+                    for yT, y in zip(yTs, tile(start))], None
+
+        yTs, _ = jax.lax.scan(body, [jnp.zeros_like(x).T for _ in mats],
+                              jnp.arange(tiles, dtype=jnp.int32))
+        return [yT.T for yT in yTs]
+
+    def _matvecs(self, mats, X):
+        """[A @ X for A in mats] in the ORIGINAL slot ordering, X (G, S):
+        one column permutation, one padded X and one scan over row tiles
+        for all of `mats`; each matrix its own pinned rows and scatter."""
+        xp = X[:, self.col_perm]
+        xp = zeropad(xp, ((0, 0), (0, mats[0].bands.shape[-1] - self.n)))
+        outs = []
+        for A, yp in zip(mats, self._band_mv(
+                [(A.bands, A.dsel) for A in mats], xp)):
             if self.t and A.Vt is not None:
                 pin_vals = jnp.einsum("gtn,gn->gt", A.Vt, xp)
                 yp = yp.at[:, self.pin_pos].add(pin_vals)
             # yp[p] = (A @ X)[row_perm[p]]
             out = jnp.zeros_like(X)
-            return out.at[:, self.row_perm].set(yp[:, :self.n])
+            outs.append(out.at[:, self.row_perm].set(yp[:, :self.n]))
+        return outs
+
+    def matvec(self, A, X):
+        """Full A @ X in the ORIGINAL slot ordering; X (G, S)."""
+        with jax.named_scope("dedalus/matsolve/banded.matvec"):
+            return self._matvecs([A], X)[0]
 
     def matvec_pair(self, M, L, X):
         """(M @ X, L @ X) in ONE pass over the operand: the fused-step
-        pair surface (core/fusedstep.py). The column permutation, pad,
-        pin einsums and row scatter run once over a shared padded X; each
-        matrix keeps its own trimmed diagonal loop, so both outputs are
-        BITWISE identical to separate `matvec` calls."""
+        pair surface (core/fusedstep.py). The column permutation, the pads
+        and ONE scan over row tiles (`_band_mv`) are shared: a body reads
+        its tile of both stores against one window of the padded X, each
+        matrix summing its own trimmed diagonals in `matvec`'s order, so
+        every row of both outputs is the float operations of a separate
+        `matvec` call."""
         with jax.named_scope("dedalus/matsolve/banded.matvec_pair"):
-            width = M.bands.shape[-1]
-            xp = X[:, self.col_perm]
-            xp = zeropad(xp, ((0, 0), (0, width - self.n)))
-            outs = []
-            for A in (M, L):
-                yp = self._band_mv(A.bands, A.dsel, xp)
-                if self.t and A.Vt is not None:
-                    pin_vals = jnp.einsum("gtn,gn->gt", A.Vt, xp)
-                    yp = yp.at[:, self.pin_pos].add(pin_vals)
-                out = jnp.zeros_like(X)
-                outs.append(out.at[:, self.row_perm].set(yp[:, :self.n]))
-            return tuple(outs)
+            return tuple(self._matvecs([M, L], X))
 
     def _chunk_blocks(self, chunk):
         """One block-row's (G, D, q) band chunk -> (diag, left, right) blocks

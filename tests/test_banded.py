@@ -400,3 +400,154 @@ def test_packed_solve_transpose_matches_dense_transpose(q, NB, pins):
     x = np.asarray(ops.solve_transpose(aux, jnp.asarray(rhs)))
     x_ref = np.linalg.solve(np.swapaxes(dense, 1, 2), rhs[..., None])[..., 0]
     assert np.abs(x - x_ref).max() / np.abs(x_ref).max() < 1e-10
+
+
+# ---- the band product as a scan over row tiles (PR 38) ----
+#
+# `BandedOps._band_mv` reads each store once: a scan over tiles of rows,
+# the stored diagonals summed inside the body in the order of the loop
+# over whole diagonals it replaced, which is kept here as the reference.
+# The tile is read off the shapes (`_BAND_TILE_BYTES` a body); the cases
+# shrink that number on the instance to force the tilings that can go
+# wrong. Two kinds of entries: small integers, where every product and
+# sum is exact in either dtype, so that "equal" means equal whatever XLA
+# fuses or contracts (the CPU backend contracts a multiply and an add
+# into an fma fusion by fusion: through `matvec`, random entries differ
+# in the last bit between any two programs, the parent's
+# pair and singles among them); and random reals for `_band_mv` alone,
+# where the two forms are the same float operations row by row and are
+# equal bit for bit.
+
+def _with_loop_band_mv(ops):
+    """A copy of `ops` whose `_band_mv` is what it was until PR 38: each
+    stored diagonal a shifted pass over the whole padded x."""
+    import copy
+    import jax
+    from dedalus_tpu.tools.array import zeropad
+    old = copy.copy(ops)
+
+    def band_mv(mats, x):
+        xpad = zeropad(x, ((0, 0), (old.kl, old.ku)))
+        ys = []
+        for bands, dsel in mats:
+            y = jnp.zeros_like(x)
+            for i, d in enumerate(dsel):
+                y = y + bands[:, i, :] * jax.lax.slice_in_dim(
+                    xpad, d, d + bands.shape[-1], axis=1)
+            ys.append(y)
+        return ys
+    old._band_mv = band_mv
+    return old
+
+
+BAND_G = 3
+# case: (q, NB, diagonals M keeps, diagonals L drops, rows asked of a
+# tile or None for the default, (rows, tiles) the shapes then give)
+BAND_CASES = {
+    "one_tile": (7, 6, (6, 7, 8), (), None, (42, 1)),
+    "width_no_multiple_of_the_tile": (7, 6, (6, 7, 8), (), 16, (16, 3)),
+    "width_a_multiple_of_the_tile": (16, 4, (15, 16, 17), (), 16, (16, 4)),
+    "tile_narrower_than_kl_plus_ku": (16, 5, (0, 16, 32), (), 8, (8, 10)),
+    "dsel_with_gaps": (7, 9, (0, 7, 13), (1, 2, 5, 11), 24, (24, 3)),
+}
+
+
+def _band_case(case, dtype, draw):
+    """(ops, M, L, host stores): a BandedOps over random permutations
+    with 3 pinned rows, M on three diagonals with no pinned content (its
+    Vt is dropped) beside L on the full lattice less `drop`, with pinned
+    rows; entries from `draw(rng, shape)`."""
+    from types import SimpleNamespace
+    from dedalus_tpu.libraries.pencilops import BandedOps
+    q, NB, keep, drop, ask, tiling = BAND_CASES[case]
+    rng = np.random.default_rng(sum(map(ord, case)))
+    S, n_store, pins = NB * q - 2, NB * q, 3
+    pin_pos = np.sort(rng.choice(S, size=pins, replace=False))
+    st = SimpleNamespace(S=S, NB=NB, q=q, t_pins=pins, kl=q, ku=q,
+                         row_perm=rng.permutation(S),
+                         col_perm=rng.permutation(S),
+                         pinned_positions=pin_pos)
+    ops = BandedOps(st, refine=1)
+    cols = np.arange(n_store)[None, :] + np.arange(-q, q + 1)[:, None]
+    off = (cols < 0) | (cols >= S)
+    hosts = {}
+    for name, lattice in (("M", keep), ("L", sorted(set(range(2 * q + 1))
+                                                   - set(drop)))):
+        bands = np.zeros((BAND_G, 2 * q + 1, n_store))
+        bands[:, lattice] = draw(rng, (BAND_G, len(lattice), n_store))
+        bands[:, off] = 0.0
+        bands[:, :, S:] = 0.0
+        bands[:, :, pin_pos] = 0.0
+        Vt = np.zeros((BAND_G, pins, n_store))
+        if name == "L":
+            Vt[:, :, :S] = draw(rng, (BAND_G, pins, S))
+        hosts[name] = {"bands": bands, "Vt": Vt}
+    M, L = (ops.to_device(hosts[n], dtype) for n in ("M", "L"))
+    assert M.Vt is None and L.Vt is not None
+    if ask is not None:
+        ops._BAND_TILE_BYTES = (
+            ask * BAND_G * len(L.dsel) * np.dtype(dtype).itemsize)
+    assert ops._band_tiles(M.bands, L.bands) == tiling
+    return ops, M, L, hosts
+
+
+def _integers(rng, shape):
+    return rng.integers(-4, 5, size=shape).astype(np.float64)
+
+
+def _reals(rng, shape):
+    return rng.standard_normal(shape)
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.float32])
+@pytest.mark.parametrize("case", list(BAND_CASES))
+def test_tiled_band_product(case, dtype):
+    """On exact entries: the dense matrix's product, the loop's, the pair
+    against the two single products, and `vmap` over an ensemble axis of X
+    (M and L unbatched, as core/ensemble.py steps), all equal. On random
+    entries: the dense product in float64 to rounding, and `_band_mv`
+    bit for bit the loop."""
+    import jax
+    tiles = BAND_CASES[case][5][1]
+    ops, M, L, hosts = _band_case(case, dtype, _integers)
+    assert M.dsel == BAND_CASES[case][2]
+    assert len(L.dsel) == ops.nd - len(BAND_CASES[case][3])
+    old = _with_loop_band_mv(ops)
+    rng = np.random.default_rng(7)
+    Xe = jnp.asarray(rng.integers(-8, 9, size=(4, BAND_G, ops.n)),
+                     dtype=dtype)
+    X = Xe[0]
+    matvec, pair = jax.jit(ops.matvec), jax.jit(ops.matvec_pair)
+    # the scan really is there, where the case asks for tiles
+    assert ("while" in matvec.lower(L, X).as_text()) == (tiles > 1)
+    dense = {name: np.stack([ops.densify_host(hosts[name], g)
+                             for g in range(BAND_G)]) for name in "ML"}
+    singles = {"M": matvec(M, X), "L": matvec(L, X)}
+    for (name, y), A, both in zip(singles.items(), (M, L), pair(M, L, X)):
+        want = np.einsum("gij,gj->gi", dense[name], np.asarray(X, np.float64))
+        assert np.array_equal(np.asarray(y), want)
+        assert np.array_equal(np.asarray(both), want)
+        assert np.array_equal(np.asarray(jax.jit(old.matvec)(A, X)), want)
+    for e, (MX, LX) in enumerate(zip(*jax.jit(jax.vmap(
+            lambda x: ops.matvec_pair(M, L, x)))(Xe))):
+        for name, y in (("M", MX), ("L", LX)):
+            assert np.array_equal(np.asarray(y), np.einsum(
+                "gij,gj->gi", dense[name], np.asarray(Xe[e], np.float64)))
+    # random entries
+    ops, M, L, hosts = _band_case(case, dtype, _reals)
+    old = _with_loop_band_mv(ops)
+    matvec = jax.jit(ops.matvec)
+    X = jnp.asarray(rng.standard_normal((BAND_G, ops.n)), dtype=dtype)
+    for name, A in (("M", M), ("L", L)):
+        dense = np.stack([ops.densify_host(hosts[name], g)
+                          for g in range(BAND_G)]).astype(dtype)
+        want = np.einsum("gij,gj->gi", dense.astype(np.float64),
+                         np.asarray(X, np.float64))
+        tol = 1e-13 if dtype == np.float64 else 2e-5
+        assert (np.abs(np.asarray(matvec(A, X)) - want).max()
+                < tol * np.abs(want).max())
+    mats = [(A.bands, A.dsel) for A in (M, L)]
+    xp = jnp.asarray(rng.standard_normal((BAND_G, ops.n_store)), dtype=dtype)
+    for y, want in zip(jax.jit(lambda x: ops._band_mv(mats, x))(xp),
+                       jax.jit(lambda x: old._band_mv(mats, x))(xp)):
+        assert np.array_equal(np.asarray(y), np.asarray(want))

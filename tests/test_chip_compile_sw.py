@@ -27,7 +27,8 @@ from jax.sharding import SingleDeviceSharding
 
 sys.path.insert(0, str(pathlib.Path(__file__).parent))
 from test_chip_compile import (  # noqa: E402,F401
-    V5E_HBM_BYTES, _compile_f32, topo)
+    V5E_HBM_BYTES, _band_product_reads_the_store_in_place, _compile_f32,
+    topo)
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 G, S = 256, 1536
@@ -69,6 +70,9 @@ def pencils(topo):
             "ops": ops, "M": M, "L": L, "aux": aux[0],
             "incremental": ops.use_incremental_factor(G, 4),
             "factor": (ts._factor_uniq, (M, L, scalar)),
+            "M@X": (ts._mx0, (M, X)),
+            "L@X": (jax.jit(ops.matvec), (L, X)),
+            "pair": (jax.jit(ops.matvec_pair), (M, L, X)),
             "stage_solve": (ts._stage_solve, (2, X, [X, X], [X, X], scalar,
                                               aux[0], M, L)),
         }
@@ -105,3 +109,16 @@ def test_pencil_program_fits_a_v5e(pencils, program):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     # beside the 1.0 GB of SWSH stacks and the step's own temporaries
     assert held < 0.25 * V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("program", ["M@X", "L@X", "pair"])
+def test_band_product_is_one_tile(pencils, program):
+    """A store of 17 MB (f32[256,11,1540]) fits one tile of the band
+    product (tests/test_chip_compile.py, PR 38): the body once, with no
+    `while` around it, the store read in place by the fusion that sums
+    the diagonals, and nothing of a store's size among the temporaries."""
+    compiled, text = _compile_f32(*pencils[program])
+    assert pencils["ops"]._band_tiling == (NB * Q, 1)
+    _band_product_reads_the_store_in_place(text, (G, 11, NB * Q),
+                                           tiled=False)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e6
