@@ -153,11 +153,15 @@ def transform_to_coeff(data, domain, scales, tdim, library=None, tensorsig=()):
     try:
         data = _constrain(data, mesh, layout)
         for r in range(R):
-            if chunks > 1 and data.shape[tdim + r + 1] % mesh.shape[names[r]] == 0:
+            if data.shape[tdim + r + 1] % mesh.shape[names[r]] == 0:
                 # overlapped chunked stage: transform + per-chunk
                 # all_to_all interleaved inside one shard_map
                 # (parallel/transposes.py; bit-identical to the
-                # monolithic constraint-walk below)
+                # monolithic constraint-walk below). One chunk too: the
+                # exchange is then an explicit all_to_all under
+                # `dedalus/transpose/`, where the one GSPMD inserts for the
+                # constraint carries its neighbouring transform's op_name
+                # and no reader can see it (CPU, PR 40)
                 from ..parallel.transposes import overlapped_to_coeff_stage
                 del layout[tdim + r + 1]
                 data = overlapped_to_coeff_stage(
@@ -209,7 +213,7 @@ def transform_to_grid(data, domain, scales, tdim, library=None, tensorsig=()):
             data = bwd(data, axis)
         for r in range(R - 1, -1, -1):
             n = mesh.shape[names[r]]
-            if chunks > 1 and data.shape[tdim + r + 1] % n == 0 \
+            if data.shape[tdim + r + 1] % n == 0 \
                     and data.shape[tdim + r] % n == 0:
                 # overlapped chunked stage (parallel/transposes.py):
                 # chunk k+1's all_to_all rides under chunk k's backward

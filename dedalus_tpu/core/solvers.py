@@ -168,6 +168,10 @@ class SolverBase:
         # An explicitly named dense matsolver (or solver class) is always
         # honored; only 'auto' lets the size heuristic pick the banded path.
         auto = isinstance(self.matsolver, str) and spec.lower() == "auto"
+        mesh = getattr(self.dist, "mesh", None)
+        if mesh is not None:
+            # a pencil mesh: each chip holds its own share of the stacks
+            dense_bytes //= mesh.shape[mesh.axis_names[0]]
         try_banded = (forced == "banded"
                       or (auto and dense_bytes > cutoff_bytes))
         self.structure = None

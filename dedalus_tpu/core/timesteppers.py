@@ -83,6 +83,10 @@ def _use_split_step(solver):
         return mode == "split"
     G, S = solver.pencil_shape
     threshold = int(config["execution"].get("STEP_SPLIT_MODES", str(1 << 22)))
+    mesh = getattr(solver.dist, "mesh", None)
+    if mesh is not None:
+        # a pencil mesh: each chip compiles and runs its own share
+        G //= mesh.shape[mesh.axis_names[0]]
     return G * S > threshold
 
 

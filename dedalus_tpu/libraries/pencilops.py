@@ -90,6 +90,22 @@ def active_pencil_mesh():
     return getattr(_PENCIL_MESH, "state", None)
 
 
+class _chip_program:
+    """Trace-time context of the body of a `BandedOps._on_each_chip`
+    shard_map: the program one chip runs over its own groups. Inside it no
+    pencil mesh is active and no funnel routes again, so the chunking,
+    the memory-based choices and the scans are a one-chip program's for
+    G / chips groups."""
+
+    def __enter__(self):
+        self.prev = (getattr(_PENCIL_MESH, "state", None),
+                     getattr(_PENCIL_MESH, "chip", False))
+        _PENCIL_MESH.state, _PENCIL_MESH.chip = None, True
+
+    def __exit__(self, *exc):
+        _PENCIL_MESH.state, _PENCIL_MESH.chip = self.prev
+
+
 # -------------------------------------------------- adjoint solve funnel
 #
 # The batched pivoted-LU solves are opaque to JAX's autodiff at the
@@ -392,10 +408,16 @@ class BandedOps(AdjointSolveOps):
         # Chosen at factor time (needs G and the dtype); solve re-derives
         # the count from the aux's shapes — this attr is diagnostic only.
         self._g_chunks = 1
+        # ... and the chunk width of that factorization, per chip where the
+        # groups are sharded (read by build_phases.record())
+        self._chunk_width = None
         # (rows per tile, tiles) of the band product's scan as last
         # traced (_band_tiles reads them off the stores' shapes) —
         # diagnostic, as _g_chunks is
         self._band_tiling = None
+        # (mesh, axis name) the pencil groups are sharded over, set by
+        # parallel.distribute_solver (`shard_over`); None on one chip
+        self._mesh = None
         # Re-blocking: BANDED_MIN_Q = <integer> re-blocks the SAME banded
         # lattice with a larger q (fewer, fatter scan steps). The band
         # STORAGE keeps its assembled width (n_store); factor transients
@@ -453,12 +475,27 @@ class BandedOps(AdjointSolveOps):
         quarter of the device is what the step's temporaries take there.
         An explicit `on` or a restructured composition (it consumes the
         operators) is never overridden; a backend that reports no limit
-        (the CPU) keeps the operators."""
+        (the CPU) keeps the operators.
+
+        In float32 a block of q >= _POLISHED_MIN_Q keeps the packed factors
+        on every backend, whatever the memory (PR 40): there the operators'
+        products of the stored inverses lose what the packed sweep keeps —
+        3-D RB's structural q = 80 held continuity to 9.3e-6 through the
+        operators against 4.2e-8 packed, the dense residual to 1.9e-6
+        against 4.5e-8 (CPU, 8x8x128, ten steps), and the walls to 1.2e-5
+        after twenty — and a sweep reads 7 q^2 numbers a block row for the
+        packed 4 q^2. No cell before PR 40 has such a q: sw_ell255's 7 and
+        rb2048x1024's 32 decide as before."""
         if not self._fused_solve_auto or self._composition != "sequential":
+            return
+        if itemsize <= 4 and self._polished:
+            self._fused_solve = self._fused_solve_auto = False
             return
         limit = device_memory_bytes()
         if not limit:
             return
+        # under a pencil mesh each chip holds its own groups' share
+        G = self.groups_per_chip(G)
         q = self.q
         nb = -(-self.n_store // q)
         bands = 2 * G * self.nd * self.n_store
@@ -578,7 +615,7 @@ class BandedOps(AdjointSolveOps):
         transposed, the default layout IS group-minor and both `.T` are
         bitcasts there; on a CPU they transpose one result, 1/D of the
         bytes. The group axis is not tiled: a pencil mesh shards it as
-        before. Scan indices are int32 (`_shard_chunked`)."""
+        before. Scan indices are int32 (the x64 index mismatch of PR 13)."""
         width = mats[0][0].shape[-1]
         rows, tiles = self._band_tiling = self._band_tiles(
             *(bands for bands, _ in mats))
@@ -629,6 +666,8 @@ class BandedOps(AdjointSolveOps):
 
     def matvec(self, A, X):
         """Full A @ X in the ORIGINAL slot ordering; X (G, S)."""
+        if self._route() is not None:
+            return self._on_each_chip(self.matvec, A, X)
         with jax.named_scope("dedalus/matsolve/banded.matvec"):
             return self._matvecs([A], X)[0]
 
@@ -640,6 +679,8 @@ class BandedOps(AdjointSolveOps):
         matrix summing its own trimmed diagonals in `matvec`'s order, so
         every row of both outputs is the float operations of a separate
         `matvec` call."""
+        if self._route() is not None:
+            return self._on_each_chip(self.matvec_pair, M, L, X)
         with jax.named_scope("dedalus/matsolve/banded.matvec_pair"):
             return tuple(self._matvecs([M, L], X))
 
@@ -656,15 +697,61 @@ class BandedOps(AdjointSolveOps):
             out[o] = blk
         return out[0], out[-1], out[1]
 
+    # From this block size on FUSED_SOLVE = auto keeps float32 factors
+    # packed (_ensure_fused_fits), and the packed factors keep nothing that
+    # the TPU's triangular solves return unpolished (PR 40): the triangles'
+    # inverses get _NEWTON_STEPS Newton steps, the fill blocks U12 one
+    # refinement of their substitution, the last block is kept as its
+    # polished inverse and the Woodbury capacitance solve is refined once
+    # against the capacitance matrix kept beside its LU. RB 3-D's q = 80
+    # read walls 2.1e-3, continuity 1.2e-4 and a dense residual of 2.5e-6
+    # on the v5e with PR 29's single step (float32, 1,024 groups, ten
+    # steps; my chip run, PR 40), where the CPU reads 2.8e-7, 4.2e-8 and
+    # 4.5e-8; polished, the chip reads 1.4e-6, 5.1e-7 and 1.3e-8 there.
+    # q = 32 (rb2048x1024) and q = 7 (sw_ell255) keep their programs.
+    _POLISHED_MIN_Q = 64
+    _NEWTON_STEPS = 3
+
     @staticmethod
-    def _triangle_inverses(top):
+    def _product(A, B):
+        """A @ B over the last two axes in plain multiply-adds (no dot: on
+        the TPU a dot is MXU passes)."""
+        return (A[..., :, :, None] * B[..., None, :, :]).sum(axis=-2)
+
+    @staticmethod
+    def _polish(A, X, steps=1):
+        """`steps` Newton steps X + X (I - A X) towards A^-1, in plain
+        multiply-adds."""
+        eye = jnp.eye(A.shape[-1], dtype=A.dtype)
+        product = BandedOps._product
+        for _ in range(steps):
+            X = X + product(X, eye - product(A, X))
+        return X
+
+    @property
+    def _polished(self):
+        return self.q >= self._POLISHED_MIN_Q
+
+    def _block_inverse(self, lu):
+        """The polished inverse of a (G, q, q) block from its LU (unit L
+        below the diagonal, U on and above it): (L U)^-1 = U^-1 L^-1 from
+        the polished triangles, then Newton steps against L U."""
+        eye = jnp.eye(lu.shape[-1], dtype=lu.dtype)
+        inv = self._triangle_inverses(lu, self._NEWTON_STEPS)
+        X = self._product(jnp.triu(inv), jnp.tril(inv, -1) + eye)
+        A = self._product(jnp.tril(lu, -1) + eye, jnp.triu(lu))
+        return self._polish(A, X, self._NEWTON_STEPS)
+
+    @staticmethod
+    def _triangle_inverses(top, steps=1):
         """A panel's (G, q, q) LU top — L1 below the diagonal (unit lower),
         U11 on and above it — with both triangles inverted in place: the
         strict lower triangle of L1^-1 (unit lower stays unit lower) and
         the upper triangle of U11^-1 (upper stays upper).
 
-        Each inverse gets one Newton step, X + X (I - A X), in plain
-        multiply-adds (no dot: on the TPU a dot is MXU passes). What
+        Each inverse gets one Newton step (`steps` of them where q asks:
+        `_POLISHED_MIN_Q`), X + X (I - A X), in plain multiply-adds (no
+        dot: on the TPU a dot is MXU passes). What
         `solve_triangular` against the identity returns there is not good
         enough to be STORED: an error in an entry of the inverse reaches
         the solution multiplied by the block's condition number. RB
@@ -678,13 +765,13 @@ class BandedOps(AdjointSolveOps):
         L1, U11 = jnp.tril(top, -1) + eye, jnp.triu(top)
         L1inv = jsl.solve_triangular(L1, eyes, lower=True, unit_diagonal=True)
         U11inv = jsl.solve_triangular(U11, eyes, lower=False)
-
-        def product(A, B):
-            return (A[..., :, :, None] * B[..., None, :, :]).sum(axis=-2)
+        product = BandedOps._product
 
         def polished(A, X):
             return X + product(X, eye - product(A, X))
 
+        for _ in range(steps - 1):
+            L1inv, U11inv = polished(L1, L1inv), polished(U11, U11inv)
         return (jnp.tril(polished(L1, L1inv), -1)
                 + jnp.triu(polished(U11, U11inv)))
 
@@ -731,6 +818,8 @@ class BandedOps(AdjointSolveOps):
 
         eye_q = jnp.eye(q, dtype=dtype)
         zero_qq = jnp.zeros((G, q, q), dtype=dtype)
+        polish = self._polished
+        newton = self._NEWTON_STEPS if polish else 1
 
         # All arrays entering/leaving the scan are flattened to (G, flat):
         # TPU tiles the two minor dims to (8, 128), so stacked (steps, G, q,
@@ -756,11 +845,18 @@ class BandedOps(AdjointSolveOps):
             U12 = jsl.solve_triangular(jnp.tril(top, -1) + eye_q,
                                        T[:, :q, :], lower=True,
                                        unit_diagonal=True)        # (G,q,2q)
+            if polish:
+                # one refinement of the substitution, by the polished L1^-1
+                L1 = jnp.tril(top, -1) + eye_q
+                L1inv = jnp.tril(self._triangle_inverses(top, newton),
+                                 -1) + eye_q
+                U12 = U12 + self._product(
+                    L1inv, T[:, :q, :] - self._product(L1, U12))
             Tn = T[:, q:, :] - L2 @ U12                           # (G,q,2q)
             carry = (Tn[:, :, :q],
                      jnp.concatenate([Tn[:, :, q:], zero_qq], axis=2))
             packed = jnp.concatenate(
-                [self._triangle_inverses(top), L2], axis=1)
+                [self._triangle_inverses(top, newton), L2], axis=1)
             return carry, (perm, packed.reshape(G, 2 * q * q),
                            U12.reshape(G, 2 * q * q))
 
@@ -1015,6 +1111,8 @@ class BandedOps(AdjointSolveOps):
 
         def last_solve(w):
             w = jnp.take_along_axis(w, lastP[:, :, None], axis=1)
+            if self._polished:
+                return self._product(lastLU, w)   # the polished inverse
             y = jsl.solve_triangular(jnp.tril(lastLU, -1) + eye_q, w,
                                      lower=True, unit_diagonal=True)
             return jsl.solve_triangular(jnp.triu(lastLU), y, lower=False)
@@ -1138,6 +1236,11 @@ class BandedOps(AdjointSolveOps):
         interior = self._factor_interior(bands)
         fsub = self._precompose_subst(interior) if fused else None
         if not fused:
+            if self._polished:
+                # the last block kept as its polished inverse
+                # (`_solve_interior` applies it)
+                interior = interior[:4] + (
+                    self._block_inverse(interior[4]),)
             interior = self._batch_minor(interior)
         if fused:
             # the fused solve consumes only fsub — dropping the pivoted
@@ -1171,6 +1274,10 @@ class BandedOps(AdjointSolveOps):
                 fsub["CapInv"] = jnp.linalg.inv(Cap)
             else:
                 CapLU = jsl.lu_factor(Cap)
+                if self._polished:
+                    # kept beside its LU: `_solve_core` refines the
+                    # capacitance solve once against it
+                    CapLU = CapLU + (Cap,)
         if fused and self._ladder:
             # precision ladder (libraries/solvecomp.py): the whole
             # A'-solve — substitution operators AND Woodbury correction
@@ -1210,7 +1317,7 @@ class BandedOps(AdjointSolveOps):
         with jax.named_scope("dedalus/matsolve/banded.factor"):
             G = bands.shape[0]
             C, Gc = self._pick_chunks(G, bands.dtype.itemsize)
-            self._g_chunks = C
+            self._g_chunks, self._chunk_width = C, Gc
             if C == 1:
                 core = self._factor_core(bands, Vt)
             else:
@@ -1239,6 +1346,8 @@ class BandedOps(AdjointSolveOps):
 
     def factor(self, A):
         """Factor a matrix already resident in banded storage."""
+        if self._route() is not None:
+            return self._on_each_chip(self.factor, A)
         self._ensure_fused_fits(A.bands.shape[0], A.bands.dtype.itemsize)
         bands, Vt = self.expand(A)
         return self._factor_impl(bands, Vt, {"A": A})
@@ -1249,11 +1358,13 @@ class BandedOps(AdjointSolveOps):
         when chunking is active), and the refinement residual uses matvecs
         of the already-resident trimmed M and L (saves one full band store
         at large S)."""
+        if self._route() is not None:
+            return self._on_each_chip(self.factor_lincomb, a, M, b, L)
         G = M.bands.shape[0]
         dtype = M.bands.dtype
         self._ensure_fused_fits(G, dtype.itemsize)
         C, Gc = self._pick_chunks(G, dtype.itemsize)
-        self._g_chunks = C
+        self._g_chunks, self._chunk_width = C, Gc
         dM = np.asarray(M.dsel)
         dL = np.asarray(L.dsel)
 
@@ -1317,8 +1428,13 @@ class BandedOps(AdjointSolveOps):
         store + M/L + one chunk, vs the fused program's store + M/L + all
         scan temps). Engaged automatically when the factor output alone
         exceeds BANDED_INCREMENTAL_GB (the RB 2048x1024 regime: ~5.5 GB of
-        factors on a 16 GB chip)."""
+        factors on a 16 GB chip). Under a pencil mesh (`shard_over`) never:
+        each chip's program factors its own groups chunk by chunk in one
+        dispatch (`_on_each_chip`), where the host-level slices of the
+        incremental path would cut across chips."""
         self._ensure_fused_fits(G, itemsize)
+        if self._route() is not None:
+            return False
         mode = config["linear algebra"].get(
             "BANDED_FACTOR_MODE", "auto").lower()
         if mode in ("fused", "incremental"):
@@ -1396,7 +1512,7 @@ class BandedOps(AdjointSolveOps):
         G = M.bands.shape[0]
         rd = np.dtype(M.bands.dtype)
         write, store_shapes, C, Gc = self.incremental_chunk_program(M, L)
-        self._g_chunks = C
+        self._g_chunks, self._chunk_width = C, Gc
         a = jnp.asarray(a, dtype=rd)
         b = jnp.asarray(b_scale, dtype=rd)
         store = jax.tree.map(
@@ -1447,6 +1563,12 @@ class BandedOps(AdjointSolveOps):
                       - fp[:, self.pin_pos])
                 if fsub is not None and "CapInv" in fsub:
                     z = jnp.einsum("gij,gj->gi", fsub["CapInv"], Vy)
+                elif len(auxc["Cap"]) == 3:
+                    # q >= _POLISHED_MIN_Q: one refinement against Cap
+                    lu, piv, Cap = auxc["Cap"]
+                    z = jsl.lu_solve((lu, piv), Vy)
+                    z = z + jsl.lu_solve((lu, piv), Vy - self._product(
+                        Cap, z[..., None])[..., 0])
                 else:
                     z = jsl.lu_solve(auxc["Cap"], Vy)
                 y = y - jnp.einsum("gtn,gt->gn", auxc["YbT"], z)
@@ -1472,12 +1594,8 @@ class BandedOps(AdjointSolveOps):
                     if k in aux}
             fpr = fp.reshape(C, Gc, self.n_pad)
 
-            def chunked_solve(auxc, fpr):
-                return jax.lax.map(
-                    lambda xs: self._solve_core(xs[0], xs[1]),
-                    (auxc, fpr))
-
-            y = self._shard_chunked(chunked_solve, (auxc, fpr), Gc)
+            y = jax.lax.map(lambda xs: self._solve_core(xs[0], xs[1]),
+                            (auxc, fpr))
             y = y.reshape(-1, self.n_pad)[:G]
         xp = y[:, :self.n]
         out = xp[:, self.pos_col]
@@ -1485,41 +1603,108 @@ class BandedOps(AdjointSolveOps):
             out = out.astype(rhs.dtype)   # ladder: back to the rhs dtype
         return out
 
-    def _shard_chunked(self, fn, args, Gc):
-        """Run a chunk-mapped factor/solve (`fn(*args)`, every traced
-        leaf a (C, Gc, ...) slab) with the per-chunk GROUP axis (dim 1)
-        sharded over the active pencil mesh, inside manual shard_map.
-        Two reasons: the t x t capacitance LU custom calls stay
-        device-local (GSPMD cannot partition them), and XLA's SPMD
-        partitioner miscompiles the chunk scan's dynamic_update_slice
-        under x64 (s64/s32 index mismatch, verifier failure after
-        spmd-partitioning — observed on the 2048x1024 north-star banded
-        step). Falls back to the plain GSPMD call when no mesh context
-        is active, the chunk width does not tile the mesh, or any leaf
-        does not carry the (C, Gc, ...) layout."""
-        state = active_pencil_mesh()
-        if state is not None:
-            mesh, name = state
-            n = mesh.shape[name]
-            spec = PartitionSpec(None, name)
+    # ------------------------------------------------------ pencil mesh
+    #
+    # A solver sharded over a mesh (parallel.distribute_solver) runs every
+    # device program of these operators as each chip's own program over its
+    # own groups: one shard_map per funnel (factor, solve with its
+    # refinement, matvec), every leaf split on the axis its layout keeps the
+    # groups on (`group_axes`), no collective inside. Nothing here is
+    # reached without `shard_over`: a solver on one chip traces as before.
 
-            def spec_of(leaf):
-                ndim = getattr(leaf, "ndim", 0)
-                if ndim == 0:
-                    return PartitionSpec()
-                if ndim >= 2 and leaf.shape[1] == Gc:
-                    return spec
-                return None
+    def shard_over(self, mesh, axis_name=None):
+        """Shard these operators' programs over `mesh`'s axis (the first
+        by default): called by parallel.distribute_solver."""
+        self._mesh = (mesh, axis_name or mesh.axis_names[0])
 
-            in_specs = jax.tree.map(spec_of, args)
-            if Gc % n == 0 and not any(
-                    s is None for s in jax.tree.leaves(
-                        in_specs, is_leaf=lambda x: x is None)):
-                return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=spec)(*args)
-        return fn(*args)
+    def _route(self):
+        """(mesh, axis name) where the groups are sharded and this trace is
+        not already one chip's program; else None."""
+        if self._mesh is None or getattr(_PENCIL_MESH, "chip", False):
+            return None
+        return self._mesh
+
+    def groups_per_chip(self, G):
+        """The groups of G that one chip holds: G / chips under a pencil
+        mesh (outside a chip's own program), G otherwise."""
+        route = self._route()
+        return G if route is None else G // route[0].shape[route[1]]
+
+    def group_axes(self, tree):
+        """The axis each array leaf of `tree` keeps the pencil groups on
+        (-1 for scalars), as a tree of the same structure. Band stores,
+        states and right-hand sides lead with the groups; a factorization's
+        aux keeps them where `_factor_core` and `_batch_minor` put them:
+        last in the group-minor packed store, after the chunk (and block
+        row) axes where the factorization is chunked."""
+        def lead(t):
+            return jax.tree.map(lambda a: 0 if np.ndim(a) else -1, t)
+
+        if not isinstance(tree, dict) or not ({"Vt", "ab", "A"} & set(tree)):
+            return lead(tree)
+        probe = (tree["fsub"]["lastOp"] if "fsub" in tree
+                 else tree["interior"][-1])
+        c = int(np.ndim(probe) == 4)       # 1 where chunked (C, Gc, ...)
+        axes = {}
+        for key, value in tree.items():
+            if key == "interior":
+                minor = lambda a: np.ndim(a) - 1   # noqa: E731
+                axes[key] = tuple(
+                    None if a is None else (minor(a) if i < 3 else c)
+                    for i, a in enumerate(value))
+            elif key == "fsub":
+                if set(value) - {"lastOp", "FwdOp", "BwdOp", "CapInv"}:
+                    raise NotImplementedError(
+                        "a pencil mesh shards the sequential compositions "
+                        f"only, not {sorted(value)}")
+                axes[key] = {k: c + (k in ("FwdOp", "BwdOp"))
+                             for k in value}
+            elif key in ("ab", "A"):
+                axes[key] = lead(value)
+            else:
+                axes[key] = jax.tree.map(lambda a: c, value)
+        return axes
+
+    def _specs(self, tree, name):
+        """PartitionSpecs of `tree`'s leaves with `name` on each group
+        axis."""
+        def spec(a, axis):
+            if axis < 0:
+                return PartitionSpec()
+            return PartitionSpec(*([None] * axis + [name]))
+        return jax.tree.map(spec, tree, self.group_axes(tree))
+
+    def _on_each_chip(self, fn, *args):
+        """`fn(*args)` as each chip's own program over its own groups: a
+        shard_map whose every leaf is split on its group axis
+        (`group_axes`), in and out. Inside, `_chip_program` clears the
+        routing, so G is the chip's share wherever the program reads it
+        (the chunk width, the band product's tiles, FUSED_SOLVE = auto)."""
+        mesh, name = self._mesh
+        n = mesh.shape[name]
+
+        def local(*xs):
+            with _chip_program():
+                return fn(*xs)
+
+        def share(a, axis):
+            shape = list(np.shape(a))
+            if axis >= 0:
+                shape[axis] //= n
+            return jax.ShapeDtypeStruct(tuple(shape), a.dtype)
+
+        shares = tuple(jax.tree.map(share, a, self.group_axes(a))
+                       for a in args)
+        out_specs = self._specs(jax.eval_shape(local, *shares), name)
+        in_specs = tuple(self._specs(a, name) for a in args)
+        # check_vma off: a chip's program builds constants (zeros, pins)
+        # that its scans carry beside the split operands
+        return shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
     def _solve_impl(self, aux, rhs, mats=None):
+        if self._route() is not None:
+            return self._on_each_chip(self._solve_impl, aux, rhs, mats)
         with jax.named_scope("dedalus/matsolve/banded.solve"):
             x = self._solve_once(aux, rhs)
             if mats is None and "A" not in aux:

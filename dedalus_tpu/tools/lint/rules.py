@@ -541,8 +541,9 @@ class NonDifferentiableOpInStepBody(Rule):
 
 # Modules whose traced bodies run inside (or compose into) shard_map
 # manual/partial-auto regions — the scope of DTL009. libraries/pencilops.py
-# is deliberately NOT listed: its lax.map chunk dispatches route through
-# BandedOps._shard_chunked manual shard_maps / static unrolls (the PR-13
+# is deliberately NOT listed: under a pencil mesh its lax.map chunk
+# dispatches run inside BandedOps._on_each_chip's manual shard_maps (PR 40;
+# static unrolls where only a pencil_mesh context is active, the PR-13
 # fixes), and its one surviving jnp.pad is mode="edge" factor-time padding
 # that tools.array.zeropad cannot express — the compiled-program contract
 # DTP105 (tools/lint/progcheck.py) still guards the lowered result.
@@ -758,7 +759,7 @@ class GspmdFragileOp(Rule):
     padding there must lower through `tools.array.zeropad`
     (concatenation, bitwise identical) and chunk maps must route through
     an explicit manual shard_map or a static unroll
-    (libraries/pencilops.BandedOps._shard_chunked is the model).
+    (libraries/pencilops.BandedOps._on_each_chip is the model).
 
     Heuristic: flags any `jnp.pad` / `jax.lax.map` call in the scoped
     modules, whole-file — these modules' functions are reached under the
@@ -793,4 +794,4 @@ class GspmdFragileOp(Rule):
                     ctx, node, "lax.map in a manual-region module "
                     "miscompiles under GSPMD; route the chunk map "
                     "through a manual shard_map or a static unroll "
-                    "(see pencilops.BandedOps._shard_chunked)")
+                    "(see pencilops.BandedOps._on_each_chip)")

@@ -124,7 +124,7 @@ PHASES = SUM_PHASES + ("fused", "transpose_exposed", "transpose_overlapped")
 # is not among them: it is the sum of the solver's program rows.
 BUILD_PHASES = ("layout", "assembly_cache", "host_assembly", "pattern",
                 "structure", "factor", "basis_stacks", "plans", "upload",
-                "dd_prepare")
+                "dd_prepare", "shard")
 
 
 def trace_scope(phase, detail=None):
@@ -214,6 +214,9 @@ class BuildPhases:
         dd_prepare      the float64 route's host work: float64 copies of M
                         and L, their int8 planes and float32 pairs, the
                         double-double state (core/ddstep.py)
+        shard           parallel.distribute_solver: the state's, M's and
+                        L's copies onto the mesh, each leaf split on its
+                        pencil-group axis
 
     `compile` is no scope: `compile_sec` is the sum of `first_call_sec`
     over the program rows this solver owns (tools/retrace.py: the thread's
@@ -251,6 +254,10 @@ class BuildPhases:
         # native on a CPU, software on a TPU), None for a narrower dtype
         self.f64_route = None
         self.dd = None
+        # where a solver is sharded over a pencil mesh, the callable that
+        # gives {chips, groups_per_chip, Gc_per_chip}
+        # (parallel.distribute_solver; it holds its operators weakly)
+        self.pencils = None
         self.init_sec = 0.0
         self._init_depth = 0
         self._named_in_init = 0.0
@@ -375,6 +382,8 @@ class BuildPhases:
         dd = self.dd() if self.dd is not None else None
         if dd is not None:
             out["dd"] = dd()
+        if self.pencils is not None:
+            out.update(self.pencils())
         out["programs"] = self.programs()
         return out
 

@@ -316,6 +316,23 @@ def test_north_star_program_fits_a_v5e(north_star, program):
     # 14.4 GB held, 0.85 of the chip, until PR 38)
     if program == "stage_solve":
         assert held < 0.72 * V5E_HBM_BYTES
+        # PR 40's guard: the one-chip stage solve is the parent's program
+        # (its optimised HLO, metadata stripped, diffs empty against PR
+        # 38's: PERF.md), and its temporaries stay where PR 38 left them
+        assert mem.temp_size_in_bytes <= 1770e6
+
+
+def test_north_star_takes_no_mesh_route(north_star):
+    """Nothing PR 40 adds is reached on one chip at q = 32: no pencil mesh
+    routes a program, a chip's share is every group, the q-selected polish
+    of the packed factors is off (the stores keep the parent's layout:
+    the capacitance's LU and pivots alone, the last block's LU)."""
+    ops = north_star["ops"]
+    assert ops._mesh is None and ops._route() is None
+    assert ops.groups_per_chip(NORTH_STAR_G) == NORTH_STAR_G
+    assert not ops._polished
+    aux = north_star["aux"]
+    assert len(aux["Cap"]) == 2 and "CapInv" not in aux
 
 
 def test_north_star_sweep_bodies_are_straight_line(north_star):

@@ -17,16 +17,20 @@ comes second skips its file, it does not fail.
 """
 
 import pathlib
+import re
 import sys
 
 import numpy as np
 import pytest
 import jax
-from jax.sharding import Mesh, SingleDeviceSharding
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 import dedalus_tpu.public as d3
 from dedalus_tpu.extras.bench_problems import build_rb_solver
 from dedalus_tpu.tools.config import config
+from dedalus_tpu.tools.lint.progcheck import collective_counts
 
 sys.path.insert(0, str(pathlib.Path(__file__).parent))
 from test_chip_compile import (  # noqa: E402,F401
@@ -168,3 +172,117 @@ def test_sharded_shear_step_compiles_for_four_v5e_chips(topo):
     _moves_by_all_to_all(text)
     G, S = solver.pencil_shape
     assert not _stack_copies(text, G // 4, S)
+
+
+# ---- rb3d128x128x128 on four chips (PR 40) ----
+#
+# The cell `rb3d128x128x128.block10`: 3-D RB through BandedOps with the
+# pencil groups sharded over the four chips of one host. The step program
+# needs the transform layout of a real build, so it is compiled at 32^2 x
+# 128 (the published pencil, 64 groups a chip); the factor and a stage
+# solve, which need only shapes, at the cell's 4,096 groups.
+
+RB3D_CELL_G = 4096
+
+
+@pytest.fixture(scope="module")
+def rb3d(topo):
+    import json
+    from chipbench.manifest import load_module
+    from dedalus_tpu.libraries import pencilops
+    from test_chip_compile import V5E_HBM_BYTES
+    root = pathlib.Path(__file__).resolve().parents[1]
+    rb = load_module(root / "chipbench" / "configs" / "rb3d128x128x128.py")
+    mp = pytest.MonkeyPatch()
+    mp.setattr(pencilops, "device_memory_bytes", lambda: V5E_HBM_BYTES)
+    mesh = Mesh(np.array(topo.devices), ("x",))
+    try:
+        size = {"Nx": 32, "Ny": 32, "Nz": 128, "matsolver": "banded"}
+        solver = rb.build(0, size=size).solver
+        ops, ts = solver.ops, solver.timestepper
+        # what parallel.distribute_solver records (a described chip takes
+        # no device_put)
+        solver.dist.mesh = mesh
+        ops.shard_over(mesh)
+
+        def place(tree, G=None):
+            def one(a, axis):
+                shape = np.shape(a)
+                if G is not None and axis >= 0:
+                    shape = shape[:axis] + (G,) + shape[axis + 1:]
+                spec = [None] * len(shape)
+                if axis >= 0:
+                    spec[axis] = "x"
+                return jax.ShapeDtypeStruct(
+                    shape, a.dtype, sharding=NamedSharding(mesh, P(*spec)))
+            return jax.tree.map(one, tree, ops.group_axes(tree))
+
+        rd = solver.real_dtype
+        scalar = place(jnp.asarray(0.0, rd))
+        M, L, X = (place(a) for a in (solver.M_mat, solver.L_mat, solver.X))
+        aux = [place(a) for a in jax.eval_shape(ts._factor_uniq, M, L,
+                                                scalar)]
+        G = solver.pencil_shape[0]
+        extra = jax.tree.map(
+            lambda a: place(a) if np.ndim(a) and np.shape(a)[0] == G
+            else jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                      sharding=NamedSharding(mesh, P())),
+            solver.rhs_extra())
+        # the cell's shapes: 4,096 groups
+        Mc, Lc, Xc = (place(a, RB3D_CELL_G)
+                      for a in (solver.M_mat, solver.L_mat, solver.X))
+        auxc = [place(a) for a in jax.eval_shape(ts._factor_uniq, Mc, Lc,
+                                                 scalar)]
+        yield {
+            "spec": dict(rb.SPEC, sizes=dict(rb.SPEC["sizes"], **size)),
+            "ops": ops, "ts": ts, "aux": aux, "auxc": auxc,
+            "step_many": (ts._step_n, (M, L, X, scalar, scalar, extra,
+                                       [aux[j] for j in ts.stage_slot],
+                                       10)),
+            "factor": (ts._factor_uniq, (Mc, Lc, scalar)),
+            "stage_solve": (ts._stage_solve, (2, Xc, [Xc, Xc], [Xc, Xc],
+                                              scalar, auxc[0], Mc, Lc)),
+        }
+    finally:
+        mp.undo()
+
+
+def test_rb3d_step_many_on_four_chips(rb3d):
+    """`step_many(10)` on the four described chips: no all-gather; every
+    all_to_all under `dedalus/transpose/`, as many as
+    chipbench/costs_transpose.py counts (5 exchanges a stage, four chunks
+    each on an accelerator); no copy of the packed store under the banded
+    solve."""
+    from chipbench import costs_transpose
+    ts = rb3d["ts"]
+    _, text = _compile_f32(*rb3d["step_many"])
+    counts = collective_counts(text)
+    assert counts["all-gather"] == 0, counts
+    a2a = [ln for ln in text.splitlines()
+           if " all-to-all(" in ln or " all-to-all-start(" in ln]
+    assert all("dedalus/transpose/" in ln for ln in a2a)
+    want = costs_transpose.rk_step(rb3d["spec"], 4, ts.stages, 4)
+    assert len(a2a) == counts["all-to-all"] == want["ops"] == 40
+    # a chip's packed store: (steps, 2q^2, 64 groups)
+    store = re.compile(r"= f32\[32,12800,64\]\S* copy\(")
+    for ln in text.splitlines():
+        if "banded.solve" in ln:
+            assert not store.search(ln), ln[:300]
+
+
+@pytest.mark.parametrize("program", ["factor", "stage_solve"])
+def test_rb3d_cell_programs_fit_a_chip(rb3d, program):
+    """At the cell's 4,096 groups each chip factors and solves its 1,024
+    (8 chunks of 128 lanes) within its 16 GB: arguments, temporaries and
+    outputs that are not aliased, per chip."""
+    from test_chip_compile import V5E_HBM_BYTES
+    ops = rb3d["ops"]
+    compiled, text = _compile_f32(*rb3d[program])
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert held < V5E_HBM_BYTES
+    assert collective_counts(text)["all-gather"] == 0
+    assert (ops._g_chunks, ops._chunk_width) == (8, 128)
+    panel = rb3d["auxc"][0]["interior"][1]
+    assert panel.shape == (8, 32, 12800, 512)

@@ -99,6 +99,8 @@ def test_auto_keeps_the_structural_q_on_a_tpu(pencils):
     operators = G * ((NB - 1) * 7 * Q * Q + Q * Q) * 4
     woodbury = G * (2 * 5 * NB * Q + 5 * 5) * 4
     assert held == operators + woodbury + 2 * 4 == 92_765_192
+    # PR 40: q = 7 takes neither the mesh route nor the q-selected polish
+    assert ops._route() is None and not ops._polished
 
 
 @pytest.mark.parametrize("program", ["factor", "stage_solve"])

@@ -524,7 +524,7 @@ def backward(data, n):
     assert _rules_fired(bad) == ["DTL009"]
     assert len(bad.findings) == 2
     messages = " ".join(f.message for f in bad.findings)
-    assert "zeropad" in messages and "_shard_chunked" in messages
+    assert "zeropad" in messages and "_on_each_chip" in messages
     good = _lint_src(tmp_path, "core/transforms.py", """
 from ..tools.array import zeropad
 
@@ -533,7 +533,7 @@ def backward(data, n):
 """)
     assert good.findings == []
     # pencilops is deliberately out of scope (documented: its chunk maps
-    # route through _shard_chunked; DTP105 guards the lowered programs)
+    # route through _on_each_chip; DTP105 guards the lowered programs)
     scoped = _lint_src(tmp_path, "libraries/pencilops.py", """
 import jax.numpy as jnp
 

@@ -400,9 +400,10 @@ def test_log_stats_and_the_record_carry_the_ledger(rb, caplog):
 
 # ------------------------------------------------- the per-layer metrics
 
+# every cell, PR 40's four-chip one appended
 ALL_CELLS = ["rb256x64.block", "rb256x64.cfl", "shear512.block",
              "rb2048x1024.block10", "sw_ell255.block",
-             "rb256x64-f64.block10"]
+             "rb256x64-f64.block10", "rb3d128x128x128.block10"]
 NEW_METRICS = [
     ("compile_s", "s", "program_span", "step program", ALL_CELLS),
     ("trace_lower_s", "s", "program_span", "step program", ALL_CELLS),
@@ -426,13 +427,16 @@ def test_per_layer_entry_and_reader(name, unit, source, layer, cells, rb,
                                     monkeypatch):
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     names = [m["name"] for m in bench["per_layer"]]
-    # the ten are the last ten, in the table's order
-    assert names[-len(NEW_METRICS):] == [m[0] for m in NEW_METRICS]
+    # the ten follow one another in the table's order (PR 40's three
+    # metrics come after them)
+    first = names.index(NEW_METRICS[0][0])
+    assert names[first:first + len(NEW_METRICS)] == [m[0] for m in
+                                                      NEW_METRICS]
     entry = bench["per_layer"][names.index(name)]
     assert entry == {"name": name, "unit": unit, "better": "lower",
                      "source": source, "layer": layer, "moves": "setup_s",
                      "workloads": cells}
-    assert layer in {m["layer"] for m in bench["per_layer"][:-10]}
+    assert layer in {m["layer"] for m in bench["per_layer"][:first]}
     assert (ROOT / "chipbench" / "layers" / f"{name}.py").is_file()
     from chipbench.manifest import Manifest
     read = Manifest().layer_reader(name)
