@@ -1047,10 +1047,42 @@ class BandedOps(AdjointSolveOps):
         x = jnp.concatenate([outs[::-1], x_last[None]], axis=0)
         return jnp.moveaxis(x, 0, 1).reshape(G, self.n_pad, k)
 
-    def _solve_interior_fused(self, interior_aux, f, fsub):
+    @staticmethod
+    def _block_rows(c, *stores, reverse=False):
+        """`(xs, i0, read)` of a block-row scan over `stores`: what the scan
+        walks beside the right-hand side, the block-row index it carries
+        from `i0`, and `read(x, i) -> (blocks, next i)`, a body's block row
+        of each store from its slice `x` of `xs`. Without a chunk index `c`
+        the stores are one chunk's (steps, ...) and are the scan's operands
+        themselves (no index). With one they are a chunked factorization's
+        whole (C, steps, ...) stores, and a body takes its block of each
+        with ONE dynamic_slice at (c, i, 0, ...), where it lies, as a body
+        of `_band_mv` takes its tile. Handed a chunk's stores as the chunk
+        loop's operands instead, the TPU wrote each out whole before the
+        scans read it: 268 MB a store and 34 GB a step at RB 2048x1024
+        (compiled for a described v5e). i is carried, counted down where
+        the scan runs in reverse: a scalar beside the loop's own counter,
+        where an index array walked as an operand is an element fetched
+        from memory in each of the 16,384 bodies of a north-star step.
+        Indices are int32."""
+        if c is None:
+            return stores, None, lambda x, i: (x, None)
+        steps = stores[0].shape[1]
+        zero = jnp.int32(0)
+
+        def read(_, i):
+            blocks = tuple(jax.lax.dynamic_slice(
+                s, (c, i) + (zero,) * (s.ndim - 2),
+                (1, 1) + s.shape[2:])[0, 0] for s in stores)
+            return blocks, i - 1 if reverse else i + 1
+
+        return None, jnp.int32(steps - 1 if reverse else 0), read
+
+    def _solve_interior_fused(self, interior_aux, f, fsub, c=None):
         """Solve B~ x = f via the precomposed substitution operators: the
         same blocked sweeps as `_solve_interior`, each scan step one
-        batched GEMM against the factor-time FwdOp/BwdOp."""
+        batched GEMM against the factor-time FwdOp/BwdOp (those of chunk
+        `c` of the whole stores where `c` is given: `_block_rows`)."""
         G, _, k = f.shape
         q, NB = self.q, self.NB
         lastOp = fsub["lastOp"]
@@ -1060,39 +1092,47 @@ class BandedOps(AdjointSolveOps):
             return jnp.moveaxis(x[None], 0, 1).reshape(G, self.n_pad, k)
         # restructured compositions (resolved once per build): spike
         # factors carry their chunk operators in the aux; ascan slices
-        # the step-stacked operators at solve time
+        # the step-stacked operators at solve time. Both get their chunk's
+        # operators sliced out by the chunk loop (`_chunk_of`; no cell
+        # runs them)
         if "spikeF" in fsub:
             return self._solve_interior_spike(f, fsub)
         if self._composition == "ascan":
             return self._solve_interior_ascan(f, fsub)
 
-        def fwd(w_cur, xs):
-            f_next, op_flat = xs
+        fwd_xs, i0, fwd_op = self._block_rows(c, fsub["FwdOp"])
+
+        def fwd(carry, xs):
+            w_cur, i = carry
+            f_next, blocks = xs
+            (op_flat,), i = fwd_op(blocks, i)
             wf = jnp.concatenate([w_cur, f_next.reshape(G, q, k)], axis=1)
             yw = op_flat.reshape(G, 2 * q, 2 * q) @ wf
-            return yw[:, q:], yw[:, :q].reshape(G, q * k)
+            return (yw[:, q:], i), yw[:, :q].reshape(G, q * k)
 
         with jax.named_scope("dedalus/matsolve/banded.fwd"):
-            w_f, ys = jax.lax.scan(fwd, fb[0].reshape(G, q, k),
-                                   (fb[1:], fsub["FwdOp"]))
+            (w_f, _), ys = jax.lax.scan(fwd, (fb[0].reshape(G, q, k), i0),
+                                        (fb[1:], fwd_xs))
         x_last = lastOp @ w_f
         zero = jnp.zeros_like(x_last)
+        bwd_xs, i0, bwd_op = self._block_rows(c, fsub["BwdOp"], reverse=True)
 
         def bwd(carry, xs):
-            x1, x2 = carry
-            y_flat, op_flat = xs
+            (x1, x2), i = carry
+            y_flat, blocks = xs
+            (op_flat,), i = bwd_op(blocks, i)
             z = jnp.concatenate([y_flat.reshape(G, q, k), x1, x2], axis=1)
             x = op_flat.reshape(G, q, 3 * q) @ z
-            return (x, x1), x.reshape(G, q * k)
+            return ((x, x1), i), x.reshape(G, q * k)
 
         with jax.named_scope("dedalus/matsolve/banded.bwd"):
-            _, xs_rev = jax.lax.scan(bwd, (x_last, zero),
-                                     (ys, fsub["BwdOp"]), reverse=True)
+            _, xs_rev = jax.lax.scan(bwd, ((x_last, zero), i0),
+                                     (ys, bwd_xs), reverse=True)
         x = jnp.concatenate([xs_rev.reshape(NB - 1, G, q, k),
                              x_last[None]], axis=0)
         return jnp.moveaxis(x, 0, 1).reshape(G, self.n_pad, k)
 
-    def _solve_interior(self, interior_aux, f, fsub=None):
+    def _solve_interior(self, interior_aux, f, fsub=None, c=None):
         """Solve B~ x = f for f (G, n_pad, k) via the pivoted block factors
         as `_batch_minor` keeps them. Both block-row scans run group-minor
         from end to end: a block row of the right-hand side is (k, q, G),
@@ -1101,9 +1141,11 @@ class BandedOps(AdjointSolveOps):
         against an iota, the triangular solves products by the inverses
         `_factor_interior` stored — so that the TPU program of a body holds
         no gather, no custom call and no re-laid-out copy of a factor
-        slice (tests/test_chip_compile.py asks the compiler)."""
+        slice (tests/test_chip_compile.py asks the compiler). Where `c` is
+        given, perms, panelLU and U12 are the whole chunked stores and the
+        bodies read chunk c's block rows in place (`_block_rows`)."""
         if fsub is not None:
-            return self._solve_interior_fused(interior_aux, f, fsub)
+            return self._solve_interior_fused(interior_aux, f, fsub, c)
         perms, panelLU, U12, lastP, lastLU = interior_aux
         G, _, k = f.shape
         q, NB = self.q, self.NB
@@ -1141,37 +1183,46 @@ class BandedOps(AdjointSolveOps):
         fb = fb.reshape(NB, k * q, G)
 
         # forward: eliminate with pivots; carry the updated next block
-        def fwd(w_cur, xs):
-            f_next, perm, panel = xs
+        fwd_xs, i0, fwd_blocks = self._block_rows(c, perms, panelLU)
+
+        def fwd(carry, xs):
+            w_cur, i = carry
+            f_next, blocks = xs
+            (perm, panel), i = fwd_blocks(blocks, i)
             panel = panel.reshape(q, 2 * q, G)
             w = permute(jnp.concatenate(
                 [w_cur, f_next.reshape(k, q, G)], axis=1), perm)
             L1inv_strict = jnp.where(row > col, panel[:, :q], 0)
             y = w[:, :q] + apply(L1inv_strict, w[:, :q])
             w_next = w[:, q:] - apply(panel[:, q:], y)
-            return w_next, y.reshape(k * q, G)
+            return (w_next, i), y.reshape(k * q, G)
 
         with jax.named_scope("dedalus/matsolve/banded.fwd"):
-            w_f, ys = jax.lax.scan(fwd, fb[0].reshape(k, q, G),
-                                   (fb[1:], perms, panelLU))
+            (w_f, _), ys = jax.lax.scan(fwd, (fb[0].reshape(k, q, G), i0),
+                                        (fb[1:], fwd_xs))
         x_last = last_solve(jnp.transpose(w_f, (2, 1, 0)))        # (G,q,k)
         x_last = jnp.transpose(x_last, (2, 1, 0))                 # (k,q,G)
 
         # backward: x_i = U11_i^-1 (y_i - U12_i @ [x_{i+1}; x_{i+2}])
+        bwd_xs, i0, bwd_blocks = self._block_rows(c, panelLU, U12,
+                                                  reverse=True)
+
         def bwd(carry, xs):
-            x1, x2 = carry                                        # x_{i+1}, x_{i+2}
-            y_flat, panel, U12_i = xs
+            (x1, x2), i = carry                                   # x_{i+1}, x_{i+2}
+            y_flat, blocks = xs
+            (panel, U12_i), i = bwd_blocks(blocks, i)
             rhs = y_flat.reshape(k, q, G) - apply(
                 U12_i.reshape(2 * q, q, G),
                 jnp.concatenate([x1, x2], axis=1))
             U11inv = jnp.where(
                 row <= col, panel.reshape(q, 2 * q, G)[:, :q], 0)
             x = apply(U11inv, rhs)
-            return (x, x1), x.reshape(k * q, G)
+            return ((x, x1), i), x.reshape(k * q, G)
 
         with jax.named_scope("dedalus/matsolve/banded.bwd"):
-            _, xs_rev = jax.lax.scan(bwd, (x_last, jnp.zeros_like(x_last)),
-                                     (ys, panelLU, U12), reverse=True)
+            _, xs_rev = jax.lax.scan(
+                bwd, ((x_last, jnp.zeros_like(x_last)), i0), (ys, bwd_xs),
+                reverse=True)
         x = jnp.concatenate([xs_rev.reshape(NB - 1, k, q, G),
                              x_last[None]], axis=0)
         return jnp.transpose(x, (3, 0, 2, 1)).reshape(G, self.n_pad, k)
@@ -1548,7 +1599,7 @@ class BandedOps(AdjointSolveOps):
             return a * MX + b * LX
         return a * self.matvec(M, x) + b * self.matvec(L, x)
 
-    def _solve_core(self, auxc, fp):
+    def _solve_core(self, auxc, fp, c=None):
         fsub = auxc.get("fsub")
         if fsub is not None and fsub["lastOp"].dtype != fp.dtype:
             # precision ladder: the factors are stored low — run the
@@ -1556,7 +1607,7 @@ class BandedOps(AdjointSolveOps):
             # and _solve_impl refines against the f64 M/L matvec
             fp = fp.astype(fsub["lastOp"].dtype)
         y = self._solve_interior(auxc.get("interior"), fp[..., None],
-                                 fsub=fsub)[..., 0]
+                                 fsub=fsub, c=c)[..., 0]
         if self.t:
             with jax.named_scope("dedalus/matsolve/banded.woodbury"):
                 Vy = (jnp.einsum("gtn,gn->gt", auxc["Vt"], y)
@@ -1574,6 +1625,29 @@ class BandedOps(AdjointSolveOps):
                 y = y - jnp.einsum("gtn,gt->gn", auxc["YbT"], z)
         return y
 
+    def _chunk_of(self, aux, c):
+        """Chunk `c` of a chunked aux as `_solve_core` reads it: every leaf
+        sliced at c (a few MB each; their slices fuse into their readers)
+        but the block-row stores the sequential sweeps walk — the packed
+        perms, panelLU and U12, the precomposed FwdOp and BwdOp —, which
+        stay whole: the sweeps' bodies read chunk c's block rows where
+        they lie (`_block_rows`). The ascan composition takes its
+        operators sliced."""
+        def take(tree):
+            return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, c, keepdims=False), tree)
+
+        out = {k: take(aux[k]) for k in ("Vt", "YbT", "Cap") if k in aux}
+        if "interior" in aux:
+            interior = aux["interior"]
+            out["interior"] = interior[:3] + take(interior[3:])
+        if "fsub" in aux:
+            whole = (("FwdOp", "BwdOp") if self._composition != "ascan"
+                     else ())
+            out["fsub"] = {k: v if k in whole else take(v)
+                           for k, v in aux["fsub"].items()}
+        return out
+
     def _solve_once(self, aux, rhs):
         G = rhs.shape[0]
         fp = rhs[:, self.row_perm]
@@ -1589,13 +1663,13 @@ class BandedOps(AdjointSolveOps):
         else:
             Gc = probe.shape[1]
             fp = self._pad_groups(fp, C * Gc)   # match factor-time padding
-            auxc = {k: aux[k] for k in ("interior", "Vt", "YbT", "Cap",
-                                        "fsub")
-                    if k in aux}
             fpr = fp.reshape(C, Gc, self.n_pad)
 
-            y = jax.lax.map(lambda xs: self._solve_core(xs[0], xs[1]),
-                            (auxc, fpr))
+            def chunk(xs):
+                c, fpc = xs
+                return self._solve_core(self._chunk_of(aux, c), fpc, c)
+
+            y = jax.lax.map(chunk, (jnp.arange(C, dtype=jnp.int32), fpr))
             y = y.reshape(-1, self.n_pad)[:G]
         xp = y[:, :self.n]
         out = xp[:, self.pos_col]

@@ -245,6 +245,69 @@ def test_lbvp_banded_chunked_matches_dense():
     assert np.abs(ud - ub).max() < 1e-12
 
 
+# The chunk loop of `BandedOps._solve_once` hands the block-row sweeps the
+# WHOLE chunked stores and the chunk index, and each sweep body slices its
+# block row (c, i) where it lies (`BandedOps._block_rows`). Five distinct
+# pencils forced into three chunks of two (the third edge-padded) against
+# the same pencils in one chunk: the packed factors at RB 2048x1024's
+# q = 32, the polished ones from `_POLISHED_MIN_Q` (3-D RB's regime), and
+# the chunked FUSED_SOLVE operators; float32, each solve with its
+# refinement sweep, four block rows a pencil, so that each sweep runs
+# three bodies in every chunk, the backward one in reverse.
+
+CHUNK_CASES = {"packed": (32, False), "polished": (64, False),
+               "fused": (32, True)}
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_banded_chunk_loop_slices_blocks_in_place(case):
+    from dedalus_tpu.tools.config import config
+    q, fused = CHUNK_CASES[case]
+    G = 5
+    ops, A, dense = _packed_case(q, 4, 3, G=G, dtype=np.float32)
+    assert ops._polished == (q >= ops._POLISHED_MIN_Q)
+    per_g = ops.NB * 2 * q * q * 2 * 4
+
+    def factor():
+        if fused:    # a*M + b*L == A exactly: the IVP's factor path
+            return ops.factor_lincomb(0.5, A, 0.5, A), (A, A)
+        return ops.factor(A), None
+
+    la = config["linear algebra"]
+    old = la.get("BANDED_CHUNK_MB")
+    la["BANDED_CHUNK_MB"] = str(2.05 * per_g / 1e6)    # two pencils a chunk
+    try:
+        aux_c, mats = factor()
+        assert (ops._g_chunks, ops._chunk_width) == (3, 2)
+    finally:
+        la["BANDED_CHUNK_MB"] = old
+    aux_1, _ = factor()
+    assert ops._g_chunks == 1
+    stores = (aux_c["fsub"]["FwdOp"] if fused
+              else aux_c["interior"][1])
+    assert stores.shape[:2] == (3, ops.NB - 1)
+    assert ("fsub" in aux_c) == fused
+
+    rhs = np.random.default_rng(q).standard_normal((G, ops.n))
+    rhs = jnp.asarray(rhs, dtype=jnp.float32)
+    x_c = np.asarray(ops.solve(aux_c, rhs, mats=mats), np.float64)
+    x_1 = np.asarray(ops.solve(aux_1, rhs, mats=mats), np.float64)
+    x_ref = np.linalg.solve(dense, np.asarray(rhs, np.float64)[..., None])
+    x_ref = x_ref[..., 0]
+    scale = np.abs(x_ref).max(axis=1)
+    # float32 rounding, as far as these pencils amplify it: 2e-4 at q =
+    # 32, 1.2e-3 at q = 64. (On the CPU the chunked and the unchunked
+    # FACTOR programs round differently where the polish runs, so the two
+    # solves lie 3.7e-5 apart there, as they did while the chunk loop
+    # sliced the stores; elsewhere they are equal.)
+    tol = np.finfo(np.float32).eps * np.linalg.cond(dense).max()
+    # every pencil, in every chunk, as in one chunk
+    assert (np.abs(x_c - x_1).max(axis=1) / scale).max() < tol
+    # and both as the dense solve
+    for x in (x_c, x_1):
+        assert (np.abs(x - x_ref).max(axis=1) / scale).max() < tol
+
+
 def build_rb_dtype(Nz, dtype, matsolver):
     """RB column at a given dtype/matsolver for precision comparisons."""
     return build_rb(16, Nz, matsolver=matsolver, dtype=dtype)
@@ -323,10 +386,11 @@ def test_banded_min_q_reblocking_equivalence():
 PACKED_G = 3
 
 
-def _packed_case(q, NB, pins, seed=0):
+def _packed_case(q, NB, pins, seed=0, G=PACKED_G, dtype=np.float64):
     """(ops, A, dense): a BandedOps over identity permutations, its device
-    matrix and the (G, S, S) float64 matrices it represents. S is two
-    short of NB*q, so the factor width is padded."""
+    matrix in `dtype` and the (G, S, S) float64 matrices it represents
+    (rounded to `dtype` first). S is two short of NB*q, so the factor
+    width is padded."""
     from types import SimpleNamespace
     from dedalus_tpu.libraries.pencilops import BandedOps
     rng = np.random.default_rng(seed + 1000 * q + 10 * NB + pins)
@@ -337,16 +401,17 @@ def _packed_case(q, NB, pins, seed=0):
                          pinned_positions=pin_pos)
     ops = BandedOps(st, refine=1)
     n_store = NB * q
-    bands = rng.standard_normal((PACKED_G, 2 * q + 1, n_store))
+    bands = rng.standard_normal((G, 2 * q + 1, n_store))
     cols = np.arange(n_store)[None, :] + np.arange(-q, q + 1)[:, None]
     bands[:, (cols < 0) | (cols >= S)] = 0.0    # off the matrix
     bands[:, :, S:] = 0.0
     bands[:, :, pin_pos] = 0.0                  # pinned rows live in Vt
-    Vt = np.zeros((PACKED_G, pins, n_store))
-    Vt[:, :, :S] = rng.standard_normal((PACKED_G, pins, S))
-    host = {"bands": bands, "Vt": Vt}
-    dense = np.stack([ops.densify_host(host, g) for g in range(PACKED_G)])
-    return ops, ops.to_device(host, np.float64), dense
+    Vt = np.zeros((G, pins, n_store))
+    Vt[:, :, :S] = rng.standard_normal((G, pins, S))
+    host = {"bands": bands.astype(dtype).astype(np.float64),
+            "Vt": Vt.astype(dtype).astype(np.float64)}
+    dense = np.stack([ops.densify_host(host, g) for g in range(G)])
+    return ops, ops.to_device(host, dtype), dense
 
 
 def _assert_really_pivots(aux):
