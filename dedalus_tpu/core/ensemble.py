@@ -65,6 +65,18 @@ ELASTICALLY: `restore_checkpoint` re-pads the true member rows onto
 whatever mesh the restoring fleet has, so a checkpoint taken on 8
 devices restores onto 4 or 1 (and vice versa) bit-identically.
 
+The normal path: a fleet answers what a time loop asks of a solver —
+`step` / `step_many` inside the `step` / `step_many` spans (the health
+probe under `health/check` inside them), `sim_time` (the active members'
+common clock), `health_error`, `ops`, `pencil_shape`, `pencil_dtype`,
+`X` — and books to its template's `build_phases`: the `ensemble` phase
+(the fleet's state, the members' initial conditions and their upload),
+the first factorization under `factor`, and every fleet program's first
+call as a row of the set-up ledger. Its programs are traced under
+`matsolvers.member_fleet`, so that a large fleet's dense pencil products
+are ONE contraction over S each, on the MXU, where `vmap` alone gives the
+multiply-reduce per member (`matsolvers.fleet_contraction` is the rule).
+
 Telemetry: `ensemble/...` counters (fleet_steps, member_steps, dropped,
 rewinds, health_checks, reshards, checkpoints_written) plus an
 `ensemble` summary block (members / active / dropped / reshards /
@@ -85,6 +97,7 @@ dispatches steady blocks without the fleet-global cadence/ramp logic
 the serving driver owns.
 """
 
+import contextlib
 import functools
 import logging
 import time as time_mod
@@ -96,9 +109,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .subsystems import scatter_state, state_key
 from . import timesteppers as timesteppers_mod
+from ..libraries import matsolvers
 from ..tools import dcheckpoint
 from ..tools import metrics as metrics_mod
 from ..tools import retrace as retrace_mod
+from ..tools import tracing
 from ..tools.compat import shard_map
 from ..tools.config import cfg_get
 from ..tools.exceptions import CheckpointError
@@ -248,9 +263,10 @@ class EnsembleSolver:
         self.n_pad = -(-self.members // D) * D
         # ---------------------------------------------------- fleet state
         G, S = solver.pencil_shape
-        X0 = solver.gather_fields()
-        self.X = self._put(jnp.broadcast_to(X0, (self.n_pad, G, S)),
-                           pencil_dim=1)
+        with self.build_phases.scope("ensemble"):
+            X0 = solver.gather_fields()
+            self.X = self._put(jnp.broadcast_to(X0, (self.n_pad, G, S)),
+                               pencil_dim=1)
         self.sim_times = np.full(self.n_pad, float(solver.sim_time))
         self.T = self._put_host(self.sim_times, dtype=self.rd)
         self.dts = np.zeros(self.n_pad)
@@ -275,9 +291,10 @@ class EnsembleSolver:
             self._ms_iter = 0
             self._dt_hist = []
         # per-member RHS operands: every extra field batched (N, ...)
-        self._extras = [self._put(jnp.broadcast_to(
-            arr, (self.n_pad,) + arr.shape))
-            for arr in solver.rhs_extra()]
+        with self.build_phases.scope("ensemble"):
+            self._extras = [self._put(jnp.broadcast_to(
+                arr, (self.n_pad,) + arr.shape))
+                for arr in solver.rhs_extra()]
         # ------------------------------------------------------- programs
         self._programs = {}
         self._project_prog = None
@@ -285,6 +302,7 @@ class EnsembleSolver:
         self._vfactor_prog = None
         self._lhs_key = None
         self._lhs_aux = None
+        self._factored = False
         # ------------------------------------------------------- recovery
         self.iteration = 0
         self.ring = []
@@ -408,6 +426,51 @@ class EnsembleSolver:
         return self.solver.layout
 
     @property
+    def build_phases(self):
+        """The template's set-up ledger, which the fleet books to: what a
+        fleet costs before its first step is its template's build and its
+        own `ensemble` phase, first factorization and programs."""
+        return self.solver.build_phases
+
+    @property
+    def ops(self):
+        return self.solver.ops
+
+    @property
+    def pencil_shape(self):
+        return self.solver.pencil_shape
+
+    @property
+    def pencil_dtype(self):
+        return self.solver.pencil_dtype
+
+    @property
+    def sim_time(self):
+        """The common clock of the active members (of every member once
+        none is active). Raises where they disagree (per-member dts,
+        rewinds): `sim_times` has each member's."""
+        clocks = self.sim_times[:self.members]
+        active = self.active_host[:self.members]
+        if active.any():
+            clocks = clocks[active]
+        if np.ptp(clocks) > 0:
+            raise ValueError(
+                "the active members' clocks disagree "
+                f"({clocks.min():.17g} to {clocks.max():.17g}): no common "
+                "sim_time; read sim_times")
+        return float(clocks[0])
+
+    @property
+    def health_error(self):
+        """Why the fleet cannot go on (every member dropped), or None.
+        A diverged member is dropped or rewound without stopping the
+        others (`dropped`, `rewound`)."""
+        if self.n_active:
+            return None
+        return (f"every member of the fleet is dropped "
+                f"({len(self.dropped)} of {self.members})")
+
+    @property
     def variables(self):
         return self.solver.variables
 
@@ -432,31 +495,35 @@ class EnsembleSolver:
         members.
         """
         solver = self.solver
-        X_rows, extra_rows = [], []
-        for i in range(self.members):
-            fn(i)
-            X_rows.append(solver.gather_fields())
-            extra_rows.append([jnp.asarray(a) for a in solver.rhs_extra()])
-        pad = self.n_pad - self.members
-        X_rows += [X_rows[0]] * pad
-        extra_rows += [extra_rows[0]] * pad
-        self.X = self._put(jnp.stack(X_rows), pencil_dim=1)
-        self._extras = [self._put(jnp.stack([row[k] for row in extra_rows]))
-                        for k in range(len(extra_rows[0]))]
+        with self.build_phases.scope("ensemble"):
+            X_rows, extra_rows = [], []
+            for i in range(self.members):
+                fn(i)
+                X_rows.append(solver.gather_fields())
+                extra_rows.append([jnp.asarray(a)
+                                   for a in solver.rhs_extra()])
+            pad = self.n_pad - self.members
+            X_rows += [X_rows[0]] * pad
+            extra_rows += [extra_rows[0]] * pad
+            self.X = self._put(jnp.stack(X_rows), pencil_dim=1)
+            self._extras = [
+                self._put(jnp.stack([row[k] for row in extra_rows]))
+                for k in range(len(extra_rows[0]))]
         return self
 
     def set_states(self, X):
         """Install per-member initial pencil states directly:
         X is (members, G, S)."""
-        X = jnp.asarray(X, dtype=self.solver.pencil_dtype)
-        if X.shape[0] != self.members:
+        if np.shape(X)[0] != self.members:
             raise ValueError(f"expected leading dim {self.members}, "
-                             f"got {X.shape[0]}")
-        pad = self.n_pad - self.members
-        if pad:
-            X = jnp.concatenate([X, jnp.broadcast_to(
-                X[:1], (pad,) + X.shape[1:])])
-        self.X = self._put(X, pencil_dim=1)
+                             f"got {np.shape(X)[0]}")
+        with self.build_phases.scope("ensemble"):
+            X = jnp.asarray(X, dtype=self.solver.pencil_dtype)
+            pad = self.n_pad - self.members
+            if pad:
+                X = jnp.concatenate([X, jnp.broadcast_to(
+                    X[:1], (pad,) + X.shape[1:])])
+            self.X = self._put(X, pencil_dim=1)
         return self
 
     def member_arrays(self, m):
@@ -483,15 +550,19 @@ class EnsembleSolver:
         return jax.tree.map(lambda _: spec, tree)
 
     def _pencil_contexts(self, fn):
-        """Wrap a fleet body so its TRACE runs under the pencil routing
-        contexts of the 2-D batch x pencil composition: factor/solve
-        funnels shard over the pencil axis (pencilops.pencil_mesh) and
-        the per-member transform walks publish the mesh
-        (field.mesh_transforms; meshctx.walk_axis_names filters the
-        batch axis out, so the walks transpose over the pencil axes
-        only). Identity on 1-D member meshes."""
+        """Wrap a fleet body so its TRACE runs under `member_fleet` (the
+        dense pencil products take the member batching rule) and, on the
+        2-D batch x pencil composition, under its pencil routing
+        contexts: factor/solve funnels shard over the pencil axis
+        (pencilops.pencil_mesh) and the per-member transform walks
+        publish the mesh (field.mesh_transforms; meshctx.walk_axis_names
+        filters the batch axis out, so the walks transpose over the
+        pencil axes only)."""
         if self.pencil_axis is None:
-            return fn
+            def in_fleet(*args):
+                with matsolvers.member_fleet():
+                    return fn(*args)
+            return in_fleet
         from . import field as field_mod
         from ..libraries import pencilops
 
@@ -500,7 +571,8 @@ class EnsembleSolver:
             # must name the context mesh (member axis Manual, pencil axis
             # Auto), not the concrete all-Auto `self.mesh`
             mesh = jax.sharding.get_abstract_mesh()
-            with pencilops.pencil_mesh(mesh, self.pencil_axis), \
+            with matsolvers.member_fleet(), \
+                    pencilops.pencil_mesh(mesh, self.pencil_axis), \
                     field_mod.mesh_transforms(
                         mesh,
                         chunks=getattr(self.solver, "_transpose_chunks",
@@ -519,7 +591,7 @@ class EnsembleSolver:
         over the pencil axis (core/meshctx.local_fft,
         libraries/pencilops.shard_groups), the same targeted routing the
         1-D distributed solver uses, composed under the member axis."""
-        fn = retrace_mod.noted(self._pencil_contexts(raw), label)
+        fn = self._pencil_contexts(raw)
         if self.mesh is not None:
             in_specs = tuple(self._specs(a, b)
                              for a, b in zip(args, batched_flags))
@@ -532,8 +604,9 @@ class EnsembleSolver:
                                out_specs=P(MEMBER_AXIS))
         # every call site memoizes the wrapper (self._programs[n] /
         # self._project_prog / self._vfactor_prog), so each fleet program
-        # is built and traced exactly once
-        return jax.jit(fn)  # dedalus-lint: disable=DTL003
+        # is built and traced exactly once; its first call is a row of the
+        # set-up ledger
+        return retrace_mod.noted_jit(fn, label)
 
     @staticmethod
     def _freeze(new, old, act):
@@ -691,6 +764,22 @@ class EnsembleSolver:
 
     # ------------------------------------------------------ factorization
 
+    def _refactor(self, key, factor):
+        """Install `factor()` as the fleet's LHS factors under `key`, in
+        a `step/factor` span: the one place the fleet refactors. The
+        fleet's first factorization is waited for and booked as the
+        `factor` phase, as a solver's is (timesteppers._ensure_lhs)."""
+        first = not self._factored
+        booked = self.build_phases.scope("factor") if first \
+            else contextlib.nullcontext()
+        with tracing.span("step/factor", {"dt": float(self.dts[0])}), \
+                booked, self._factor_context():
+            self._lhs_key = key
+            self._lhs_aux = factor()
+            if first:
+                self._factored = True
+                jax.block_until_ready(self._lhs_aux)  # dedalus-lint: disable=DTL001
+
     def _factor_context(self):
         """Pencil routing for the (host-driven) LHS factorization of a
         2-D batch x pencil fleet: the factor program traces with the
@@ -698,7 +787,6 @@ class EnsembleSolver:
         pencil axis like the fleet state they solve against (the
         timestepper's own pencil_mesh(None) wrapper inherits this outer
         context). Null context on 1-D member meshes."""
-        import contextlib
         if self.pencil_axis is None:
             return contextlib.nullcontext()
         from ..libraries import pencilops
@@ -710,11 +798,9 @@ class EnsembleSolver:
         if not self.per_member_dt:
             key = round(float(dt), 14)
             if key != self._lhs_key:
-                self._lhs_key = key
-                with self._factor_context():
-                    self._lhs_aux = ts._factor(
-                        solver.M_mat, solver.L_mat,
-                        jnp.asarray(float(dt), dtype=self.rd))
+                self._refactor(key, lambda: ts._factor(
+                    solver.M_mat, solver.L_mat,
+                    jnp.asarray(float(dt), dtype=self.rd)))
             return
         key = tuple(np.round(self.dts, 14))
         if key == self._lhs_key:
@@ -743,12 +829,10 @@ class EnsembleSolver:
     def _ensure_factor_ms(self, a0, b0):
         key = (round(float(a0), 14), round(float(b0), 14))
         if key != self._lhs_key:
-            self._lhs_key = key
-            with self._factor_context():
-                self._lhs_aux = self.timestepper._factor(
-                    self.solver.M_mat, self.solver.L_mat,
-                    jnp.asarray(a0, dtype=self.rd),
-                    jnp.asarray(b0, dtype=self.rd))
+            self._refactor(key, lambda: self.timestepper._factor(
+                self.solver.M_mat, self.solver.L_mat,
+                jnp.asarray(a0, dtype=self.rd),
+                jnp.asarray(b0, dtype=self.rd)))
 
     # ------------------------------------------------------------ stepping
 
@@ -874,7 +958,8 @@ class EnsembleSolver:
                        jnp.asarray(c, dtype=self.rd))
 
     def step(self, dt=None):
-        self.step_many(1, dt)
+        """One fleet step (`step_many(1, dt)` in the `step` span)."""
+        self._advance(1, dt, "step")
 
     def step_many(self, n, dt=None):
         """
@@ -883,6 +968,12 @@ class EnsembleSolver:
         scanned device dispatch. With per_member_dt, `dt` may be a
         (members,) array; scalars apply fleet-wide.
         """
+        self._advance(n, dt, "step_many")
+
+    def _advance(self, n, dt, span):
+        """`step_many`'s body: the host side of the advance inside the
+        `span` span (`step` or `step_many`, as a solver opens them), this
+        fleet's template the thread's current ledger."""
         n = int(n)
         if n <= 0:
             return
@@ -891,6 +982,11 @@ class EnsembleSolver:
             # dispatch (and before the health probe can mistake the lost
             # shard's garbage for per-member divergence)
             self._handle_device_loss()
+        self.build_phases.enter()
+        with tracing.span(span, {"iteration": self.iteration, "n": n}):
+            self._advance_in_span(n, dt)
+
+    def _advance_in_span(self, n, dt):
         solver = self.solver
         ts = self.timestepper
         if dt is not None:
@@ -1138,8 +1234,13 @@ class EnsembleSolver:
     # ------------------------------------------------- health and recovery
 
     def check_health(self):
-        """Run the per-member probe now; diverged members are dropped or
-        rewound per `policy`. Returns the list of member events handled."""
+        """Run the per-member probe now (the `health/check` span);
+        diverged members are dropped or rewound per `policy`. Returns the
+        list of member events handled."""
+        with tracing.span("health/check"):
+            return self._check_health()
+
+    def _check_health(self):
         nonfinite, max_abs = jax.device_get(self._probe())
         self.metrics.inc("ensemble/health_checks")
         bad = []

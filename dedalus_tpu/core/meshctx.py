@@ -16,8 +16,12 @@ dedalus/core/transposes.pyx moves data so that stays true).
 import threading
 from functools import partial
 
+import numpy as np
+import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
+from ..libraries.matsolvers import in_member_fleet
 from ..tools.compat import shard_map
 
 _CTX = threading.local()
@@ -64,7 +68,6 @@ def surviving_mesh(mesh, lost_indices):
         raise RuntimeError("no surviving devices to build a mesh from")
     if len(survivors) < 2:
         return None
-    import numpy as np
     return Mesh(np.array(survivors), mesh.axis_names)
 
 
@@ -104,7 +107,6 @@ def gathered_apply(fn, data, mesh, axis_name, dim=0):
                            for d in range(data.ndim)])
 
     def local(block):
-        import jax
         full = jax.lax.all_gather(block, axis_name, axis=dim, tiled=True)
         out = fn(full)
         idx = jax.lax.axis_index(axis_name)
@@ -114,6 +116,48 @@ def gathered_apply(fn, data, mesh, axis_name, dim=0):
     return shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec)(data)
 
 
+# XLA:TPU's float32 FFTs are wrong past some number of rows: irfft of
+# length 384 over (1024, 96) rows (a 1,024-member fleet's RB 256x64 grid)
+# returns 0.32 of the result's largest entry as error, over (512, 96) rows
+# 2e-7, as every rfft / irfft / fft / ifft of length 96 to 384 does up to
+# 81,920 rows of a 2-D operand (v5e, the JAX of this package's
+# installation). A fleet's FFTs carry the member axis on top of a
+# member's own rows, so inside a fleet program they go in member chunks
+# of at most this many rows.
+FLEET_FFT_ROWS = 65536
+
+
+def _member_chunked(fn):
+    """`fn` whose batching rule applies it to the member axis in chunks of
+    at most `FLEET_FFT_ROWS` rows (an FFT along the last axis does not mix
+    rows: any split of them is the same transform)."""
+
+    @jax.custom_batching.custom_vmap
+    def chunked(a):
+        return fn(a)
+
+    @chunked.def_vmap
+    def _vmapped(members, in_batched, a):
+        (batched,) = in_batched
+        if not batched:
+            return fn(a), False
+        rows = members * int(np.prod(a.shape[1:-1], dtype=np.int64))
+        chunks = -(-rows // FLEET_FFT_ROWS)
+        if chunks <= 1:
+            return fn(a), True
+        # the fewest chunks that divide the members evenly, else the
+        # fewest that hold them (a shorter last chunk)
+        chunks = next((c for c in range(chunks, members + 1)
+                       if members % c == 0 and c <= 4 * chunks), chunks)
+        size = -(-members // chunks)
+        # a static unroll: the chunks are few, and a `lax.map` here would
+        # sit in the manual regions of a sharded walk (lint DTL009)
+        return jnp.concatenate([fn(a[i:i + size])
+                                for i in range(0, members, size)]), True
+
+    return chunked
+
+
 def local_fft(fn, data, orig_axis):
     """
     Apply `fn` (an FFT-like op along the LAST axis of `data`, where `data`
@@ -121,8 +165,11 @@ def local_fft(fn, data, orig_axis):
     inside shard_map each device runs the FFT on its local batch block.
     Falls back to the global-view call (which GSPMD will gather) when no
     walk is active, nothing is sharded, or a sharded dim does not divide
-    the mesh axis.
+    the mesh axis. Inside a fleet's program (`matsolvers.member_fleet`)
+    the member axis goes in chunks (`FLEET_FFT_ROWS`).
     """
+    if in_member_fleet():
+        fn = _member_chunked(fn)
     state = active()
     if state is None or orig_axis is None:
         return fn(data)

@@ -11,6 +11,8 @@ Functional API so factorizations flow through jit as pytrees:
     x   = Solver.solve(aux, rhs)    # (G, S) -> (G, S)
 """
 
+import threading
+
 import jax
 import jax.numpy as jnp
 import jax.scipy.linalg as jsl
@@ -58,6 +60,114 @@ def batched_matvec(A, x):
     return jnp.sum(A * x[:, None, :], axis=-1)
 
 
+# ------------------------------------------------------ the fleet product
+#
+# An ensemble (core/ensemble.py) vmaps the step body over a member axis
+# with the stacks shared by every member. `vmap` of `batched_matvec` then
+# makes N G S^2 multiply-adds on the vector unit for one read of the stack:
+# bound by bandwidth for a few members, by the VPU for many. For many
+# members the product is a matrix-matrix one, (G, S, S) . (G, S, N), and
+# belongs on the MXU: `fleet_matvec`, one contraction over S that reads the
+# stack once for all members. Inside `member_fleet` (a trace-time context
+# the fleet's programs are traced under) the pencil products go through
+# `shared_product`, whose batching rule picks between the two forms by
+# `fleet_contraction`; outside it they are `batched_matvec` and every
+# single-member program traces exactly as before.
+
+# the precision the configurations guarantee (dedalus_tpu/__init__.py sets
+# it for every matmul); module-level so that a check can lower it to one
+# bf16 pass and show that the limits bite (chipbench/tests/gate_bf16_ens.py)
+FLEET_PRECISION = jax.lax.Precision.HIGHEST
+
+_FLEET = threading.local()
+
+
+# the member count from which a fleet's products take the contraction, by
+# backend (the measurements are in `fleet_contraction`'s docstring)
+FLEET_MIN_MEMBERS = {"tpu": 16, "cpu": 16}
+
+
+def fleet_contraction(members, backend):
+    """The rule: the contraction for `members` members sharing a stack,
+    else the multiply-reduce vmapped. The multiply-reduce reads the stack
+    once and does N multiply-adds per entry on the vector unit; the
+    contraction reads it once and does them on the MXU (a TPU's: at
+    'highest' float32 as six bf16 passes) or as a blocked matrix product.
+    On a v5e at RB 256x64's stacks (128 x 526^2) the contraction is the
+    faster at every member count measured, 0.30 against 0.50 ms a product
+    at 8 members, 0.30 against 1.01 at 16, 3.8 against 105 at 1,024
+    (benchmarks/fleet_product.py); it pays a copy of each stack into the
+    dot's layout once a launch, 0.35 ms, so a one-step launch of 5
+    products at 8 members is no faster. On XLA:CPU it is the faster from 4
+    members (32 x 142^2: 0.67 against 4.8 ms). Both from 16: under that
+    the forms are within a fraction of a millisecond, and the small fleets
+    of serving (`BATCH_MAX_MEMBERS`, 8 seats) and of the tests keep the
+    programs and the rounding of their solo runs."""
+    return members >= FLEET_MIN_MEMBERS.get(backend, 16)
+
+
+def fleet_matvec(A, X):
+    """(G, S, S) . (N, G, S) -> (N, G, S): every member's product with
+    the shared stack as ONE contraction over S, a dot the MXU runs at
+    `FLEET_PRECISION`, reading the stack once for all N members."""
+    return jnp.einsum("gij,ngj->ngi", A, X, precision=FLEET_PRECISION)
+
+
+def _member_batched(scope):
+    """`batched_matvec` whose batching rule takes `fleet_matvec` under
+    the named scope `scope` where the vector alone carries the member axis
+    and `fleet_contraction` says so."""
+
+    @jax.custom_batching.custom_vmap
+    def product(A, x):
+        return batched_matvec(A, x)
+
+    @product.def_vmap
+    def _vmapped(members, in_batched, A, x):
+        A_batched, x_batched = in_batched
+        if x_batched and not A_batched \
+                and fleet_contraction(members, jax.default_backend()):
+            with jax.named_scope(scope):
+                return fleet_matvec(A, x), True
+        return jax.vmap(batched_matvec,
+                        in_axes=(0 if A_batched else None,
+                                 0 if x_batched else None))(A, x), True
+
+    return product
+
+
+_FLEET_PRODUCTS = {
+    "matvec": _member_batched("dedalus/matsolve/dense.fleet_matvec"),
+    "solve": _member_batched("dedalus/matsolve/dense.fleet_solve"),
+}
+
+
+class member_fleet:
+    """Trace-time context: the body of a fleet program, whose pencil
+    products `shared_product` routes through the member batching rule."""
+
+    def __enter__(self):
+        self.prev = getattr(_FLEET, "on", False)
+        _FLEET.on = True
+
+    def __exit__(self, *exc):
+        _FLEET.on = self.prev
+
+
+def in_member_fleet():
+    """Whether a fleet program is being traced (`member_fleet`)."""
+    return getattr(_FLEET, "on", False)
+
+
+def shared_product(A, x, kind):
+    """(G, S, S) . (G, S) -> (G, S) of a dense pencil product (`kind`
+    "matvec" or "solve", the stored inverse's): `batched_matvec`, or
+    inside `member_fleet` its member-batched form."""
+    if not in_member_fleet():
+        return batched_matvec(A, x)
+    return _FLEET_PRODUCTS[kind](A, x)
+
+
 @add_solver
 class BatchedLUFactorized:
     """Batched dense LU with partial pivoting (default; the TPU analogue of
@@ -89,7 +199,7 @@ class BatchedInverse:
 
     @staticmethod
     def solve(inv, rhs):
-        return batched_matvec(inv, rhs)
+        return shared_product(inv, rhs, "solve")
 
     @staticmethod
     def solve_multi(inv, rhs):

@@ -38,8 +38,8 @@ import jax.scipy.linalg as jsl
 from jax.sharding import PartitionSpec
 
 from . import solvecomp
-from .matsolvers import (BatchedInverseRefined, batched_matvec, get_solver,
-                         refined_ladder)
+from .matsolvers import (BatchedInverseRefined, get_solver, refined_ladder,
+                         shared_product)
 from ..tools.compat import shard_map
 from ..tools.config import config
 from ..tools.array import zeropad
@@ -244,14 +244,15 @@ class DenseOps(AdjointSolveOps):
 
     def matvec(self, A, X):
         with jax.named_scope("dedalus/matsolve/dense.matvec"):
-            return batched_matvec(A, X)
+            return shared_product(A, X, "matvec")
 
     def matvec_pair(self, M, L, X):
         """(M @ X, L @ X) — the fused-step pair surface (core/fusedstep).
         Dense matvecs share nothing to factor out, so this is the two
         products (bitwise identical to separate calls by construction)."""
         with jax.named_scope("dedalus/matsolve/dense.matvec_pair"):
-            return batched_matvec(M, X), batched_matvec(L, X)
+            return (shared_product(M, X, "matvec"),
+                    shared_product(L, X, "matvec"))
 
     def lincomb(self, a, A, b, B):
         return a * A + b * B

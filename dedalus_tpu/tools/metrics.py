@@ -124,7 +124,7 @@ PHASES = SUM_PHASES + ("fused", "transpose_exposed", "transpose_overlapped")
 # is not among them: it is the sum of the solver's program rows.
 BUILD_PHASES = ("layout", "assembly_cache", "host_assembly", "pattern",
                 "structure", "factor", "basis_stacks", "plans", "upload",
-                "dd_prepare", "shard")
+                "dd_prepare", "shard", "ensemble")
 
 
 def trace_scope(phase, detail=None):
@@ -217,6 +217,10 @@ class BuildPhases:
         shard           parallel.distribute_solver: the state's, M's and
                         L's copies onto the mesh, each leaf split on its
                         pencil-group axis
+        ensemble        an EnsembleSolver over this solver (it books to
+                        its template's ledger): the fleet's state and
+                        operands, the members' initial conditions and
+                        their upload
 
     `compile` is no scope: `compile_sec` is the sum of `first_call_sec`
     over the program rows this solver owns (tools/retrace.py: the thread's

@@ -484,6 +484,10 @@ class _NotedJit:
                 state.row = None
                 row.close()
 
+    def lower(self, *args, **kwargs):
+        """The jitted program's `lower` (an inspection: no ledger row)."""
+        return self.jitted.lower(*args, **kwargs)
+
 
 def noted_jit(fn, label=None, **jit_kwargs):
     """`jax.jit(noted(fn, label), **jit_kwargs)` whose first call of each

@@ -60,8 +60,20 @@ def build_heat_solver(scheme="RK222", **kw):
     return solver, member_init
 
 
+_SERIAL = {}
+
+
 def serial_states(scheme, n, dt, members=8, dts=None):
-    """Reference: each member stepped on its own solver."""
+    """Reference: each member stepped on its own solver. The states depend
+    on the arguments alone, so each set is computed once a module (the
+    bit-match cases of both mesh parametrisations share it)."""
+    key = (scheme, n, dt, members, None if dts is None else tuple(dts))
+    if key not in _SERIAL:
+        _SERIAL[key] = _serial_states(scheme, n, dt, members, dts)
+    return _SERIAL[key]
+
+
+def _serial_states(scheme, n, dt, members, dts):
     outs = []
     for i in range(members):
         solver, member_init = build_heat_solver(scheme)

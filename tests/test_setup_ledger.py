@@ -400,10 +400,11 @@ def test_log_stats_and_the_record_carry_the_ledger(rb, caplog):
 
 # ------------------------------------------------- the per-layer metrics
 
-# every cell, PR 40's four-chip one appended
+# every cell, the four-chip one and the ensemble's appended
 ALL_CELLS = ["rb256x64.block", "rb256x64.cfl", "shear512.block",
              "rb2048x1024.block10", "sw_ell255.block",
-             "rb256x64-f64.block10", "rb3d128x128x128.block10"]
+             "rb256x64-f64.block10", "rb3d128x128x128.block10",
+             "rb256x64-ens.block10"]
 NEW_METRICS = [
     ("compile_s", "s", "program_span", "step program", ALL_CELLS),
     ("trace_lower_s", "s", "program_span", "step program", ALL_CELLS),
@@ -427,8 +428,8 @@ def test_per_layer_entry_and_reader(name, unit, source, layer, cells, rb,
                                     monkeypatch):
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     names = [m["name"] for m in bench["per_layer"]]
-    # the ten follow one another in the table's order (PR 40's three
-    # metrics come after them)
+    # the ten follow one another in the table's order (the metrics of
+    # later cells come after them)
     first = names.index(NEW_METRICS[0][0])
     assert names[first:first + len(NEW_METRICS)] == [m[0] for m in
                                                       NEW_METRICS]
