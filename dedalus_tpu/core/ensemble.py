@@ -75,7 +75,10 @@ the first factorization under `factor`, and every fleet program's first
 call as a row of the set-up ledger. Its programs are traced under
 `matsolvers.member_fleet`, so that a large fleet's dense pencil products
 are ONE contraction over S each, on the MXU, where `vmap` alone gives the
-multiply-reduce per member (`matsolvers.fleet_contraction` is the rule).
+multiply-reduce per member (`matsolvers.fleet_contraction` is the rule),
+and its transforms are the bases' matrices contracted over the
+transformed axis for every member at once (core/transforms.py), counted in
+`build_phases.record()["fleet_transforms"]`.
 
 Telemetry: `ensemble/...` counters (fleet_steps, member_steps, dropped,
 rewinds, health_checks, reshards, checkpoints_written) plus an
@@ -109,6 +112,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .subsystems import scatter_state, state_key
 from . import timesteppers as timesteppers_mod
+from . import transforms
 from ..libraries import matsolvers
 from ..tools import dcheckpoint
 from ..tools import metrics as metrics_mod
@@ -670,9 +674,17 @@ class EnsembleSolver:
         # each program exactly once and the retrace sentinel stays quiet
         prog = self._programs.get(n)
         if prog is None:
-            raw = functools.partial(
+            body = functools.partial(
                 self._fleet_multistep if self._multistep else self._fleet_rk,
                 n)
+
+            def raw(*a):
+                # the step's transform calls, counted as they are traced
+                with transforms.counting_fleet_transforms() as counted:
+                    out = body(*a)
+                self.build_phases.fleet_transforms = counted.record()
+                return out
+
             prog = self._programs[n] = self._wrap(
                 raw, f"ensemble/fleet_step[{n}]", args, batched_flags)
         return prog

@@ -123,7 +123,10 @@ def gathered_apply(fn, data, mesh, axis_name, dim=0):
 # 81,920 rows of a 2-D operand (v5e, the JAX of this package's
 # installation). A fleet's FFTs carry the member axis on top of a
 # member's own rows, so inside a fleet program they go in member chunks
-# of at most this many rows.
+# of at most this many rows. Only the fleets that keep the FFT reach this:
+# under `matsolvers.fleet_contraction`'s member count, or a basis with no
+# 'matrix' plan (from that count a fleet's transforms are matrix
+# products, core/transforms.py).
 FLEET_FFT_ROWS = 65536
 
 

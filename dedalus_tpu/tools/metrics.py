@@ -238,8 +238,10 @@ class BuildPhases:
     everything named inside `init()`: the phases and, outside any phase,
     the first calls of programs, `init_compile_sec`), the assembly-cache
     verdict, the float64 route, the first step program's `group_stacks`
-    tally, and `programs`: this solver's totals of the set-up ledger and
-    its twelve rows with the largest `first_call_sec`.
+    tally, a fleet's `fleet_transforms` (its step program's transform
+    calls by form and basis class), and `programs`: this solver's totals
+    of the set-up ledger and its twelve rows with the largest
+    `first_call_sec`.
     """
 
     TOP_ROWS = 12
@@ -251,6 +253,9 @@ class BuildPhases:
         # which way the first step program applies its `gblocks` stacks
         # (core/curvilinear.gblocks_tally), once it has been lowered
         self.group_stacks = None
+        # an EnsembleSolver's transform calls traced in its step program,
+        # by form and basis class (transforms.counting_fleet_transforms)
+        self.fleet_transforms = None
         # which route keeps a float64 problem's guarantee: "dd" (the
         # emulated-f64 runner, core/ddstep.py; `dd` is then the callable
         # that gives its counters, held weakly: this object outlives its
@@ -382,6 +387,8 @@ class BuildPhases:
         out["assembly_cache"] = self.cache
         if self.group_stacks is not None:
             out["group_stacks"] = self.group_stacks
+        if self.fleet_transforms is not None:
+            out["fleet_transforms"] = self.fleet_transforms
         out["f64_route"] = self.f64_route
         dd = self.dd() if self.dd is not None else None
         if dd is not None:

@@ -179,6 +179,17 @@ def test_spans_and_the_ledger(runs):
     assert "ensemble/fleet_step[10]" in labels
 
 
+def test_the_fleet_transforms_are_matrix_products(runs):
+    """`build_phases.record()["fleet_transforms"]`: every RealFourier and
+    ChebyshevT (class Jacobi) call of the fleet's step program takes its
+    basis's matrix (core/transforms.py), none the FFT; five of each a
+    right-hand side, two of those a RK222 step."""
+    assert runs["record"]["fleet_transforms"] == {
+        "matrix": 20, "fft": 0,
+        "by_basis": {"Jacobi": {"matrix": 10, "fft": 0},
+                     "RealFourier": {"matrix": 10, "fft": 0}}}
+
+
 def test_the_cells_readers(runs):
     """The three new per-layer readers: `ensemble_init_s` reads the
     template's `ensemble` phase; the fleet-product readers read the

@@ -98,6 +98,11 @@ def test_fleet_bitmatches_serial(scheme, mesh):
     for i in range(8):
         err = np.max(np.abs(np.asarray(ens.X[i]) - serial[i]))
         assert err <= 1e-12, (i, err)
+    # under `fleet_contraction`'s member count the fleet's transforms keep
+    # the FFT of the serial runs (core/transforms.py)
+    counted = solver.build_phases.record()["fleet_transforms"]
+    assert counted["matrix"] == 0 and counted["fft"] > 0
+    assert set(counted["by_basis"]) == {"RealFourier"}
     assert np.allclose(ens.sim_times[:8], 25e-3)
 
 

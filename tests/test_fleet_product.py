@@ -18,7 +18,9 @@ member axis is ONE contraction over S for every member where
       instruction of the scan body copies a stack — the only stack-shaped
       instructions there are bitcasts; the copies into the dot's layout
       are made once a launch, at its head (at the published size, 1,024
-      members, the same: compiled once by hand, PERF.md section 4).
+      members, the same: compiled once by hand, PERF.md section 4); the
+      transforms are `convolution`s under the `fleet_matrix` scopes, and
+      the program holds no `fft`.
 """
 
 import contextlib
@@ -221,6 +223,28 @@ def test_fleet_products_are_dots_on_the_mxu(fleet_compiled):
     # M X0, two L Xi, two stored-inverse solves: one contraction each
     assert sum(SCOPES[0] in ln for ln in dots) == 3, dots
     assert sum(SCOPES[1] in ln for ln in dots) == 2, dots
+
+
+def test_fleet_transforms_are_dots_on_the_mxu(fleet_compiled):
+    """The fleet's transforms are its bases' matrices (core/transforms.py):
+    no `fft` instruction in the program, and every transform contraction
+    a `convolution` (the TPU's dot) under a `fleet_matrix` scope, one a
+    traced call: per evaluation of the right-hand side (two a RK222 step)
+    u, grad b and grad u to the grid in x, one field in z (the fused
+    composites take the others' z axis), u.grad u and u.grad b back in x
+    and z."""
+    _, text = fleet_compiled
+    assert not re.search(r" fft\(", text)
+    scope = re.compile(r'op_name="[^"]*dedalus/transform/(\w+)\.(\w+)\.(\w+)')
+    dots = {}
+    for ln in text.splitlines():
+        m = scope.search(ln)
+        if m and re.search(r" (convolution|dot)\(", ln):
+            dots[m.groups()] = dots.get(m.groups(), 0) + 1
+    assert dots == {("RealFourier", "fleet_matrix", "bwd"): 6,
+                    ("RealFourier", "fleet_matrix", "fwd"): 4,
+                    ("Jacobi", "fleet_matrix", "bwd"): 2,
+                    ("Jacobi", "fleet_matrix", "fwd"): 4}, dots
 
 
 def test_scan_body_copies_no_stack(fleet_compiled):

@@ -277,3 +277,55 @@ def test_degenerate_sizes(rng):
         c0 = np.asarray(f["c"]).copy()
         _ = f["g"]
         assert np.allclose(np.asarray(f["c"]), c0, atol=1e-12), N
+
+
+# members of a fleet from which its transforms, like its pencil products,
+# take the contraction (`matsolvers.fleet_contraction`)
+FLEET = 16
+
+
+@pytest.mark.parametrize("scale", [1, 3 / 2])
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("basis", ["RealFourier", "ChebyshevT",
+                                   "ChebyshevT-k2"])
+def test_fleet_transforms_are_matrix_contractions(basis, direction, scale,
+                                                  rng):
+    """Inside a fleet program (`matsolvers.member_fleet`), vmapped over
+    FLEET members, an `fft` plan applies its basis's matrix plan along the
+    axis (RealFourier along an inner axis, ChebyshevT along the last): the
+    FFT path batched to 1e-6 in float32, with no `fft` in the jaxpr. Under
+    FLEET members it is the FFT path vmapped, and outside a fleet the
+    plan's jaxpr is its class's own."""
+    import jax
+    from dedalus_tpu.core import transforms as tr
+    from dedalus_tpu.libraries import matsolvers
+    c = d3.Coordinate("x")
+    d3.Distributor(c, dtype=np.float64)
+    if basis == "RealFourier":
+        b, axis = d3.RealFourier(c, size=16, bounds=(0, 2.3)), 1
+    else:
+        b = d3.ChebyshevT(c, size=16, bounds=(0, 1))
+        b, axis = (b.derivative_basis(2) if basis.endswith("k2") else b), 2
+    plan = tr.get_plan(b, scale, "fft")
+    own = getattr(type(plan)(b, scale), direction)
+    n = b.grid_size(scale) if direction == "forward" else b.size
+    shape = [3, 4, 5]
+    shape[axis] = n
+    x = jnp.asarray(rng.standard_normal([FLEET] + shape), jnp.float32)
+    member = lambda a: getattr(plan, direction)(a, axis)  # noqa: E731
+
+    def fleet(a):
+        with matsolvers.member_fleet():
+            return jax.vmap(member)(a)
+
+    def ffts(fn, *args):
+        return str(jax.make_jaxpr(fn)(*args)).count(" fft[")
+
+    want = np.asarray(jax.jit(jax.vmap(lambda a: own(a, axis)))(x),
+                      np.float64)
+    got = np.asarray(jax.jit(fleet)(x), np.float64)
+    assert np.abs(got - want).max() <= 1e-6 * np.abs(want).max()
+    assert ffts(fleet, x) == 0
+    assert ffts(fleet, x[:FLEET // 2]) == ffts(jax.vmap(member), x) > 0
+    assert str(jax.make_jaxpr(member)(x[0])) == \
+        str(jax.make_jaxpr(lambda a: own(a, axis))(x[0]))
